@@ -18,6 +18,9 @@ def main() -> None:
                     choices=["multisplit", "sort", "histogram", "sssp", "roofline",
                              "roofline-multisplit", "autotune-drift", "serving"])
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     sections = args.section or ["multisplit", "sort", "histogram", "sssp",
                                 "roofline", "roofline-multisplit",
                                 "autotune-drift", "serving"]

@@ -1,9 +1,1 @@
-"""repro: TPU-native reproduction of GPU Multisplit (see ROADMAP.md).
-
-Importing the package installs the jax version-compat shims (``repro.compat``)
-so code written against the modern mesh API runs on the pinned jax.
-"""
-
-from repro import compat as _compat
-
-_compat.install()
+"""repro: TPU-native reproduction of GPU Multisplit (see ROADMAP.md)."""

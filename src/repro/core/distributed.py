@@ -24,9 +24,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
-from repro.core import multisplit as ms
-from repro.core.identifiers import BucketSpec
+from repro.core.identifiers import BucketSpec, IdentitySpec
 from repro.core.pipeline import MultisplitResult, make_plan, resolve_backend
 
 Array = jnp.ndarray
@@ -339,14 +337,17 @@ def multisplit_bucket_sharded(
 
     # final local stage: src-major -> bucket-major within my group.
     # Received buffer is a concatenation of per-src bucket-major chunks; a
-    # local multisplit on (bucket id within group) restores global order.
+    # local multisplit on (bucket id within group) restores global order —
+    # a positions-only plan, tiled like the first local stage (one dense
+    # capacity x m/D one-hot would not fit a chip at n = 2^25).
     lo = my_idx * mb
     sub_ids = jnp.clip(bucket_fn(keys_rx) - lo, 0, mb - 1)
     valid = jnp.arange(capacity) < jnp.minimum(recv.sum(), capacity)
     sub_ids = jnp.where(valid, sub_ids, mb - 1)  # pads ride in the last sub-bucket
-    sub_local, sub_hist = ms.tile_local_offsets(sub_ids, mb)
-    sub_starts = (jnp.cumsum(sub_hist) - sub_hist).astype(jnp.int32)
-    dest = sub_starts[sub_ids] + sub_local
+    dest = make_plan(
+        capacity, mb, method=method, backend=resolve_backend(use_pallas, True, backend),
+        tile=tile, bucket_fn=IdentitySpec(mb), mode="positions_only",
+    )(sub_ids.astype(jnp.int32)).permutation
     keys_out = jnp.zeros_like(keys_rx).at[dest].set(keys_rx)
     vals_out = None
     if vals_rx is not None:
@@ -379,4 +380,4 @@ def make_multisplit_sharded(
     out_specs = ShardedMultisplitResult(
         P(axis_name), P(axis_name) if key_value else None, P(), P()
     )
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)

@@ -110,7 +110,9 @@ class DeltaSpec(BucketSpec):
 
     def emit(self, keys: Array) -> Array:
         ids = keys.astype(jnp.uint32) // jnp.uint32(self.delta)
-        return jnp.minimum(ids, self.num_buckets - 1).astype(jnp.int32)
+        last = jnp.uint32(self.num_buckets - 1)
+        # a compare-select, not jnp.minimum: Mosaic lowers no unsigned min
+        return jnp.where(ids < last, ids, last).astype(jnp.int32)
 
     @property
     def name(self) -> str:

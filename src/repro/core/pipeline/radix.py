@@ -52,6 +52,13 @@ def radix_passes(radix_bits: int, key_bits: int) -> List[Tuple[int, int]]:
 # pair histograms stop paying for the saved scatter.
 MAX_PAIR_BITS = 16
 
+# Ceiling on ONE pair scan table (per-tile histograms, G): L·2^bits·s int32
+# words, L the tile count. Kernel tiles stay small (their T×T planes bound
+# VMEM), so at n = 2^25 a 16-bit pair on 512-row tiles would need 16 GiB per
+# table — more than a v5e chip's HBM. Past this ceiling the sort keeps the
+# chained single-digit schedule (same result, DESIGN.md §13).
+MAX_PAIR_TABLE_BYTES = 1 << 30
+
 
 def radix_pass_pairs(
     radix_bits: int, key_bits: int, max_pair_bits: int = MAX_PAIR_BITS
@@ -121,7 +128,8 @@ class RadixPipeline:
         s = segments or 1
         be = get_backend(backend)
         fused_stage = be.tiled and be.fuses_digits
-        if fuse_digits and fused_stage:
+        fused = fuse_digits and fused_stage
+        if fused:
             # Fused-pair schedule (DESIGN.md §13): each pair is ONE sweep
             # over the combined 2r-bit digit, which the tile stage decomposes
             # into two r-wide solves around an in-VMEM reorder (digit_split).
@@ -144,6 +152,10 @@ class RadixPipeline:
                 n, m_eff, method, key_value, backend, tile, family=self.family,
                 digits=2, stage_m=stage_m,
             )
+            n_tiles = -(-n // self.tile) * (batch or 1)
+            pair_m = max(1 << b for _, b, _ in self.schedule) * s
+            fused = 4 * n_tiles * pair_m <= MAX_PAIR_TABLE_BYTES
+        if fused:
             self.plans = tuple(
                 make_radix_plan(
                     n, shift, bits, method=method, key_value=key_value,

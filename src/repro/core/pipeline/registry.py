@@ -179,6 +179,36 @@ class KernelStages(StageImpl):
         )
 
 
+# Working-set bound for one XLA program of the vmap backend's tile stages.
+# vmapping a per-tile body over ALL tiles materializes its T×m̄ planes for
+# the whole input at once (n·m̄ words: 8 GiB of packed words at n = 2^25,
+# m = 256, more than a v5e chip's HBM), so past this bound the tiles run in
+# chunks through ``lax.map`` — same bodies, same results.
+_VMAP_WORKSET_BYTES = 1 << 30
+
+
+def _over_tiles(spec, *tiled):
+    """``jax.vmap`` over the tile axis, or ``lax.map`` over chunks of tiles
+    when the vmapped working set would pass ``_VMAP_WORKSET_BYTES``. The
+    per-tile estimate is three int32 planes of the local solve's width: the
+    sub-digit stage planes plus the pair rows for a fused pair (DESIGN.md
+    §13), else the m_eff-wide one-hot/packed planes."""
+    n_tiles, t = next(x for x in tiled if x is not None).shape
+    if spec.digit_split is not None:
+        per_tile = 12 * (t * 16 * (spec.segments or 1) + spec.m_eff)
+    else:
+        per_tile = 12 * t * spec.m_eff
+    chunk = _VMAP_WORKSET_BYTES // max(per_tile, 1)
+    if chunk >= n_tiles:
+        return jax.vmap
+
+    def chunked(fn):
+        return lambda *xs: jax.lax.map(
+            lambda a: fn(*a), xs, batch_size=max(1, chunk))
+
+    return chunked
+
+
 class VmapStages(StageImpl):
     """Tiled jnp stages: the SAME fusion as the kernels — local ranks, tile
     starts, tile destination and global destination all from one
@@ -196,7 +226,7 @@ class VmapStages(StageImpl):
     def _tile_ids(spec, keys_tiled, ids_tiled):
         if ids_tiled is not None:
             return ids_tiled
-        return jax.vmap(spec.bucket_fn.emit)(keys_tiled)
+        return jax.vmap(spec.bucket_fn.emit)(keys_tiled)  # n-sized, no planes
 
     @staticmethod
     def _local_offsets(spec, ids, m):
@@ -214,14 +244,15 @@ class VmapStages(StageImpl):
                     sub_bits=spec.sub_bits)
 
     def prescan(self, spec, keys_tiled, ids_tiled, seg_tiled):
+        _tiles = _over_tiles(spec, keys_tiled, ids_tiled)
         m = spec.num_buckets
         if spec.digit_split is not None:         # fused two-digit pair (§13)
             bf, s = spec.bucket_fn, spec.segments or 1
             if seg_tiled is not None:
-                return jax.vmap(lambda k, sg: _st.fused2_tile_counts(
+                return _tiles(lambda k, sg: _st.fused2_tile_counts(
                     k, bf.shift, bf.bits, seg=sg, num_segments=s
                 ))(keys_tiled, seg_tiled)
-            return jax.vmap(lambda k: _st.fused2_tile_counts(
+            return _tiles(lambda k: _st.fused2_tile_counts(
                 k, bf.shift, bf.bits
             ))(keys_tiled)
         ids_tiled = self._tile_ids(spec, keys_tiled, ids_tiled)
@@ -232,25 +263,26 @@ class VmapStages(StageImpl):
                 # same expression the packed postscan evaluates, so XLA CSEs
                 # the two stages under one jit; for counts_only (no postscan
                 # follows) the O(T) scatter-add below stays the cheapest form
-                return jax.vmap(
+                return _tiles(
                     lambda c: _st.packed_tile_local_offsets(c, m_eff)[1]
                 )(cid)
-            return jax.vmap(lambda c: _st.direct_counts(c, m_eff))(cid)
+            return _tiles(lambda c: _st.direct_counts(c, m_eff))(cid)
         if spec.mode == "counts_only":
             # histogram path: an O(T) scatter-add per tile — the O(T·m)
             # one-hot below buys nothing when no postscan follows
-            return jax.vmap(lambda t: _st.direct_counts(t, m))(ids_tiled)
-        return jax.vmap(lambda t: self._local_offsets(spec, t, m)[1])(ids_tiled)
+            return _tiles(lambda t: _st.direct_counts(t, m))(ids_tiled)
+        return _tiles(lambda t: self._local_offsets(spec, t, m)[1])(ids_tiled)
 
     def positions(self, spec, g, keys_tiled, ids_tiled, seg_tiled):
+        _tiles = _over_tiles(spec, keys_tiled, ids_tiled)
         m = spec.num_buckets
         if spec.digit_split is not None:         # fused two-digit pair (§13)
             kw = self._fused2_kw(spec)
             if seg_tiled is not None:
-                return jax.vmap(lambda k, sg, gt: _st.fused2_tile_postscan(
+                return _tiles(lambda k, sg, gt: _st.fused2_tile_postscan(
                     k, gt, None, seg=sg, **kw
                 )[3])(keys_tiled, seg_tiled, g)
-            return jax.vmap(lambda k, gt: _st.fused2_tile_postscan(
+            return _tiles(lambda k, gt: _st.fused2_tile_postscan(
                 k, gt, None, **kw
             )[3])(keys_tiled, g)
         ids_tiled = self._tile_ids(spec, keys_tiled, ids_tiled)
@@ -265,15 +297,16 @@ class VmapStages(StageImpl):
                     local = _st.seg_tile_local(ids, segs, m)
                 return g_tile[cid] + local
 
-            return jax.vmap(one_tile_seg)(ids_tiled, seg_tiled, g)
+            return _tiles(one_tile_seg)(ids_tiled, seg_tiled, g)
 
         def one_tile(ids, g_tile):
             local, _ = self._local_offsets(spec, ids, m)
             return g_tile[ids] + local
 
-        return jax.vmap(one_tile)(ids_tiled, g)
+        return _tiles(one_tile)(ids_tiled, g)
 
     def reorder(self, spec, g, keys_tiled, ids_tiled, vals_tiled, seg_tiled):
+        _tiles = _over_tiles(spec, keys_tiled, ids_tiled)
         m, m_eff = spec.num_buckets, spec.m_eff
         if spec.digit_split is not None:         # fused two-digit pair (§13)
             kw = self._fused2_kw(spec)
@@ -287,17 +320,17 @@ class VmapStages(StageImpl):
                 return keys_r, vals_r, pos_r, perm
 
             if vals_tiled is None:
-                keys_r, pos_r, perm = jax.vmap(
+                keys_r, pos_r, perm = _tiles(
                     lambda k, gt: fused2_tile(k, None, gt, None)
-                )(keys_tiled, g) if seg_tiled is None else jax.vmap(
+                )(keys_tiled, g) if seg_tiled is None else _tiles(
                     lambda k, sg, gt: fused2_tile(k, sg, gt, None)
                 )(keys_tiled, seg_tiled, g)
                 return keys_r, None, pos_r, perm
             if seg_tiled is None:
-                return jax.vmap(
+                return _tiles(
                     lambda k, gt, vt: fused2_tile(k, None, gt, vt)
                 )(keys_tiled, g, vals_tiled)
-            return jax.vmap(fused2_tile)(keys_tiled, seg_tiled, g, vals_tiled)
+            return _tiles(fused2_tile)(keys_tiled, seg_tiled, g, vals_tiled)
         ids_tiled = self._tile_ids(spec, keys_tiled, ids_tiled)
 
         def fused_tile(ids, segs, g_tile, keys_t, vals_t):
@@ -323,20 +356,20 @@ class VmapStages(StageImpl):
 
         if seg_tiled is None:
             if vals_tiled is None:
-                keys_r, pos_r, perm = jax.vmap(
+                keys_r, pos_r, perm = _tiles(
                     lambda i, gt, kt: fused_tile(i, None, gt, kt, None)
                 )(ids_tiled, g, keys_tiled)
                 return keys_r, None, pos_r, perm
-            keys_r, vals_r, pos_r, perm = jax.vmap(
+            keys_r, vals_r, pos_r, perm = _tiles(
                 lambda i, gt, kt, vt: fused_tile(i, None, gt, kt, vt)
             )(ids_tiled, g, keys_tiled, vals_tiled)
             return keys_r, vals_r, pos_r, perm
         if vals_tiled is None:
-            keys_r, pos_r, perm = jax.vmap(
+            keys_r, pos_r, perm = _tiles(
                 lambda i, sg, gt, kt: fused_tile(i, sg, gt, kt, None)
             )(ids_tiled, seg_tiled, g, keys_tiled)
             return keys_r, None, pos_r, perm
-        keys_r, vals_r, pos_r, perm = jax.vmap(fused_tile)(
+        keys_r, vals_r, pos_r, perm = _tiles(fused_tile)(
             ids_tiled, seg_tiled, g, keys_tiled, vals_tiled
         )
         return keys_r, vals_r, pos_r, perm

@@ -42,8 +42,11 @@ from repro.kernels.common import pad_lanes as _pad_lanes
 WMS_TILE = 1024
 BMS_TILE = 4096
 
-# VMEM budget for the heuristic (working set of the fused postscan).
-_VMEM_BUDGET_BYTES = 8 << 20
+# VMEM budget for the heuristic (working set of the fused postscan): the
+# default scoped-VMEM limit of a v5e TensorCore. Kernels are compiled with
+# twice this (kernels/multisplit_tile.py ``VMEM_LIMIT_BYTES``), so a tile the
+# cost models admit always fits what Mosaic allocates.
+_VMEM_BUDGET_BYTES = 16 << 20
 _MIN_TILE = 256
 
 # Kernel families (DESIGN.md §12). The family heuristic switches to packed
@@ -87,6 +90,17 @@ def _tile_key(n: int, m: int, method: str, key_value: bool, backend: str,
     return base + (digits, stage_m or max(1, int(m ** 0.5)))
 
 
+def _kernel_cost_bytes(t: int, m: int) -> int:
+    """Scoped VMEM Mosaic allocates for the compiled fused postscan+reorder
+    kernel of either family, in bytes: an upper bound fitted to the v5e
+    compiler's own figures ("Scoped allocation with size ..." at a 1 MiB
+    limit), e.g. 5.7 MiB at T=512 and 19.4 MiB at T=1024 (dense, m ≤ 256),
+    7.1 MiB at T=512 with m_eff=528 (packed). The T×T planes (triangular
+    scan, permutation matrix, their iota/compare temporaries) cost ~4.5 f32
+    words per T² entry; the T×m̄ one-hot planes ~2 words per entry."""
+    return 18 * t * t + 8 * t * _pad_lanes(m) + 2560 * t
+
+
 def _family_cost_bytes(t: int, m: int, family: str,
                        oblivious: bool = False) -> int:
     """Per-tile working set of the fused postscan kernel, in bytes.
@@ -99,20 +113,19 @@ def _family_cost_bytes(t: int, m: int, family: str,
 
     packed: the (T, ⌈m/k⌉) packed contribution + inclusive-scan planes, the
     small S×m level-2 scan, and ~8 T-vectors — near-flat in m.
-    ``oblivious=True`` (kernel backends, DESIGN.md §15) additionally charges
-    the T×T reorder permutation plane and the T×m one-hot the starts/G
-    picks contract against — the quadratic term pulls the packed tile
-    optimum DOWN on kernel backends, while the vmap gather form keeps its
+    ``oblivious=True`` (kernel backends, DESIGN.md §15) charges what the
+    compiler allocates for the compiled kernel of either family
+    (:func:`_kernel_cost_bytes`): its T×T planes dominate, so both
+    families get the same tile there, while the vmap gather form keeps its
     near-flat profile.
     """
+    if oblivious:
+        return _kernel_cost_bytes(t, m)
     if family == "packed":
         from repro.kernels.common import packed_layout
 
         lay = packed_layout(t, m)
-        base = 4 * (2 * t * lay.w + 3 * lay.n_sub * m + 8 * t)
-        if oblivious:
-            base += 4 * (t * t + 2 * t * m)
-        return base
+        return 4 * (2 * t * lay.w + 3 * lay.n_sub * m + 8 * t)
     m_pad = _pad_lanes(m)
     return 4 * (2 * t * m_pad + 2 * t * t + 8 * t)
 

@@ -39,10 +39,12 @@ makes the two families bitwise-comparable oracles of each other.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jnp.ndarray
 
@@ -74,7 +76,37 @@ def exclusive_starts_mxu(hist: Array) -> Array:
     rows = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
     strict_tril = (rows > cols).astype(jnp.float32)
-    return jax.lax.dot(strict_tril, hist[:, None], precision=jax.lax.Precision.HIGHEST)[:, 0]
+    return matvec_f32(strict_tril, hist)
+
+
+def matvec_f32(mat: Array, v: Array) -> Array:
+    """``mat @ v`` for an (N, K) matrix and a (K,) f32 vector, as ONE
+    lane-dense (1, K) x (N, K)^T MXU contraction giving a (1, N) row. The
+    vector rides as a row, never as a (K, 1) column: Mosaic's column
+    extraction of a narrow matmul result misplaces rows on the chip."""
+    return jax.lax.dot_general(
+        v[None, :], mat, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+    )[0]
+
+
+def matvec_32(mat: Array, x: Array) -> Array:
+    """``mat @ x`` for a 0/1 (N, K) matrix with at most one 1 per row and a
+    (K,) vector of 32-bit words (int32/uint32/float32, bit-exact): the words
+    split into 16-bit halves (f32-exact), both halves ride ONE (2, K) x
+    (N, K)^T contraction as lane-dense rows, and the halves reassemble."""
+    xi = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    which = jax.lax.broadcasted_iota(jnp.int32, (2, xi.shape[0]), 0)
+    halves = jnp.where(which == 0,
+                       _half_to_f32(xi & jnp.uint32(0xFFFF))[None, :],
+                       _half_to_f32(xi >> jnp.uint32(16))[None, :])   # (2, K)
+    moved = jax.lax.dot_general(
+        halves, mat, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+    )                                                       # (2, N)
+    lo = _f32_to_u32(moved[0])
+    hi = _f32_to_u32(moved[1])
+    return jax.lax.bitcast_convert_type(lo | (hi << jnp.uint32(16)), x.dtype)
 
 
 def permutation_matrix(dest: Array) -> Array:
@@ -82,6 +114,59 @@ def permutation_matrix(dest: Array) -> Array:
     t = dest.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     return (rows == dest[None, :]).astype(jnp.float32)
+
+
+def _half_to_f32(u: Array) -> Array:
+    """uint32 half-word (< 2^16) -> f32, through int32: Mosaic lowers no
+    uint32 -> float32 cast, and the value is exact in both."""
+    return u.astype(jnp.int32).astype(jnp.float32)
+
+
+def _f32_to_u32(x: Array) -> Array:
+    """Exact small non-negative f32 -> uint32, through int32 (Mosaic lowers
+    no float32 -> uint32 cast)."""
+    return x.astype(jnp.int32).astype(jnp.uint32)
+
+
+def _word_sum(x: Array, axis: int, cumulative: bool = False) -> Array:
+    """Sum (or inclusive cumsum) of 32-bit words along ``axis``. uint32
+    words are carried as int32: Mosaic reduces no unsigned integers, and
+    two's-complement addition wraps to the same bits."""
+    if x.dtype != jnp.uint32:
+        return jnp.cumsum(x, axis=axis) if cumulative else x.sum(axis=axis)
+    xi = jax.lax.bitcast_convert_type(x, jnp.int32)
+    r = jnp.cumsum(xi, axis=axis) if cumulative else xi.sum(axis=axis)
+    return jax.lax.bitcast_convert_type(r, jnp.uint32)
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "segment"))
+def roll_cumsum(x: Array, axis: int = 0, segment: Optional[int] = None) -> Array:
+    """Inclusive prefix sum of integer words along ``axis``, restarting
+    every ``segment`` entries: a Hillis-Steele scan of ``pltpu.roll``
+    shifts and masked adds, ⌈log2 segment⌉ steps. The kernel form of
+    ``jnp.cumsum``, which Pallas TPU does not lower; integer-exact, and
+    uint32 words wrap through int32 as in :func:`_word_sum`. Jitted:
+    ``pltpu.roll`` has no eager evaluation rule, and inside a kernel the
+    jit inlines."""
+    if x.ndim == 1:
+        return roll_cumsum(x[None, :], axis=1, segment=segment)[0]
+    seg = x.shape[axis] if segment is None else segment
+    unsigned = x.dtype == jnp.uint32
+    xi = jax.lax.bitcast_convert_type(x, jnp.int32) if unsigned else x
+    pos = jax.lax.broadcasted_iota(jnp.int32, xi.shape, axis)
+    if seg != x.shape[axis]:
+        pos = pos % seg
+    d = 1
+    while d < seg:
+        xi = xi + jnp.where(pos >= d, pltpu.roll(xi, d, axis), 0)
+        d *= 2
+    return jax.lax.bitcast_convert_type(xi, jnp.uint32) if unsigned else xi
+
+
+def _cumsum(x: Array, axis: int, oblivious: bool) -> Array:
+    """``jnp.cumsum`` on the gather form, :func:`roll_cumsum` on the
+    oblivious (compiled-kernel) form; bitwise equal."""
+    return roll_cumsum(x, axis) if oblivious else jnp.cumsum(x, axis=axis)
 
 
 def select_columns(rows: Array, col: Array) -> Array:
@@ -92,22 +177,14 @@ def select_columns(rows: Array, col: Array) -> Array:
     t, w = rows.shape
     cols = jax.lax.broadcasted_iota(jnp.int32, (t, w), 1)
     zero = jnp.zeros((), rows.dtype)
-    return jnp.where(cols == col[:, None], rows, zero).sum(axis=1)
+    return _word_sum(jnp.where(cols == col[:, None], rows, zero), axis=1)
 
 
 def pick_row_32(one_hot: Array, row: Array) -> Array:
     """One-hot pick ``row[ids]`` of FULL-RANGE 32-bit entries: the (T, m) f32
     one-hot times the row split into 16-bit halves, one MXU matmul, exact
-    (each half < 2^16 ≤ 2^24; mirrors :func:`permute_matmul_32`)."""
-    ri = jax.lax.bitcast_convert_type(row, jnp.uint32)
-    halves = jnp.stack(
-        [(ri & jnp.uint32(0xFFFF)).astype(jnp.float32),
-         (ri >> jnp.uint32(16)).astype(jnp.float32)], axis=1
-    )                                                       # (m, 2)
-    moved = jax.lax.dot(one_hot, halves, precision=jax.lax.Precision.HIGHEST)
-    lo = moved[:, 0].astype(jnp.uint32)
-    hi = moved[:, 1].astype(jnp.uint32)
-    return jax.lax.bitcast_convert_type(lo | (hi << jnp.uint32(16)), row.dtype)
+    (each half < 2^16 ≤ 2^24; see :func:`matvec_32`)."""
+    return matvec_32(one_hot, row)
 
 
 def rank_plane_pack16(rows: Array) -> Array:
@@ -115,12 +192,37 @@ def rank_plane_pack16(rows: Array) -> Array:
     (S, ceil(m/2)) uint32 LANE-PACKED RANK PLANES: two bucket carries per
     int32 lane, even bucket in the low half-word. Halves the select width
     of the packed family's level-2 carry lookup on the oblivious path."""
-    s, m = rows.shape
-    u = rows.astype(jnp.uint32)
-    if m % 2:
-        u = jnp.concatenate([u, jnp.zeros((s, 1), jnp.uint32)], axis=1)
-    u = u.reshape(s, -1, 2)
-    return u[:, :, 0] | (u[:, :, 1] << jnp.uint32(16))
+    m = rows.shape[1]
+    h = -(-m // 2)
+    b = jax.lax.broadcasted_iota(jnp.int32, (m, h), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (m, h), 1)
+    x = rows.astype(jnp.float32)
+
+    def half(off):
+        # column 2j+off of ``rows`` as column j: a 0/1 selection matmul,
+        # exact for ranks < 2^16 (a lane reshape does not lower in Mosaic)
+        sel = (b == 2 * j + off).astype(jnp.float32)
+        return _f32_to_u32(
+            jax.lax.dot(x, sel, precision=jax.lax.Precision.HIGHEST))
+
+    return half(0) | (half(1) << jnp.uint32(16))
+
+
+def dense_global_positions(one_hot: Array, local: Array, g_row: Array) -> Array:
+    """Paper eq. (2) on the dense path: ``g_row[ids] + local`` as int32.
+    ``g_row`` holds global bucket offsets, which reach ``n`` and so pass
+    2^24 at the paper's sizes: the pick goes through
+    :func:`pick_row_32`'s 16-bit halves and the add is integer, both exact
+    where an f32 pick-and-add would round."""
+    return pick_row_32(one_hot, g_row.astype(jnp.int32)) + local.astype(jnp.int32)
+
+
+def dense_positions_body(ids: Array, g_row: Array, m_pad: int) -> Array:
+    """THE dense DMS postscan (no reorder): global destinations, eq. (2)."""
+    one_hot = one_hot_f32(ids, m_pad)
+    incl = cumsum_mxu(one_hot)
+    local = ((incl - 1.0) * one_hot).sum(axis=1)            # rank within bucket
+    return dense_global_positions(one_hot, local, g_row)
 
 
 def fused_postscan_body(ids, g_row, keys, vals, m_pad: int):
@@ -136,11 +238,8 @@ def fused_postscan_body(ids, g_row, keys, vals, m_pad: int):
     local = ((incl - 1.0) * one_hot).sum(axis=1)            # (T,) in-bucket rank
     hist = incl[t - 1, :]                                   # (m,) tile histogram
     starts = exclusive_starts_mxu(hist)                     # (m,) tile bucket starts
-    pick = lambda row: jax.lax.dot(
-        one_hot, row[:, None], precision=jax.lax.Precision.HIGHEST
-    )[:, 0]
-    dest = (pick(starts) + local).astype(jnp.int32)         # within-tile destination
-    gpos = (pick(g_row.astype(jnp.float32)) + local).astype(jnp.int32)  # eq. (2)
+    dest = (matvec_f32(one_hot, starts) + local).astype(jnp.int32)  # within-tile destination
+    gpos = dense_global_positions(one_hot, local, g_row)    # eq. (2)
     perm = permutation_matrix(dest)
     keys_r = permute_matmul_32(perm, keys)
     pos_r = permute_matmul_32(perm, gpos)
@@ -260,8 +359,26 @@ def packed_encode(ids: Array, layout: PackedLayout) -> Array:
     return jnp.where(cols == q[:, None], unit[:, None], jnp.uint32(0))
 
 
-def packed_unpack(packed_rows: Array, layout: PackedLayout) -> Array:
-    """(R, w) uint32 packed counters -> (R, m_eff) int32 counts."""
+def packed_unpack(
+    packed_rows: Array, layout: PackedLayout, oblivious: bool = False
+) -> Array:
+    """(R, w) uint32 packed counters -> (R, m_eff) int32 counts.
+
+    ``oblivious=True`` (the compiled kernel form) spreads each counter lane
+    to its bucket column with one 0/1 expansion matmul per lane (counts
+    < 2^16, f32-exact) instead of a (w, k) -> w·k lane reshape, which
+    Mosaic does not lower."""
+    if oblivious:
+        j = jax.lax.broadcasted_iota(jnp.int32, (layout.w, layout.m_eff), 0)
+        b = jax.lax.broadcasted_iota(jnp.int32, (layout.w, layout.m_eff), 1)
+        counts = None
+        for lane in range(layout.k):
+            c = _half_to_f32(
+                (packed_rows >> jnp.uint32(layout.bits * lane)) & layout.lane_mask)
+            spread = (b == j * layout.k + lane).astype(jnp.float32)
+            part = jax.lax.dot(c, spread, precision=jax.lax.Precision.HIGHEST)
+            counts = part if counts is None else counts + part
+        return counts.astype(jnp.int32)
     shifts = jnp.uint32(layout.bits) * jnp.arange(layout.k, dtype=jnp.uint32)
     lanes = (packed_rows[:, :, None] >> shifts[None, None, :]) & layout.lane_mask
     return lanes.reshape(packed_rows.shape[0], layout.w * layout.k)[
@@ -285,19 +402,21 @@ def _packed_state(ids: Array, layout: PackedLayout, oblivious: bool = False):
     contrib = packed_encode(ids, layout)
     # level 1: inclusive scan of packed words inside each subtile — counts
     # stay <= subtile <= 2^bits - 1, so lanes never carry into each other.
-    incl3 = jnp.cumsum(
-        contrib.reshape(layout.n_sub, layout.subtile, layout.w), axis=1
-    )
-    incl = incl3.reshape(t_pad, layout.w)
+    contrib3 = contrib.reshape(layout.n_sub, layout.subtile, layout.w)
     if oblivious:
+        incl = roll_cumsum(contrib, 0, segment=layout.subtile)
         word = select_columns(incl, q)
+        sub_tot = _word_sum(contrib3, axis=1)   # == the scan's last rows
     else:
+        incl3 = _word_sum(contrib3, axis=1, cumulative=True)
+        incl = incl3.reshape(t_pad, layout.w)
         word = jnp.take_along_axis(incl, q[:, None], axis=1)[:, 0]
+        sub_tot = incl3[:, -1, :]
     rank_incl = ((word >> shift) & layout.lane_mask).astype(jnp.int32)
     # level 2: unpack ONLY the S subtile totals and scan those — S*m work
     # instead of the dense family's T*m.
-    sub_hist = packed_unpack(incl3[:, -1, :], layout)       # (S, m_eff)
-    excl_sub = jnp.cumsum(sub_hist, axis=0) - sub_hist
+    sub_hist = packed_unpack(sub_tot, layout, oblivious)    # (S, m_eff)
+    excl_sub = _cumsum(sub_hist, 0, oblivious) - sub_hist
     return rank_incl, sub_hist, excl_sub
 
 
@@ -360,10 +479,10 @@ def packed_counts(ids: Array, layout: PackedLayout) -> Array:
     t = ids.shape[0]
     ids, n_pad = _packed_pad_ids(ids, layout)
     contrib = packed_encode(ids, layout)
-    sub_tot = contrib.reshape(layout.n_sub, layout.subtile, layout.w).sum(
-        axis=1, dtype=jnp.uint32
+    sub_tot = _word_sum(
+        contrib.reshape(layout.n_sub, layout.subtile, layout.w), axis=1
     )
-    hist = packed_unpack(sub_tot, layout).sum(axis=0)
+    hist = packed_unpack(sub_tot, layout, oblivious=True).sum(axis=0)
     hist = _drop_pad_count(hist, layout.m_eff, n_pad)
     return hist.astype(jnp.int32)
 
@@ -391,7 +510,7 @@ def packed_postscan_body(
     32-bit globals) and reorders through permutation matmuls — every step a
     select or an MXU contraction, nothing Mosaic refuses to lower."""
     local, hist = packed_local_offsets(ids, layout, oblivious=oblivious)
-    starts = (jnp.cumsum(hist) - hist).astype(jnp.int32)
+    starts = (_cumsum(hist, 0, oblivious) - hist).astype(jnp.int32)
     if oblivious:
         oh = one_hot_f32(ids, layout.m_eff)
         dest = (pick_row_32(oh, starts) + local).astype(jnp.int32)
@@ -460,7 +579,7 @@ def _dense_local_offsets(
     one-hot product instead of ``take_along_axis`` (same int32 math)."""
     t = ids.shape[0]
     one_hot = (ids[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :]).astype(jnp.int32)
-    incl = jnp.cumsum(one_hot, axis=0)
+    incl = _cumsum(one_hot, 0, oblivious)
     if oblivious:
         local = (incl * one_hot).sum(axis=1) - 1
     else:
@@ -505,17 +624,46 @@ def fused2_counts_body(
     planes stay T×√m² each and the counts (< 2^24) are f32-exact. Both are
     order-invariant, hence computed on the UN-reordered tile; bitwise equal
     to the histogram the postscan body derives from its cell counts."""
+    if oblivious:
+        return fused2_counts_2d(keys, shift, bits, seg, num_segments).reshape(-1)
     m2 = 1 << bits
     u = keys.astype(jnp.uint32)
     pair = ((u >> jnp.uint32(shift)) & jnp.uint32(m2 - 1)).astype(jnp.int32)
     cg = pair if seg is None else seg * m2 + pair
-    if not oblivious:
-        return jnp.zeros((m2 * num_segments,), jnp.int32).at[cg].add(1)
+    return jnp.zeros((m2 * num_segments,), jnp.int32).at[cg].add(1)
+
+
+def _hist2d(cg: Array, bits: int, num_segments: int):
+    """(row one-hot, column mask, (R, C) int32 cell histogram) of the
+    combined cell ids ``cg`` in the :func:`_pair_hist2d_shape` factoring."""
     col_bits, n_rows, n_cols = _pair_hist2d_shape(bits, num_segments)
+    col = (cg & (n_cols - 1)).astype(jnp.int32)
     oh_r = one_hot_f32((cg >> col_bits).astype(jnp.int32), n_rows)
-    oh_c = one_hot_f32((cg & (n_cols - 1)).astype(jnp.int32), n_cols)
+    oh_c = one_hot_f32(col, n_cols)
     hist2d = jax.lax.dot(oh_r.T, oh_c, precision=jax.lax.Precision.HIGHEST)
-    return hist2d.reshape(-1).astype(jnp.int32)
+    return oh_r, oh_c > 0, hist2d.astype(jnp.int32)
+
+
+def fused2_counts_2d(
+    keys: Array, shift: int, bits: int, seg: Optional[Array] = None,
+    num_segments: int = 1,
+) -> Array:
+    """The oblivious fused2 prescan as its (R, C) factored table — the
+    row-major view of :func:`fused2_counts_body`. Kernels store it 2-D: a
+    2-D to 1-D reshape of an m²-wide table does not lower in Mosaic."""
+    m2 = 1 << bits
+    u = keys.astype(jnp.uint32)
+    pair = ((u >> jnp.uint32(shift)) & jnp.uint32(m2 - 1)).astype(jnp.int32)
+    cg = pair if seg is None else seg * m2 + pair
+    return _hist2d(cg, bits, num_segments)[2]
+
+
+def _exclusive_scan_2d(h: Array) -> Array:
+    """Exclusive prefix sum of an (R, C) table in row-major order, kept
+    2-D: the in-row exclusive scan plus the exclusive scan of row totals."""
+    in_row = roll_cumsum(h, axis=1) - h
+    totals = h.sum(axis=1, keepdims=True)
+    return in_row + (roll_cumsum(totals, axis=0) - totals)
 
 
 def fused2_postscan_body(
@@ -572,7 +720,7 @@ def fused2_postscan_body(
 
     def _stage(d, m, keys2, idx2, seg2):
         local, hist = _fused2_stage_local(d, m, family, oblivious=oblivious)
-        starts = (jnp.cumsum(hist) - hist).astype(jnp.int32)
+        starts = (_cumsum(hist, 0, oblivious) - hist).astype(jnp.int32)
         if oblivious:
             starts_d = select_columns(jnp.broadcast_to(starts[None, :], (t, m)), d)
             perm = permutation_matrix(starts_d + local)
@@ -604,35 +752,28 @@ def fused2_postscan_body(
              & jnp.uint32(m2 - 1)).astype(jnp.int32)
     if oblivious:
         cg2 = pair2 if seg is None else seg2 * m2 + pair2
-        col_bits, n_rows, n_cols = _pair_hist2d_shape(bits, num_segments)
-        row2 = (cg2 >> col_bits).astype(jnp.int32)
-        col2 = (cg2 & (n_cols - 1)).astype(jnp.int32)
-        oh_r = one_hot_f32(row2, n_rows)                    # (T, R)
-        oh_c = one_hot_f32(col2, n_cols)                    # (T, C)
-        hist2d = jax.lax.dot(oh_r.T, oh_c, precision=jax.lax.Precision.HIGHEST)
-        hist_c = hist2d.reshape(-1).astype(jnp.int32)
-        starts_t = (jnp.cumsum(hist_c) - hist_c).astype(jnp.int32)
+        _, n_rows, n_cols = _pair_hist2d_shape(bits, num_segments)
+        oh_r, col_mask, hist2d = _hist2d(cg2, bits, num_segments)  # (T,R),(T,C),(R,C)
+        starts_t = _exclusive_scan_2d(hist2d)
+        # kernels hand G over as its (R, C) table (see fused2_counts_2d)
+        g2d = g_row if g_row.ndim == 2 else g_row.reshape(n_rows, n_cols)
 
-        col_iota = jax.lax.broadcasted_iota(jnp.int32, (t, n_cols), 1)
-        col_mask = (col_iota == col2[:, None])
-
-        def _pick2d(flat_vals):
-            # flat_vals[cg2] without a gather: one-hot row matmul brings the
-            # element's (C,) row slice in, a masked column select finishes;
-            # 16-bit halves keep full 32-bit values f32-exact.
-            u = jax.lax.bitcast_convert_type(
-                flat_vals.reshape(n_rows, n_cols), jnp.uint32)
-            lo = jax.lax.dot(oh_r, (u & jnp.uint32(0xFFFF)).astype(jnp.float32),
+        def _pick2d(table):
+            # table[cg2] of an (R, C) table without a gather: one-hot row
+            # matmul brings the element's (C,) row slice in, a masked column
+            # select finishes; 16-bit halves keep full 32-bit values exact.
+            u = jax.lax.bitcast_convert_type(table, jnp.uint32)
+            lo = jax.lax.dot(oh_r, _half_to_f32(u & jnp.uint32(0xFFFF)),
                              precision=jax.lax.Precision.HIGHEST)
-            hi = jax.lax.dot(oh_r, (u >> jnp.uint32(16)).astype(jnp.float32),
+            hi = jax.lax.dot(oh_r, _half_to_f32(u >> jnp.uint32(16)),
                              precision=jax.lax.Precision.HIGHEST)
-            lo_s = jnp.where(col_mask, lo, 0.0).sum(axis=1).astype(jnp.uint32)
-            hi_s = jnp.where(col_mask, hi, 0.0).sum(axis=1).astype(jnp.uint32)
+            lo_s = _f32_to_u32(jnp.where(col_mask, lo, 0.0).sum(axis=1))
+            hi_s = _f32_to_u32(jnp.where(col_mask, hi, 0.0).sum(axis=1))
             return jax.lax.bitcast_convert_type(
                 lo_s | (hi_s << jnp.uint32(16)), jnp.int32)
 
         local_c = idx - _pick2d(starts_t)
-        gpos2 = (_pick2d(g_row.astype(jnp.int32)) + local_c).astype(jnp.int32)
+        gpos2 = (_pick2d(g2d.astype(jnp.int32)) + local_c).astype(jnp.int32)
         q = permutation_matrix(idx2)         # q[j, i] = (idx2_i == j)
         vals_r = permute_matmul_32(q.T, vals) if vals is not None else None
         gpos = permute_matmul_32(q, gpos2)                  # element-ordered perm
@@ -688,11 +829,14 @@ def fused2_vmem_bytes(
     words, so the pair only profits when L is small).
 
     ``oblivious=True`` (the kernel backends' compiled-lowerable bodies)
-    additionally charges the T×T permutation planes (one per in-flight
-    stage reorder plus the tracked source permutation) and the two-level
-    one-hot / pick planes (T×(R + 2C) f32, R·C = m²·s) — the quadratic term
-    dominates and pulls the fused2 tile optimum DOWN on kernel backends,
-    the opposite shift of the gather form (DESIGN.md §15)."""
+    additionally charges the T×T permutation planes (the stage reorders,
+    the tracked source permutation and their iota/compare temporaries: ~7
+    f32 words per T² entry, fitted to the v5e compiler's scoped-VMEM
+    figures of 2.6 MiB at T=256 and 8.0 MiB at T=512 for a 16-bit pair) and
+    the two-level one-hot / pick planes (T×(R + 2C) f32, R·C = m²·s) — the
+    quadratic term dominates and pulls the fused2 tile optimum DOWN on
+    kernel backends, the opposite shift of the gather form (DESIGN.md
+    §15)."""
     m_hi = m_lo if m_hi is None else m_hi
     m2 = m_lo * m_hi
     stage_w = max(min(1 << (sub_bits or _FUSED2_SUB_BITS), max(m_lo, m_hi)),
@@ -709,23 +853,11 @@ def fused2_vmem_bytes(
     if oblivious:
         bits = max(1, (m2 - 1).bit_length())
         _, n_rows, n_cols = _pair_hist2d_shape(bits, num_segments)
-        total += 4 * (2 * tile * tile + tile * (n_rows + 2 * n_cols))
+        total += 4 * (7 * tile * tile + tile * (n_rows + 2 * n_cols))
     return total
 
 
 def permute_matmul_32(perm: Array, x: Array) -> Array:
-    """Permute a (T,) vector of 32-bit words by the (T, T) matrix ``perm``.
-
-    Bitcasts to uint32 (exact for int32/uint32/float32 payloads), splits into
-    16-bit halves so the fp32 MXU accumulation is exact, permutes both halves
-    in one matmul, and reassembles.
-    """
-    xi = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    halves = jnp.stack(
-        [(xi & jnp.uint32(0xFFFF)).astype(jnp.float32),
-         (xi >> jnp.uint32(16)).astype(jnp.float32)], axis=1
-    )                                                       # (T, 2)
-    moved = jax.lax.dot(perm, halves, precision=jax.lax.Precision.HIGHEST)
-    lo = moved[:, 0].astype(jnp.uint32)
-    hi = moved[:, 1].astype(jnp.uint32)
-    return jax.lax.bitcast_convert_type(lo | (hi << jnp.uint32(16)), x.dtype)
+    """Permute a (T,) vector of 32-bit words by the (T, T) matrix ``perm``,
+    exactly (int32/uint32/float32 payloads; see :func:`matvec_32`)."""
+    return matvec_32(perm, x)

@@ -31,10 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.4.x exposes the stable aliases here
-    from jax.extend import core as _core
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as _core  # type: ignore
+from jax.extend import core as _core
 
 from repro.core.identifiers import BitfieldSpec, EvenSpec, RangeSpec
 from repro.kernels import multisplit_tile as _mst

@@ -60,10 +60,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
     cumsum_mxu as _cumsum_mxu,
+    dense_positions_body,
     exclusive_starts_mxu,
+    _pair_hist2d_shape,
+    fused2_counts_2d,
     fused2_counts_body,
     fused2_positions_body,
     fused2_postscan_body,
@@ -80,6 +84,69 @@ from repro.kernels.common import (
 
 Array = jnp.ndarray
 
+# Scoped-VMEM limit handed to Mosaic for every tile kernel: twice the tile
+# heuristic's budget (core/pipeline/tiles.py ``_VMEM_BUDGET_BYTES``, whose
+# cost models bound what the compiler allocates), so every heuristic tile
+# compiles with headroom; a v5e TensorCore has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 32 << 20
+
+
+def _blocked(shape):
+    """``(L, w)`` rows travel as ``(L, 1, w)``; ``(L, a, b)`` tables as is."""
+    return tuple(shape) if len(shape) == 3 else (shape[0], 1, shape[1])
+
+
+def _tiled_call(kernel, args, out_shape, *, interpret: bool):
+    """ONE grid step per tile over ``(L, w)`` row arrays (or ``(L, a, b)``
+    per-tile tables).
+
+    Each row array reaches the kernel as an ``(L, 1, w)`` array in
+    ``(None, 1, w)`` blocks: the block's last two dims then equal the
+    array's, which is the layout Mosaic accepts for any ``L`` (a ``(1, w)``
+    block of an ``(L, w)`` array is refused for ``L > 1``), and the reshape
+    is a free bitcast of the row-major data. Kernels index ``ref[0, :]``
+    for rows and ``ref[...]`` for tables. ``out_shape`` lists structs of
+    either rank; the outputs come back as a list in those shapes."""
+    n_tiles = args[0].shape[0]
+
+    def spec(shape):
+        return pl.BlockSpec((None,) + shape[1:], lambda i: (i, 0, 0))
+
+    outs = pl.pallas_call(
+        kernel,
+        grid=(n_tiles,),
+        in_specs=[spec(_blocked(a.shape)) for a in args],
+        out_specs=[spec(_blocked(o.shape)) for o in out_shape],
+        out_shape=[jax.ShapeDtypeStruct(_blocked(o.shape), o.dtype)
+                   for o in out_shape],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(*[a.reshape(_blocked(a.shape)) for a in args])
+    return [o.reshape(s.shape) for o, s in zip(outs, out_shape)]
+
+
+def _rows(n_tiles: int, width: int, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct((n_tiles, width), dtype)
+
+
+def _reorder_call(kernel, args, key_dtype, val_dtype, t: int, *, interpret):
+    """Run a fused postscan+reorder kernel: outputs (keys_r, vals_r?, pos_r,
+    perm), each ``(L, t)``; ``vals_r`` is None when ``val_dtype`` is."""
+    n_tiles = args[0].shape[0]
+    out_shape = [_rows(n_tiles, t, key_dtype)]
+    if val_dtype is not None:
+        out_shape.append(_rows(n_tiles, t, val_dtype))
+    out_shape += [_rows(n_tiles, t), _rows(n_tiles, t)]
+    out = _tiled_call(kernel, args, out_shape, interpret=interpret)
+    if val_dtype is not None:
+        return tuple(out)
+    keys_r, pos_r, perm = out
+    return keys_r, None, pos_r, perm
+
+
+def _pad_g(g: Array, width: int, m_pad: int) -> Array:
+    return jnp.zeros((g.shape[0], m_pad), g.dtype).at[:, :width].set(g)
+
 
 # ---------------------------------------------------------------------------
 # Kernel 1: per-tile histograms (the prescan direct solve)
@@ -93,16 +160,11 @@ def _histogram_kernel(ids_ref, hist_ref, *, m_pad: int):
 
 def tile_histograms_pallas(ids_tiled: Array, num_buckets: int, *, interpret: bool = True) -> Array:
     """(L, T) int32 ids -> (L, m) int32 histograms."""
-    n_tiles, t = ids_tiled.shape
     m_pad = _pad_lanes(num_buckets)
-    out = pl.pallas_call(
-        functools.partial(_histogram_kernel, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, t), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, m_pad), jnp.int32),
-        interpret=interpret,
-    )(ids_tiled)
+    (out,) = _tiled_call(
+        functools.partial(_histogram_kernel, m_pad=m_pad), [ids_tiled],
+        [_rows(ids_tiled.shape[0], m_pad)], interpret=interpret,
+    )
     return out[:, :num_buckets]
 
 
@@ -111,33 +173,20 @@ def tile_histograms_pallas(ids_tiled: Array, num_buckets: int, *, interpret: boo
 # ---------------------------------------------------------------------------
 
 def _positions_kernel(ids_ref, g_ref, pos_ref, *, m_pad: int):
-    ids = ids_ref[0, :]
-    g = g_ref[0, :].astype(jnp.float32)
-    one_hot = _one_hot(ids, m_pad)
-    incl = _cumsum_mxu(one_hot)
-    local = ((incl - 1.0) * one_hot).sum(axis=1)          # rank within bucket
-    base = jax.lax.dot(one_hot, g[:, None], precision=jax.lax.Precision.HIGHEST)[:, 0]
-    pos_ref[0, :] = (base + local).astype(jnp.int32)
+    pos_ref[0, :] = dense_positions_body(ids_ref[0, :], g_ref[0, :], m_pad)
 
 
 def tile_positions_pallas(
     ids_tiled: Array, g: Array, num_buckets: int, *, interpret: bool = True
 ) -> Array:
     """(L, T) ids + (L, m) bases -> (L, T) destinations (paper eq. (2))."""
-    n_tiles, t = ids_tiled.shape
     m_pad = _pad_lanes(num_buckets)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :num_buckets].set(g)
-    return pl.pallas_call(
+    (pos,) = _tiled_call(
         functools.partial(_positions_kernel, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
+        [ids_tiled, _pad_g(g, num_buckets, m_pad)], [_rows(*ids_tiled.shape)],
         interpret=interpret,
-    )(ids_tiled, g_pad)
+    )
+    return pos
 
 
 # ---------------------------------------------------------------------------
@@ -184,34 +233,15 @@ def fused_postscan_reorder_pallas(
     ``perm[l, i]`` is the global destination of INPUT element i (eq. (2)) —
     a free byproduct of the same one-hot/cumsum evaluation.
     """
-    n_tiles, t = ids_tiled.shape
     m_pad = _pad_lanes(num_buckets)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :num_buckets].set(g)
     has_values = values_tiled is not None
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    in_specs = [row, pl.BlockSpec((1, m_pad), lambda i: (i, 0)), row] + ([row] if has_values else [])
-    out_specs = [row] * (4 if has_values else 3)
-    out_shape = [jax.ShapeDtypeStruct((n_tiles, t), keys_tiled.dtype)]
-    if has_values:
-        out_shape.append(jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype))
-    out_shape += [
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-    ]
-    args = (ids_tiled, g_pad, keys_tiled) + ((values_tiled,) if has_values else ())
-    out = pl.pallas_call(
+    args = [ids_tiled, _pad_g(g, num_buckets, m_pad), keys_tiled] + (
+        [values_tiled] if has_values else [])
+    return _reorder_call(
         functools.partial(_fused_postscan_kernel, m_pad=m_pad, has_values=has_values),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if has_values:
-        keys_r, vals_r, pos_r, perm = out
-        return keys_r, vals_r, pos_r, perm
-    keys_r, pos_r, perm = out
-    return keys_r, None, pos_r, perm
+        args, keys_tiled.dtype, values_tiled.dtype if has_values else None,
+        ids_tiled.shape[1], interpret=interpret,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -229,31 +259,19 @@ def seg_tile_histograms_pallas(
     *, interpret: bool = True,
 ) -> Array:
     """(L, T) bucket ids + (L, T) segment ids -> (L, s*m) combined histograms."""
-    n_tiles, t = ids_tiled.shape
     m_eff = num_buckets * num_segments
     m_pad = _pad_lanes(m_eff)
-    out = pl.pallas_call(
+    (out,) = _tiled_call(
         functools.partial(_seg_histogram_kernel, m=num_buckets, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, m_pad), jnp.int32),
+        [ids_tiled, seg_tiled], [_rows(ids_tiled.shape[0], m_pad)],
         interpret=interpret,
-    )(ids_tiled, seg_tiled)
+    )
     return out[:, :m_eff]
 
 
 def _seg_positions_kernel(ids_ref, seg_ref, g_ref, pos_ref, *, m: int, m_pad: int):
     cid = ids_ref[0, :] + seg_ref[0, :] * m
-    g = g_ref[0, :].astype(jnp.float32)
-    one_hot = _one_hot(cid, m_pad)
-    incl = _cumsum_mxu(one_hot)
-    local = ((incl - 1.0) * one_hot).sum(axis=1)
-    base = jax.lax.dot(one_hot, g[:, None], precision=jax.lax.Precision.HIGHEST)[:, 0]
-    pos_ref[0, :] = (base + local).astype(jnp.int32)
+    pos_ref[0, :] = dense_positions_body(cid, g_ref[0, :], m_pad)
 
 
 def seg_tile_positions_pallas(
@@ -261,22 +279,14 @@ def seg_tile_positions_pallas(
     *, interpret: bool = True,
 ) -> Array:
     """Segmented DMS postscan: combined (seg, bucket) destinations, eq. (2)."""
-    n_tiles, t = ids_tiled.shape
     m_eff = num_buckets * num_segments
     m_pad = _pad_lanes(m_eff)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :m_eff].set(g)
-    return pl.pallas_call(
+    (pos,) = _tiled_call(
         functools.partial(_seg_positions_kernel, m=num_buckets, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        interpret=interpret,
-    )(ids_tiled, seg_tiled, g_pad)
+        [ids_tiled, seg_tiled, _pad_g(g, m_eff, m_pad)],
+        [_rows(*ids_tiled.shape)], interpret=interpret,
+    )
+    return pos
 
 
 def _seg_fused_postscan_kernel(*refs, m: int, m_pad: int, has_values: bool):
@@ -315,41 +325,18 @@ def seg_fused_postscan_reorder_pallas(
     reorder + global destinations from ONE one-hot/cumsum evaluation over the
     combined id. Output contract matches :func:`fused_postscan_reorder_pallas`
     with the bucket axis widened to ``s*m``."""
-    n_tiles, t = ids_tiled.shape
     m_eff = num_buckets * num_segments
     m_pad = _pad_lanes(m_eff)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :m_eff].set(g)
     has_values = values_tiled is not None
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    in_specs = [row, row, pl.BlockSpec((1, m_pad), lambda i: (i, 0)), row] + (
-        [row] if has_values else []
-    )
-    out_specs = [row] * (4 if has_values else 3)
-    out_shape = [jax.ShapeDtypeStruct((n_tiles, t), keys_tiled.dtype)]
-    if has_values:
-        out_shape.append(jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype))
-    out_shape += [
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-    ]
-    args = (ids_tiled, seg_tiled, g_pad, keys_tiled) + (
-        (values_tiled,) if has_values else ()
-    )
-    out = pl.pallas_call(
+    args = [ids_tiled, seg_tiled, _pad_g(g, m_eff, m_pad), keys_tiled] + (
+        [values_tiled] if has_values else [])
+    return _reorder_call(
         functools.partial(
             _seg_fused_postscan_kernel, m=num_buckets, m_pad=m_pad, has_values=has_values
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if has_values:
-        keys_r, vals_r, pos_r, perm = out
-        return keys_r, vals_r, pos_r, perm
-    keys_r, pos_r, perm = out
-    return keys_r, None, pos_r, perm
+        args, keys_tiled.dtype, values_tiled.dtype if has_values else None,
+        ids_tiled.shape[1], interpret=interpret,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -369,49 +356,32 @@ def spec_tile_histograms_pallas(
     keys_tiled: Array, spec, *, interpret: bool = True
 ) -> Array:
     """(L, T) keys -> (L, m) per-tile histograms; labels fused in-kernel."""
-    n_tiles, t = keys_tiled.shape
     m = spec.num_buckets
     m_pad = _pad_lanes(m)
-    out = pl.pallas_call(
+    (out,) = _tiled_call(
         functools.partial(_spec_hist_kernel, spec=spec, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, t), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, m_pad), jnp.int32),
-        interpret=interpret,
-    )(keys_tiled)
+        [keys_tiled], [_rows(keys_tiled.shape[0], m_pad)], interpret=interpret,
+    )
     return out[:, :m]
 
 
 def _spec_positions_kernel(keys_ref, g_ref, pos_ref, *, spec, m_pad: int):
     ids = spec.emit_in_kernel(keys_ref[0, :])
-    g = g_ref[0, :].astype(jnp.float32)
-    one_hot = _one_hot(ids, m_pad)
-    incl = _cumsum_mxu(one_hot)
-    local = ((incl - 1.0) * one_hot).sum(axis=1)
-    base = jax.lax.dot(one_hot, g[:, None], precision=jax.lax.Precision.HIGHEST)[:, 0]
-    pos_ref[0, :] = (base + local).astype(jnp.int32)
+    pos_ref[0, :] = dense_positions_body(ids, g_ref[0, :], m_pad)
 
 
 def spec_tile_positions_pallas(
     keys_tiled: Array, g: Array, spec, *, interpret: bool = True
 ) -> Array:
     """Fused-label DMS postscan: (L, T) keys + (L, m) bases -> (L, T) dests."""
-    n_tiles, t = keys_tiled.shape
     m = spec.num_buckets
     m_pad = _pad_lanes(m)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :m].set(g)
-    return pl.pallas_call(
+    (pos,) = _tiled_call(
         functools.partial(_spec_positions_kernel, spec=spec, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
+        [keys_tiled, _pad_g(g, m, m_pad)], [_rows(*keys_tiled.shape)],
         interpret=interpret,
-    )(keys_tiled, g_pad)
+    )
+    return pos
 
 
 def _spec_ids_kernel(keys_ref, ids_ref, *, spec):
@@ -425,16 +395,11 @@ def spec_bucket_ids_pallas(
     evaluated per tile. The generic materialized-label entry point — any
     declarative BucketSpec, same plan/tile machinery as every other kernel
     (replaces the seed-era fixed-even-spec kernel in histogram_tile.py)."""
-    n_tiles, t = keys_tiled.shape
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_spec_ids_kernel, spec=spec),
-        grid=(n_tiles,),
-        in_specs=[row],
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        interpret=interpret,
-    )(keys_tiled)
+    (ids,) = _tiled_call(
+        functools.partial(_spec_ids_kernel, spec=spec), [keys_tiled],
+        [_rows(*keys_tiled.shape)], interpret=interpret,
+    )
+    return ids
 
 
 def _spec_fused_postscan_kernel(*refs, spec, m_pad: int, has_values: bool):
@@ -468,37 +433,17 @@ def spec_fused_postscan_reorder_pallas(
     """Fused-label WMS/BMS postscan: contract of
     :func:`fused_postscan_reorder_pallas` with the label strip replaced by
     in-kernel ``spec.emit`` evaluation."""
-    n_tiles, t = keys_tiled.shape
     m = spec.num_buckets
     m_pad = _pad_lanes(m)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :m].set(g)
     has_values = values_tiled is not None
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    in_specs = [row, pl.BlockSpec((1, m_pad), lambda i: (i, 0))] + ([row] if has_values else [])
-    out_specs = [row] * (4 if has_values else 3)
-    out_shape = [jax.ShapeDtypeStruct((n_tiles, t), keys_tiled.dtype)]
-    if has_values:
-        out_shape.append(jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype))
-    out_shape += [
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-    ]
-    args = (keys_tiled, g_pad) + ((values_tiled,) if has_values else ())
-    out = pl.pallas_call(
+    args = [keys_tiled, _pad_g(g, m, m_pad)] + ([values_tiled] if has_values else [])
+    return _reorder_call(
         functools.partial(
             _spec_fused_postscan_kernel, spec=spec, m_pad=m_pad, has_values=has_values
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if has_values:
-        keys_r, vals_r, pos_r, perm = out
-        return keys_r, vals_r, pos_r, perm
-    keys_r, pos_r, perm = out
-    return keys_r, None, pos_r, perm
+        args, keys_tiled.dtype, values_tiled.dtype if has_values else None,
+        keys_tiled.shape[1], interpret=interpret,
+    )
 
 
 # -- segmented fused-label kernels: cid = seg*m + spec.emit(keys), both parts
@@ -514,31 +459,19 @@ def seg_spec_tile_histograms_pallas(
     *, interpret: bool = True,
 ) -> Array:
     """(L, T) keys + (L, T) segment ids -> (L, s*m) combined histograms."""
-    n_tiles, t = keys_tiled.shape
     m_eff = spec.num_buckets * num_segments
     m_pad = _pad_lanes(m_eff)
-    out = pl.pallas_call(
+    (out,) = _tiled_call(
         functools.partial(_seg_spec_hist_kernel, spec=spec, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, m_pad), jnp.int32),
+        [keys_tiled, seg_tiled], [_rows(keys_tiled.shape[0], m_pad)],
         interpret=interpret,
-    )(keys_tiled, seg_tiled)
+    )
     return out[:, :m_eff]
 
 
 def _seg_spec_positions_kernel(keys_ref, seg_ref, g_ref, pos_ref, *, spec, m_pad: int):
     cid = spec.emit_in_kernel(keys_ref[0, :]) + seg_ref[0, :] * spec.num_buckets
-    g = g_ref[0, :].astype(jnp.float32)
-    one_hot = _one_hot(cid, m_pad)
-    incl = _cumsum_mxu(one_hot)
-    local = ((incl - 1.0) * one_hot).sum(axis=1)
-    base = jax.lax.dot(one_hot, g[:, None], precision=jax.lax.Precision.HIGHEST)[:, 0]
-    pos_ref[0, :] = (base + local).astype(jnp.int32)
+    pos_ref[0, :] = dense_positions_body(cid, g_ref[0, :], m_pad)
 
 
 def seg_spec_tile_positions_pallas(
@@ -546,22 +479,14 @@ def seg_spec_tile_positions_pallas(
     *, interpret: bool = True,
 ) -> Array:
     """Segmented fused-label DMS postscan: (seg, bucket) dests, eq. (2)."""
-    n_tiles, t = keys_tiled.shape
     m_eff = spec.num_buckets * num_segments
     m_pad = _pad_lanes(m_eff)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :m_eff].set(g)
-    return pl.pallas_call(
+    (pos,) = _tiled_call(
         functools.partial(_seg_spec_positions_kernel, spec=spec, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        interpret=interpret,
-    )(keys_tiled, seg_tiled, g_pad)
+        [keys_tiled, seg_tiled, _pad_g(g, m_eff, m_pad)],
+        [_rows(*keys_tiled.shape)], interpret=interpret,
+    )
+    return pos
 
 
 def _seg_spec_fused_postscan_kernel(*refs, spec, m_pad: int, has_values: bool):
@@ -596,40 +521,19 @@ def seg_spec_fused_postscan_reorder_pallas(
 ) -> Tuple[Array, Optional[Array], Array, Array]:
     """Segmented fused-label postscan+reorder: contract of
     :func:`seg_fused_postscan_reorder_pallas` with in-kernel labels."""
-    n_tiles, t = keys_tiled.shape
     m_eff = spec.num_buckets * num_segments
     m_pad = _pad_lanes(m_eff)
-    g_pad = jnp.zeros((n_tiles, m_pad), g.dtype).at[:, :m_eff].set(g)
     has_values = values_tiled is not None
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    in_specs = [row, row, pl.BlockSpec((1, m_pad), lambda i: (i, 0))] + (
-        [row] if has_values else []
-    )
-    out_specs = [row] * (4 if has_values else 3)
-    out_shape = [jax.ShapeDtypeStruct((n_tiles, t), keys_tiled.dtype)]
-    if has_values:
-        out_shape.append(jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype))
-    out_shape += [
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-    ]
-    args = (keys_tiled, seg_tiled, g_pad) + ((values_tiled,) if has_values else ())
-    out = pl.pallas_call(
+    args = [keys_tiled, seg_tiled, _pad_g(g, m_eff, m_pad)] + (
+        [values_tiled] if has_values else [])
+    return _reorder_call(
         functools.partial(
             _seg_spec_fused_postscan_kernel, spec=spec, m_pad=m_pad,
             has_values=has_values,
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if has_values:
-        keys_r, vals_r, pos_r, perm = out
-        return keys_r, vals_r, pos_r, perm
-    keys_r, pos_r, perm = out
-    return keys_r, None, pos_r, perm
+        args, keys_tiled.dtype, values_tiled.dtype if has_values else None,
+        keys_tiled.shape[1], interpret=interpret,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -677,18 +581,15 @@ def packed_tile_histograms_pallas(
     m = spec.num_buckets if spec is not None else num_buckets
     m_eff = m * num_segments
     layout = packed_layout(t, m_eff, **_layout_kw(bits, subtile))
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
     has_seg = seg_tiled is not None
-    return pl.pallas_call(
+    (hist,) = _tiled_call(
         functools.partial(
             _packed_hist_kernel, spec=spec, m=m, has_seg=has_seg, layout=layout
         ),
-        grid=(n_tiles,),
-        in_specs=[row] * (2 if has_seg else 1),
-        out_specs=pl.BlockSpec((1, m_eff), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, m_eff), jnp.int32),
+        [tiled, seg_tiled] if has_seg else [tiled], [_rows(n_tiles, m_eff)],
         interpret=interpret,
-    )(*((tiled, seg_tiled) if has_seg else (tiled,)))
+    )
+    return hist
 
 
 def _packed_positions_kernel(*refs, spec, m: int, has_seg: bool, layout,
@@ -724,22 +625,16 @@ def packed_tile_positions_pallas(
     m = spec.num_buckets if spec is not None else num_buckets
     m_eff = m * num_segments
     layout = packed_layout(t, m_eff, rank16=oblivious, **_layout_kw(bits, subtile))
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    grow = pl.BlockSpec((1, m_eff), lambda i: (i, 0))
     has_seg = seg_tiled is not None
-    in_specs = [row, row, grow] if has_seg else [row, grow]
-    args = (tiled, seg_tiled, g) if has_seg else (tiled, g)
-    return pl.pallas_call(
+    (pos,) = _tiled_call(
         functools.partial(
             _packed_positions_kernel, spec=spec, m=m, has_seg=has_seg,
             layout=layout, oblivious=oblivious,
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
+        [tiled, seg_tiled, g] if has_seg else [tiled, g], [_rows(n_tiles, t)],
         interpret=interpret,
-    )(*args)
+    )
+    return pos
 
 
 def _packed_fused_kernel(
@@ -801,38 +696,18 @@ def packed_fused_postscan_reorder_pallas(
     has_keys = keys_tiled is not None
     has_values = values_tiled is not None
     key_src = keys_tiled if has_keys else tiled
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    grow = pl.BlockSpec((1, m_eff), lambda i: (i, 0))
-    in_specs = [row] + ([row] if has_seg else []) + [grow] + (
-        [row] if has_keys else []) + ([row] if has_values else [])
-    args = ((tiled,) + ((seg_tiled,) if has_seg else ()) + (g,)
-            + ((keys_tiled,) if has_keys else ())
-            + ((values_tiled,) if has_values else ()))
-    out_specs = [row] * (4 if has_values else 3)
-    out_shape = [jax.ShapeDtypeStruct((n_tiles, t), key_src.dtype)]
-    if has_values:
-        out_shape.append(jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype))
-    out_shape += [
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-    ]
-    out = pl.pallas_call(
+    args = ([tiled] + ([seg_tiled] if has_seg else []) + [g]
+            + ([keys_tiled] if has_keys else [])
+            + ([values_tiled] if has_values else []))
+    return _reorder_call(
         functools.partial(
             _packed_fused_kernel, spec=spec, m=m, has_seg=has_seg,
             has_keys=has_keys, has_values=has_values, layout=layout,
             oblivious=oblivious,
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        args, key_src.dtype, values_tiled.dtype if has_values else None, t,
         interpret=interpret,
-    )(*args)
-    if has_values:
-        keys_r, vals_r, pos_r, perm = out
-        return keys_r, vals_r, pos_r, perm
-    keys_r, pos_r, perm = out
-    return keys_r, None, pos_r, perm
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -853,11 +728,13 @@ def _fused2_hist_kernel(*refs, shift: int, bits: int, num_segments: int,
         keys_ref, seg_ref, hist_ref = refs
     else:
         (keys_ref, hist_ref), seg_ref = refs, None
+    seg = seg_ref[0, :] if has_seg else None
+    if oblivious:                           # the (R, C) table, stored 2-D
+        hist_ref[...] = fused2_counts_2d(
+            keys_ref[0, :], shift, bits, seg=seg, num_segments=num_segments)
+        return
     hist_ref[0, :] = fused2_counts_body(
-        keys_ref[0, :], shift, bits,
-        seg=seg_ref[0, :] if has_seg else None, num_segments=num_segments,
-        oblivious=oblivious,
-    )
+        keys_ref[0, :], shift, bits, seg=seg, num_segments=num_segments)
 
 
 def fused2_tile_histograms_pallas(
@@ -874,21 +751,18 @@ def fused2_tile_histograms_pallas(
     half-width one-hots on the MXU (Mosaic-lowerable); ``oblivious=False``
     keeps the O(T) in-kernel scatter-add. The m²-wide one-hot never exists
     on either path."""
-    n_tiles, t = keys_tiled.shape
-    m_eff = spec.num_buckets * num_segments
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
+    n_tiles = keys_tiled.shape[0]
     has_seg = seg_tiled is not None
-    return pl.pallas_call(
+    (hist,) = _tiled_call(
         functools.partial(
             _fused2_hist_kernel, shift=spec.shift, bits=spec.bits,
             num_segments=num_segments, has_seg=has_seg, oblivious=oblivious,
         ),
-        grid=(n_tiles,),
-        in_specs=[row] * (2 if has_seg else 1),
-        out_specs=pl.BlockSpec((1, m_eff), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, m_eff), jnp.int32),
+        [keys_tiled, seg_tiled] if has_seg else [keys_tiled],
+        [_pair_table(n_tiles, spec, num_segments, oblivious)],
         interpret=interpret,
-    )(*((keys_tiled, seg_tiled) if has_seg else (keys_tiled,)))
+    )
+    return hist.reshape(n_tiles, -1)
 
 
 def _fused2_positions_kernel(*refs, shift: int, split: int, bits: int,
@@ -900,7 +774,7 @@ def _fused2_positions_kernel(*refs, shift: int, split: int, bits: int,
     else:
         (keys_ref, g_ref, pos_ref), seg_ref = refs, None
     pos_ref[0, :] = fused2_positions_body(
-        keys_ref[0, :], g_ref[0, :], shift, split, bits,
+        keys_ref[0, :], g_ref[...] if oblivious else g_ref[0, :], shift, split, bits,
         seg=seg_ref[0, :] if has_seg else None, num_segments=num_segments,
         family=family, sub_bits=sub_bits, oblivious=oblivious,
     )
@@ -922,25 +796,18 @@ def fused2_tile_positions_pallas(
     """Fused2 DMS postscan: (L, T) keys + (L, s·m²) pair bases -> (L, T)
     element-ordered global pair destinations (paper eq. (2) over the
     combined digit)."""
-    n_tiles, t = keys_tiled.shape
-    m_eff = spec.num_buckets * num_segments
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    grow = pl.BlockSpec((1, m_eff), lambda i: (i, 0))
     has_seg = seg_tiled is not None
-    in_specs = [row, row, grow] if has_seg else [row, grow]
-    args = (keys_tiled, seg_tiled, g) if has_seg else (keys_tiled, g)
-    return pl.pallas_call(
+    (pos,) = _tiled_call(
         functools.partial(
             _fused2_positions_kernel, shift=spec.shift, split=split,
             bits=spec.bits, num_segments=num_segments, family=family,
             sub_bits=sub_bits, has_seg=has_seg, oblivious=oblivious,
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        interpret=interpret,
-    )(*args)
+        [keys_tiled] + ([seg_tiled] if has_seg else [])
+        + [_pair_g(g, spec, num_segments, oblivious)],
+        [_rows(*keys_tiled.shape)], interpret=interpret,
+    )
+    return pos
 
 
 def _fused2_fused_kernel(*refs, shift: int, split: int, bits: int,
@@ -958,7 +825,7 @@ def _fused2_fused_kernel(*refs, shift: int, split: int, bits: int,
         (keys_out_ref, pos_out_ref, perm_out_ref), vals_out_ref = refs, None
 
     keys_r, vals_r, pos_r, gpos = fused2_postscan_body(
-        keys_ref[0, :], g_ref[0, :],
+        keys_ref[0, :], g_ref[...] if oblivious else g_ref[0, :],
         vals_ref[0, :] if has_values else None, shift, split, bits,
         seg=seg_ref[0, :] if has_seg else None, num_segments=num_segments,
         family=family, sub_bits=sub_bits, oblivious=oblivious,
@@ -990,42 +857,34 @@ def fused2_fused_postscan_reorder_pallas(
     caller's single scatter per PAIR is the only HBM round trip.
     ``oblivious`` (default) traces the gather-free stage-permutation body
     of DESIGN.md §15; ``oblivious=False`` keeps the gather/scatter form."""
-    n_tiles, t = keys_tiled.shape
-    m_eff = spec.num_buckets * num_segments
     has_seg = seg_tiled is not None
     has_values = values_tiled is not None
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    grow = pl.BlockSpec((1, m_eff), lambda i: (i, 0))
-    in_specs = ([row] + ([row] if has_seg else []) + [grow]
-                + ([row] if has_values else []))
-    args = ((keys_tiled,) + ((seg_tiled,) if has_seg else ()) + (g,)
-            + ((values_tiled,) if has_values else ()))
-    out_specs = [row] * (4 if has_values else 3)
-    out_shape = [jax.ShapeDtypeStruct((n_tiles, t), keys_tiled.dtype)]
-    if has_values:
-        out_shape.append(jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype))
-    out_shape += [
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-    ]
-    out = pl.pallas_call(
+    args = ([keys_tiled] + ([seg_tiled] if has_seg else [])
+            + [_pair_g(g, spec, num_segments, oblivious)]
+            + ([values_tiled] if has_values else []))
+    return _reorder_call(
         functools.partial(
             _fused2_fused_kernel, shift=spec.shift, split=split,
             bits=spec.bits, num_segments=num_segments, family=family,
             sub_bits=sub_bits, has_seg=has_seg, has_values=has_values,
             oblivious=oblivious,
         ),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if has_values:
-        keys_r, vals_r, pos_r, perm = out
-        return keys_r, vals_r, pos_r, perm
-    keys_r, pos_r, perm = out
-    return keys_r, None, pos_r, perm
+        args, keys_tiled.dtype, values_tiled.dtype if has_values else None,
+        keys_tiled.shape[1], interpret=interpret,
+    )
+
+
+def _pair_table(n_tiles: int, spec, num_segments: int, oblivious: bool):
+    """Per-tile shape of a fused2 pair table (histogram or G): the oblivious
+    kernels keep it as its (R, C) factoring (:func:`fused2_counts_2d`)."""
+    if not oblivious:
+        return _rows(n_tiles, spec.num_buckets * num_segments)
+    _, n_rows, n_cols = _pair_hist2d_shape(spec.bits, num_segments)
+    return jax.ShapeDtypeStruct((n_tiles, n_rows, n_cols), jnp.int32)
+
+
+def _pair_g(g: Array, spec, num_segments: int, oblivious: bool) -> Array:
+    return g.reshape(_pair_table(g.shape[0], spec, num_segments, oblivious).shape)
 
 
 def _layout_kw(bits: Optional[int], subtile: Optional[int]) -> dict:
@@ -1069,24 +928,11 @@ def tile_reorder_pallas(
     """Stable within-tile bucket-major reorder of (keys, values) + dest map."""
     n_tiles, t = ids_tiled.shape
     m_pad = _pad_lanes(num_buckets)
-    keys_r, vals_r, dest = pl.pallas_call(
+    keys_r, vals_r, dest = _tiled_call(
         functools.partial(_reorder_kernel, m_pad=m_pad),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, t), keys_tiled.dtype),
-            jax.ShapeDtypeStruct((n_tiles, t), values_tiled.dtype),
-            jax.ShapeDtypeStruct((n_tiles, t), jnp.int32),
-        ],
+        [ids_tiled, keys_tiled, values_tiled],
+        [_rows(n_tiles, t, keys_tiled.dtype), _rows(n_tiles, t, values_tiled.dtype),
+         _rows(n_tiles, t)],
         interpret=interpret,
-    )(ids_tiled, keys_tiled, values_tiled)
+    )
     return keys_r, vals_r, dest

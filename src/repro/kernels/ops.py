@@ -27,10 +27,9 @@ Array = jnp.ndarray
 
 @functools.lru_cache(maxsize=1)
 def _tpu_available() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:                    # no backend at all
-        return False
+    # a backend that fails to initialize raises here: it must never read as
+    # "no TPU", which would silently select interpret mode
+    return any(d.platform == "tpu" for d in jax.devices())
 
 
 # Unrecognized REPRO_INTERPRET values already warned about (one warning per

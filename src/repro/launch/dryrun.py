@@ -121,9 +121,7 @@ def _lower_and_compile(cfg, shape, mesh, pcfg, tc, capture_hlo_to=None):
         compiled = lowered.compile()
         t_compile = time.time() - t0
 
-    from repro.compat import cost_analysis_dict
-
-    cost = cost_analysis_dict(compiled)
+    cost = dict(compiled.cost_analysis() or {})
     try:
         mem = compiled.memory_analysis()
         mem_rec = {

@@ -24,6 +24,17 @@ def data_axes(mesh) -> tuple:
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
 
 
+def make_device_mesh():
+    """The production ``(data, model)`` axes over the devices present (all
+    of them on ``data``): what a non-smoke run serves on when the host has
+    fewer chips than a pod."""
+    n = len(jax.devices())
+    return jax.make_mesh(
+        (n, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
+
+
 def make_host_mesh(n_devices: int = 0, axes=("data",)):
     """Small local mesh for tests/examples on whatever devices exist."""
     n = n_devices or len(jax.devices())

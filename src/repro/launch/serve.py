@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_host_mesh
 from repro.models import model as M
 from repro.parallel.sharding import init_params, param_count
 
@@ -106,6 +107,7 @@ def main(argv=None):
     ap.add_argument("--backend", default="vmap")
     ap.add_argument("--fault-rate", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.traffic:
         return run_traffic(args)
@@ -113,7 +115,7 @@ def main(argv=None):
         ap.error("--arch is required unless --traffic is given")
 
     cfg = get_config(args.arch).smoke() if args.smoke else get_config(args.arch)
-    mesh = make_host_mesh() if args.smoke else make_production_mesh()
+    mesh = make_host_mesh() if args.smoke else make_device_mesh()
     max_len = args.prompt_len + args.gen_len
 
     decls = M.decl_model(cfg)

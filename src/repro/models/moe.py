@@ -28,7 +28,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import get_abstract_mesh
 from repro.configs.base import ModelConfig
 from repro.models.layers import apply_norm, mlp_block, mlp_decl, norm_decl
 from repro.parallel.sharding import ParamDecl, constrain as _constrain
@@ -250,7 +249,7 @@ def _dispatch_multisplit_ep(p, xn, gates, experts, cfg: ModelConfig, cap: int, d
     Capacity is per-data-shard (cap / DP), the standard local-capacity MoE
     semantics. Output matches the GSPMD path exactly when nothing drops.
     """
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     names = getattr(mesh, "axis_names", ()) or ()
     if "model" not in names:
         return None  # no mesh context (smoke tests): caller falls back

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
 from repro.configs.base import ParallelConfig
 
 
@@ -101,7 +100,7 @@ def constrain(x, *entries):
     without these anchors the partitioner sometimes replicates the batch
     dim of 5-D einsums (observed on GQA fallback shardings).
     """
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     names = getattr(mesh, "axis_names", ()) or ()
     if not names:
         return x
@@ -129,7 +128,7 @@ def constrain(x, *entries):
 
 
 def tp_size() -> int:
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     names = getattr(mesh, "axis_names", ()) or ()
     return mesh.shape["model"] if "model" in names else 1
 

@@ -23,7 +23,8 @@ net between the plan layer and its callers:
   halves the tile (down to ``_MIN_TILE``, pinning the survivor in the tile
   cache so the shape class never re-learns the lesson); persistent errors
   demote the backend along :data:`DEMOTION_ORDER`
-  (``pallas → pallas-interpret → vmap → reference``).  The reference
+  (``pallas → pallas-interpret → vmap → reference``; ``pallas-interpret``
+  is skipped when a TPU is attached).  The reference
   oracle is the floor — a failure there re-raises.  ``REPRO_STRICT=1`` /
   :func:`set_strict` disables all fallback (CI/debug: fail loud).
 * **Circuit breaker.** Per ``(spec, shape, backend)`` plan class, repeated
@@ -518,7 +519,14 @@ def demote(backend: str) -> Optional[str]:
         i = DEMOTION_ORDER.index(backend)
     except ValueError:
         return "reference"          # unknown/future backend: fall to the oracle
-    return DEMOTION_ORDER[i + 1]
+    nxt = DEMOTION_ORDER[i + 1]
+    if nxt == "pallas-interpret":
+        from repro.kernels import ops as kops
+
+        if kops._tpu_available():
+            # interpreting the kernels on a TPU host is never a useful rung
+            nxt = DEMOTION_ORDER[i + 2]
+    return nxt
 
 
 def _block(result: Any) -> Any:

@@ -8,11 +8,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
-# Install the jax version-compat shims (jax.set_mesh, get_abstract_mesh, ...)
-# before any test module touches jax — tests are written against the modern
-# mesh API and the pinned jax 0.4.x lacks parts of it.
-import repro  # noqa: F401  (side effect: repro.compat.install())
-
 # Property tests use hypothesis; fall back to the deterministic shim when the
 # real library is not baked into the image (see tests/_hypothesis_shim.py).
 try:

@@ -273,15 +273,16 @@ def test_heuristic_tile_regression_n1m_m256():
     The pre-PR-5 model under-counted the one-hot working set (one T×m̄
     plane, one T×T matrix) and chose tile=1024, whose true fused-postscan
     footprint (two T×m̄ planes + two T×T matrices ≈ 10.5 MB) blows the 8 MB
-    budget. The corrected model halves it to 512. Since PR-8 kernel
-    backends trace the OBLIVIOUS packed body (DESIGN.md §15), whose T×T
-    permutation matrix caps the packed tile at 1024 (vmap keeps 4096)."""
+    budget. The corrected model halves it to 512. Kernel backends are now
+    charged what Mosaic allocates for the compiled kernel of either family
+    (19 MiB at T=1024 on v5e, over the 16 MiB budget), so the packed tile
+    is 512 there too (vmap keeps 4096)."""
     clear_tile_cache()
     assert msplan._heuristic_tile(1 << 20, 256, "bms", "pallas", family="onehot") == 512
-    assert msplan._heuristic_tile(1 << 20, 256, "bms", "pallas", family="packed") == 1024
+    assert msplan._heuristic_tile(1 << 20, 256, "bms", "pallas", family="packed") == 512
     assert msplan._heuristic_tile(1 << 20, 256, "bms", "vmap", family="packed") == 4096
     p = make_plan(1 << 20, 256, method="bms", backend="pallas")
-    assert (p.family, p.tile) == ("packed", 1024)
+    assert (p.family, p.tile) == ("packed", 512)
     p1h = make_plan(1 << 20, 256, method="bms", backend="pallas", family="onehot")
     assert (p1h.family, p1h.tile) == ("onehot", 512)
 
@@ -291,13 +292,14 @@ def test_explicit_family_does_not_poison_tile_cache():
     model WITHOUT writing the shape's cache entry (mirrors the explicit-tile
     rule)."""
     clear_tile_cache()
-    shape = (1 << 20, 256, "bms", False, "pallas")
-    p_pk = make_plan(1 << 20, 256, method="bms", backend="pallas")          # auto: packed
-    assert msplan._TILE_CACHE[shape] == p_pk.tile == 1024
-    p_1h = make_plan(1 << 20, 256, method="bms", backend="pallas", family="onehot")
-    assert p_1h.tile == 512
-    assert msplan._TILE_CACHE[shape] == 1024        # auto entry untouched
-    assert make_plan(1 << 20, 256, method="bms", backend="pallas").tile == 1024
+    shape = (1 << 20, 256, "bms", False, "vmap")
+    msplan._TILE_CACHE[shape] = 2048                # a measured pin
+    p_pk = make_plan(1 << 20, 256, method="bms", backend="vmap")            # auto: packed
+    assert p_pk.family == "packed" and msplan._TILE_CACHE[shape] == p_pk.tile == 2048
+    p_1h = make_plan(1 << 20, 256, method="bms", backend="vmap", family="onehot")
+    assert (p_1h.family, p_1h.tile) == ("onehot", 4096)    # its own model
+    assert msplan._TILE_CACHE[shape] == 2048        # auto entry untouched
+    assert make_plan(1 << 20, 256, method="bms", backend="vmap").tile == 2048
 
 
 def test_family_is_a_hashable_plan_axis():
@@ -347,10 +349,10 @@ def test_autotune_family_flip_invalidates_other_kv_tile():
     not silently served under the flipped family."""
     clear_tile_cache()
     bf = delta_buckets(256, 2**30)
-    # key-only plan caches tile 1024 under the heuristic 'packed' family
-    # (the oblivious T×T term caps kernel-backend packed tiles; DESIGN.md §15)
+    # key-only plan caches tile 512 under the heuristic 'packed' family
+    # (the compiled kernel's T×T planes cap kernel-backend tiles)
     p0 = make_plan(1 << 14, 256, method="bms", backend="pallas-interpret")
-    assert (p0.family, p0.tile) == ("packed", 1024)
+    assert (p0.family, p0.tile) == ("packed", 512)
     # force an autotuned family flip via the kv variant (onehot only)
     msplan.autotune_tile(
         1 << 14, bf, method="bms", backend="pallas-interpret", key_value=True,
@@ -358,7 +360,8 @@ def test_autotune_family_flip_invalidates_other_kv_tile():
     )
     assert family_decision(1 << 14, 256, "bms", "pallas-interpret")[0] == "onehot"
     # the key-only shape must now re-resolve its tile under 'onehot' — the
-    # stale packed-model 1024 (a VMEM blowout for the one-hot) is gone
+    # stale entry sized under the packed model is gone
+    assert (1 << 14, 256, "bms", False, "pallas-interpret") not in msplan._TILE_CACHE
     p1 = make_plan(1 << 14, 256, method="bms", backend="pallas-interpret")
     assert (p1.family, p1.tile) == ("onehot", 512)
 

@@ -209,6 +209,39 @@ def test_demotion_order_respected():
     assert s["backend_demotions"] == 3 and s["degradations"] == 3
 
 
+def test_demotion_skips_interpret_rung_when_tpu_attached(monkeypatch):
+    """On a TPU host the ladder goes pallas -> vmap: interpreting the
+    kernels there would leave the chip idle behind a CPU emulation."""
+    monkeypatch.setattr(kops, "_tpu_available", lambda: True)
+    assert rz.demote("pallas") == "vmap"
+    attempts = []
+
+    def run(backend, tile):
+        attempts.append(backend)
+        if backend != "reference":
+            raise RuntimeError("Mosaic lowering failed: unsupported primitive")
+        return "ok"
+
+    assert rz.dispatch(run, _ctx(), backend="pallas") == "ok"
+    assert attempts == ["pallas", "vmap", "reference"]
+    assert rz.stats()["backend_demotions"] == 2
+
+
+def test_tpu_probe_raises_on_backend_init_failure(monkeypatch):
+    """A backend that fails to initialize must surface, not read as "no
+    TPU" (which would silently select interpret mode)."""
+    def broken_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    kops._tpu_available.cache_clear()
+    monkeypatch.setattr(jax, "devices", broken_backend)
+    try:
+        with pytest.raises(RuntimeError, match="initialize"):
+            kops._tpu_available()
+    finally:
+        kops._tpu_available.cache_clear()
+
+
 def test_resource_error_halves_tile_and_pins_survivor():
     tried, pinned = [], []
 
