@@ -13,9 +13,7 @@ and reports the gap ``t_heuristic / t_tuned``.
 
 A gap of 1.0 means the heuristic still picks what measurement picks; the
 gap grows as the cost model rots. ``--ci-max X`` exits non-zero when any
-grid point's gap exceeds ``X`` — the CI drift gate. Full (non ``--quick``)
-runs append the gaps to BENCH_multisplit.json so drift is trended over
-commits like every other trajectory metric.
+grid point's gap exceeds ``X`` — the CI drift gate.
 """
 
 import argparse
@@ -26,7 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import append_trajectory, row
+from benchmarks.common import row
 from repro.core.identifiers import EvenSpec
 from repro.core.pipeline import clear_tile_cache, family_decision, make_plan, set_autotune
 from repro.core.pipeline import autotune as _at
@@ -120,15 +118,13 @@ def main(quick: bool = False, ci_max: float = None) -> int:
     if ci_max is not None:
         print(f"# ok: worst heuristic-vs-tuned gap {worst:.3f}x at "
               f"{worst_tag} (gate {ci_max:.2f}x)")
-    if not quick:
-        append_trajectory(results, n=n, key_value=False)
     return 0
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
-                    help="small-n smoke (no trajectory append)")
+                    help="small-n smoke")
     ap.add_argument("--ci-max", type=float, default=None,
                     help="exit 1 if heuristic > MAX x slower than autotuned")
     a = ap.parse_args()

@@ -3,15 +3,14 @@ vs the platform baseline (jnp.histogram — XLA's native path).
 
 The "ours" rows run the ``counts_only`` partial pipeline (DESIGN.md §10):
 prescan + tree-reduce, tiles from the shared heuristic cache — no scan, no
-scatter. ``main(emit_json=True)`` appends an even-histogram trajectory point
-to BENCH_multisplit.json.
+scatter.
 """
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import append_trajectory, bench, row
+from benchmarks.common import bench, row
 from repro.core.histogram import histogram_even, histogram_range
 
 N = 1 << 20
@@ -19,7 +18,7 @@ M_SWEEP = (2, 8, 32, 64, 256)
 RANGE_M_SWEEP = (8, 64, 256)
 
 
-def main(emit_json: bool = True):
+def main():
     rng = np.random.RandomState(0)
     keys = jnp.asarray(rng.uniform(0, 1024.0, N).astype(np.float32))
     results = {}
@@ -45,8 +44,6 @@ def main(emit_json: bool = True):
         t = bench(g, keys)
         row(f"histogram/range/m={m}/platform", t, f"{N / t / 1e6:.1f} Melem/s")
 
-    if emit_json:
-        append_trajectory(results, n=N, key_value=False)
     return results
 
 

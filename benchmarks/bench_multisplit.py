@@ -1,8 +1,7 @@
 """Paper Tables 3/4/5 analogue: multisplit throughput vs bucket count, for
 DMS / WMS / BMS vs the sort-based baselines (RB-sort, direct key sort), for
 key-only and key-value, plus Table 6's input-distribution sensitivity, plus
-the fused-plan vs legacy-unfused pipeline comparison (DESIGN.md §6), which
-appends a trajectory point to BENCH_multisplit.json.
+the fused-plan vs legacy-unfused pipeline comparison (DESIGN.md §6).
 
 Rates are Mkeys/s on THIS host (CPU — relative standings are the
 reproduction target; absolute GPU numbers are in the paper).
@@ -17,7 +16,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import append_trajectory, bench, row
+from benchmarks.common import bench, row
 from repro.core.identifiers import delta_buckets
 from repro.core.multisplit import (
     batched_multisplit,
@@ -83,10 +82,9 @@ def run_distributions():
         row(f"multisplit/dist={name}/m=256/bms", t, f"{N / t / 1e6:.1f} Mkeys/s")
 
 
-def run_fused_vs_legacy(emit_json: bool = True):
+def run_fused_vs_legacy():
     """The tentpole measurement: the plan's fused single-pass postscan vs the
-    legacy three-pass (positions, key reorder, value reorder) orchestration.
-    Appends one trajectory point per run to BENCH_multisplit.json."""
+    legacy three-pass (positions, key reorder, value reorder) orchestration."""
     results = {}
     keys = _keys()
     vals = jnp.arange(N, dtype=jnp.int32)
@@ -106,17 +104,14 @@ def run_fused_vs_legacy(emit_json: bool = True):
             row(f"multisplit/kv/{tag}/fused-plan", t_f, f"{N / t_f / 1e6:.1f} Mkeys/s")
             row(f"multisplit/kv/{tag}/legacy-unfused", t_l,
                 f"{N / t_l / 1e6:.1f} Mkeys/s ({t_l / t_f:.2f}x slower)")
-    if emit_json:
-        append_trajectory(results, n=N, key_value=True)
     return results
 
 
-def run_batched_vs_host_loop(emit_json: bool = True):
+def run_batched_vs_host_loop():
     """DESIGN.md §9 measurement: b independent multisplits as ONE batched
     (and one segmented) plan launch vs the host loop every consumer used to
-    write (one flat plan call per row). Appends a trajectory point to
-    BENCH_multisplit.json; the acceptance bar is batched >= 1.5x host-loop
-    on the vmap backend at b=64, n=4096, m=32."""
+    write (one flat plan call per row). The acceptance bar is batched >=
+    1.5x host-loop on the vmap backend at b=64, n=4096, m=32."""
     b = int(os.environ.get("MS_BENCH_B", "64"))
     n = 1 << int(os.environ.get("MS_BENCH_BN", "12"))        # 4096 per row
     m = 32
@@ -168,19 +163,16 @@ def run_batched_vs_host_loop(emit_json: bool = True):
         f"{total / t_h / 1e6:.1f} Mkeys/s ({t_h / t_b:.2f}x slower than batched)")
     row(f"multisplit/kv/{tag}/host-loop-jit", t_hj,
         f"{total / t_hj / 1e6:.1f} Mkeys/s ({t_hj / t_b:.2f}x slower than batched)")
-    if emit_json:
-        append_trajectory(results, n=total, key_value=True)
     return results
 
 
-def run_fused_labels_vs_materialized(emit_json: bool = True):
+def run_fused_labels_vs_materialized():
     """ISSUE 4 measurement: in-tile fused labels (hashable specs evaluated
     inside the tile stage / kernels) vs the pre-PR-4 materialized-labels
     execution, which the CallableSpec escape hatch still exercises — the
     full n-sized int32 label array is computed, padded and carried through
     the pipeline.  Flat multisplit at m∈{32,256} plus the chained radix
-    sort (BitfieldSpec digits, radix_bits∈{5,8} → m∈{32,256} per pass).
-    Appends a trajectory point to BENCH_multisplit.json."""
+    sort (BitfieldSpec digits, radix_bits∈{5,8} → m∈{32,256} per pass)."""
     from repro import ops
     from repro.core.pipeline import radix_passes
 
@@ -233,19 +225,16 @@ def run_fused_labels_vs_materialized(emit_json: bool = True):
         row(f"sort/kv/{tag}/materialized", t_m,
             f"{N / t_m / 1e6:.1f} Mkeys/s ({t_m / t_f:.2f}x slower)")
 
-    if emit_json:
-        append_trajectory(results, n=N, key_value=True)
     return results
 
 
-def run_packed_vs_onehot(emit_json: bool = True, quick: bool = False):
+def run_packed_vs_onehot(quick: bool = False):
     """ISSUE 5 measurement: the packed-counter kernel family (bit-packed
     subword counters + two-level rank, DESIGN.md §12) vs the dense one-hot
     family, on the SAME plans — only ``family`` differs, outputs are bitwise
     identical.  Flat key-value multisplit sweeping m ∈ {8, 32, 64, 128, 256}
     plus the chained radix sort at radix_bits ∈ {5, 8}; ``quick=True``
-    restricts to the m=256 flat + radix points (the CI perf-smoke floor).
-    Appends a commit-stamped trajectory point to BENCH_multisplit.json."""
+    restricts to the m=256 flat + radix points (the CI perf-smoke floor)."""
     from repro.core.sort import radix_sort
 
     results = {}
@@ -287,12 +276,10 @@ def run_packed_vs_onehot(emit_json: bool = True, quick: bool = False):
             f"{N / timed['onehot'] / 1e6:.1f} Mkeys/s "
             f"({timed['onehot'] / timed['packed']:.2f}x slower)")
 
-    if emit_json:
-        append_trajectory(results, n=N, key_value=True)
     return results
 
 
-def run_oblivious_vs_gather(emit_json: bool = True, quick: bool = False):
+def run_oblivious_vs_gather(quick: bool = False):
     """ISSUE 8 measurement (DESIGN.md §15): the gather-free OBLIVIOUS kernel
     bodies (one-hot selects, 16-bit rank planes, permutation matmuls — the
     only forms Mosaic lowers with ``interpret=False``) vs the legacy gather
@@ -302,7 +289,7 @@ def run_oblivious_vs_gather(emit_json: bool = True, quick: bool = False):
     emit vs the serialized compare chain at s ∈ {31, 255} (satellite 1).
     ``speedup = t_gather / t_oblivious``; the CI floor asserts the oblivious
     forms cost <= ~1.1x the gather forms even on a host, where gathers are
-    native.  Appends a trajectory point to BENCH_multisplit.json."""
+    native."""
     from repro.core.identifiers import BitfieldSpec, RangeSpec
     from repro.kernels import ops as kops
 
@@ -357,16 +344,13 @@ def run_oblivious_vs_gather(emit_json: bool = True, quick: bool = False):
         row(f"kernels/rangespec/s={s}/tree-emit", t_tree,
             f"{t_chain / t_tree:.2f}x vs chain")
 
-    if emit_json:
-        append_trajectory(results, n=N, key_value=True)
     return results
 
 
 def main(quick: bool = False):
     if quick:
-        # smoke sizes must not pollute the full-sweep trajectory history
-        run_packed_vs_onehot(quick=True, emit_json=False)
-        run_oblivious_vs_gather(quick=True, emit_json=False)
+        run_packed_vs_onehot(quick=True)
+        run_oblivious_vs_gather(quick=True)
         return
     run(key_value=False)
     run(key_value=True)

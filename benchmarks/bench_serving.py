@@ -18,8 +18,7 @@ Three measurements over the same synthetic request set:
    (or ``--qps``): exact nearest-rank p50/p95/p99 latency, sustained QPS,
    shed count, and PASS/FAIL against ``--slo-ms``.
 
-Every run conservation-checks request accounting (``dropped_by_bug == 0``)
-and appends a git-stamped trajectory point to ``BENCH_multisplit.json``.
+Every run conservation-checks request accounting (``dropped_by_bug == 0``).
 """
 
 from __future__ import annotations
@@ -32,7 +31,7 @@ from typing import Dict, List, Tuple
 import jax
 import numpy as np
 
-from benchmarks.common import append_trajectory, row
+from benchmarks.common import row
 from repro.serving import (
     ServerLoop, ServingConfig, closed_loop, open_loop, poisson_arrivals,
     synthetic_requests,
@@ -203,7 +202,6 @@ def run_serving_slo(
             fields = " ".join(f"{k}={v}" for k, v in e.items() if k != "kind")
             print(f"DEGRADATION_EVENT kind={e['kind']} {fields}")
 
-    append_trajectory(results, n=requests, key_value=False, backend=cfg.backend)
     _disarm()
 
     if ci_floor is not None and ratio < ci_floor:

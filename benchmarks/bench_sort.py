@@ -12,18 +12,17 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import append_trajectory, bench, row
+from benchmarks.common import bench, row
 from repro.core.sort import radix_sort, radix_sort_per_pass
 
 N = 1 << int(os.environ.get("MS_BENCH_N", "18"))
 N_PALLAS = min(N, 1 << 14)
 
 
-def run_chained_vs_per_pass_radix(emit_json: bool = True):
+def run_chained_vs_per_pass_radix():
     """DESIGN.md §10 measurement: the chained RadixPipeline (tiles resolved
     once, buffers padded once, ping-pong across digit passes) vs the PR-2
-    per-pass execution (a full pad/tile/run/slice round trip per pass).
-    Appends a trajectory point to BENCH_multisplit.json."""
+    per-pass execution (a full pad/tile/run/slice round trip per pass)."""
     rng = np.random.RandomState(0)
     keys = jnp.asarray(rng.randint(0, 2**32, N, dtype=np.uint32))
     vals = jnp.arange(N, dtype=jnp.int32)
@@ -40,8 +39,6 @@ def run_chained_vs_per_pass_radix(emit_json: bool = True):
         row(f"sort/kv/{tag}/chained-pipeline", t_c, f"{N / t_c / 1e6:.1f} Mpairs/s")
         row(f"sort/kv/{tag}/per-pass-legacy", t_p,
             f"{N / t_p / 1e6:.1f} Mpairs/s ({t_p / t_c:.2f}x slower)")
-    if emit_json:
-        append_trajectory(results, n=N, key_value=True)
     return results
 
 

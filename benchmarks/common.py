@@ -1,14 +1,9 @@
 """Benchmark timing utilities."""
 
-import json
-import subprocess
 import time
-from pathlib import Path
 
 import jax
 import numpy as np
-
-BENCH_JSON = Path(__file__).resolve().parent.parent / "BENCH_multisplit.json"
 
 # The shared exact (interpolation-free, nearest-rank) percentile estimator:
 # one implementation for serving metrics and the SLO bench, so a reported
@@ -16,55 +11,6 @@ BENCH_JSON = Path(__file__).resolve().parent.parent / "BENCH_multisplit.json"
 # actually experienced.  Defined in repro.serving.metrics (benchmarks depend
 # on repro, never the reverse) and re-exported here for benchmark code.
 from repro.serving.metrics import percentiles  # noqa: E402,F401
-
-
-def git_commit() -> str:
-    """Short hash of the checked-out commit (with ``-dirty`` when the tree
-    has uncommitted changes), so every trajectory point is attributable
-    (regressions were previously dated but not attributable).
-
-    Note the run-bench-then-commit workflow: a point measured from a dirty
-    tree and committed WITH the code that produced it is stamped
-    ``<parent>-dirty`` — the commit that introduced the entry (via
-    ``git log -- BENCH_multisplit.json``) is the one containing the
-    measured code."""
-    try:
-        cwd = Path(__file__).resolve().parent
-        out = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"],
-            capture_output=True, text=True, timeout=5, cwd=cwd,
-        )
-        sha = out.stdout.strip()
-        if not sha:
-            return "unknown"
-        dirty = subprocess.run(
-            ["git", "status", "--porcelain"],
-            capture_output=True, text=True, timeout=5, cwd=cwd,
-        ).stdout.strip()
-        return sha + ("-dirty" if dirty else "")
-    except Exception:
-        return "unknown"
-
-
-def append_trajectory(results: dict, *, n: int, key_value: bool, backend: str = "vmap",
-                      path: Path = None) -> None:
-    """Append one timestamped, commit-stamped trajectory point to
-    BENCH_multisplit.json."""
-    path = path or BENCH_JSON
-    history = []
-    if path.exists():
-        history = json.loads(path.read_text())
-    history.append({
-        "ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
-        "commit": git_commit(),
-        "n": n,
-        "key_value": key_value,
-        "host": jax.default_backend(),
-        "backend": backend,
-        "results": results,
-    })
-    path.write_text(json.dumps(history, indent=2) + "\n")
-    print(f"# trajectory point appended to {path.name}")
 
 
 def bench(fn, *args, warmup=1, trials=3):

@@ -20,9 +20,7 @@ L×m tile-histogram matrix. The tracker:
    FRACTION OF ROOFLINE = (ideal_bytes / peak_bw) / measured_time.
 
 ``--ci-floor X`` exits non-zero when fused throughput < X× chained at the
-headline r=8 point — the CI perf-smoke guard (S5). ``--quick`` shrinks n
-and skips the trajectory append (smoke sizes must not pollute the
-BENCH_multisplit.json history).
+headline r=8 point — the CI perf-smoke guard (S5). ``--quick`` shrinks n.
 """
 
 import argparse
@@ -33,7 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import append_trajectory, bench, row
+from benchmarks.common import bench, row
 from repro.core.pipeline import RadixPipeline, radix_pass_pairs, radix_passes
 from repro.core.sort import radix_sort, radix_sort_per_pass
 
@@ -145,15 +143,13 @@ def main(quick: bool = False, ci_floor: float = None) -> int:
     if ci_floor is not None:
         print(f"# ok: fused radix at r=8 is {headline:.3f}x chained "
               f"(floor {ci_floor:.2f}x)")
-    if not quick:
-        append_trajectory(results, n=n, key_value=True)
     return 0
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
-                    help="small-n smoke (no trajectory append)")
+                    help="small-n smoke")
     ap.add_argument("--ci-floor", type=float, default=None,
                     help="exit 1 if fused < FLOOR x chained at r=8")
     a = ap.parse_args()

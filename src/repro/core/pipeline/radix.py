@@ -33,6 +33,7 @@ from repro.core.pipeline import stages as _st
 from repro.core.pipeline.registry import get_backend
 from repro.core.pipeline.spec import make_radix_plan
 from repro.core.pipeline.tiles import resolve_kernel_family, resolve_tile
+from repro.runtime import tracing
 
 Array = jnp.ndarray
 
@@ -248,31 +249,35 @@ class RadixPipeline:
         be.check_keys(keys)
         tile = self.tile
         # ---- pad ONCE: sentinel keys sort to the tail in every pass
-        keys_pad, _ = _st.pad_to_tiles(keys, tile, self.plans[0].pad_key(keys.dtype))
-        vals_pad = None
-        if values is not None:
-            vals_pad, _ = _st.pad_to_tiles(values, tile, 0)
-        seg_tiled = None
-        if seg is not None:
-            # position-keyed and pass-invariant: elements never cross
-            # segment boundaries, so one seg buffer drives all passes
-            seg_ids = _st.segment_ids_from_starts(seg, n)
-            seg_p, _ = _st.pad_to_tiles(seg_ids, tile, self.segments - 1)
-            seg_tiled = seg_p.reshape(-1, tile)
+        with self.plans[0]._stage("layout", -(-n // tile)):
+            keys_pad, _ = _st.pad_to_tiles(
+                keys, tile, self.plans[0].pad_key(keys.dtype))
+            vals_pad = None
+            if values is not None:
+                vals_pad, _ = _st.pad_to_tiles(values, tile, 0)
+            seg_tiled = None
+            if seg is not None:
+                # position-keyed and pass-invariant: elements never cross
+                # segment boundaries, so one seg buffer drives all passes
+                seg_ids = _st.segment_ids_from_starts(seg, n)
+                seg_p, _ = _st.pad_to_tiles(seg_ids, tile, self.segments - 1)
+                seg_tiled = seg_p.reshape(-1, tile)
 
         # ---- chained passes on resident buffers (reshape views are free).
         # On label-fusing backends each pass's BitfieldSpec digit is computed
         # inside the tile stage (in-register in the kernels) — zero label
         # traffic; only non-fusing backends materialize the digit strip.
-        for plan in self.plans:
-            keys_tiled = keys_pad.reshape(-1, tile)
-            vals_tiled = vals_pad.reshape(-1, tile) if vals_pad is not None else None
-            ids_tiled = None
-            if not plan.label_fusion(keys_pad):
-                ids_tiled = plan._host_labels(keys_pad).reshape(-1, tile)
-            keys_pad, vals_pad, _, _ = plan.run_tiled(
-                keys_tiled, ids_tiled, vals_tiled, seg_tiled
-            )
+        for plan, (shift, bits, _) in zip(self.plans, self.schedule):
+            with tracing.span("repro.sort.pass", shift=shift, bits=bits):
+                keys_tiled = keys_pad.reshape(-1, tile)
+                vals_tiled = (vals_pad.reshape(-1, tile)
+                              if vals_pad is not None else None)
+                ids_tiled = None
+                if not plan.label_fusion(keys_pad):
+                    ids_tiled = plan._host_labels(keys_pad).reshape(-1, tile)
+                keys_pad, vals_pad, _, _ = plan.run_tiled(
+                    keys_tiled, ids_tiled, vals_tiled, seg_tiled
+                )
 
         # ---- slice the pad tail off ONCE
         return keys_pad[:n], (vals_pad[:n] if values is not None else None)
@@ -299,17 +304,20 @@ class RadixPipeline:
         tile = self.tile
         l_b = -(-n // tile)
         n_row = l_b * tile
-        keys_pad = _st.pad_rows(keys, n_row, self.plans[0].pad_key(keys.dtype))
-        vals_pad = _st.pad_rows(values, n_row, 0) if values is not None else None
+        with self.plans[0]._stage("layout", b * l_b):
+            keys_pad = _st.pad_rows(keys, n_row, self.plans[0].pad_key(keys.dtype))
+            vals_pad = _st.pad_rows(values, n_row, 0) if values is not None else None
 
-        for plan in self.plans:
-            keys_tiled = keys_pad.reshape(b * l_b, tile)
-            vals_tiled = vals_pad.reshape(b * l_b, tile) if vals_pad is not None else None
-            ids_tiled = None
-            if not plan.label_fusion(keys_pad):
-                ids_tiled = plan._host_labels(keys_pad).reshape(b * l_b, tile)
-            keys_pad, vals_pad, _, _ = plan.run_tiled(
-                keys_tiled, ids_tiled, vals_tiled, rows=b
-            )
+        for plan, (shift, bits, _) in zip(self.plans, self.schedule):
+            with tracing.span("repro.sort.pass", shift=shift, bits=bits):
+                keys_tiled = keys_pad.reshape(b * l_b, tile)
+                vals_tiled = (vals_pad.reshape(b * l_b, tile)
+                              if vals_pad is not None else None)
+                ids_tiled = None
+                if not plan.label_fusion(keys_pad):
+                    ids_tiled = plan._host_labels(keys_pad).reshape(b * l_b, tile)
+                keys_pad, vals_pad, _, _ = plan.run_tiled(
+                    keys_tiled, ids_tiled, vals_tiled, rows=b
+                )
 
         return keys_pad[:, :n], (vals_pad[:, :n] if values is not None else None)

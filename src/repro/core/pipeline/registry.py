@@ -40,6 +40,11 @@ class StageImpl:
     backends).
     """
 
+    def map_batch(self, spec, n_tiles: int, tile: int) -> int:
+        """The ``lax.map`` batch size of the tile stages over ``n_tiles``
+        tiles of ``tile`` keys; 0 where they are not run in chunks."""
+        return 0
+
     def prescan(self, spec, keys_tiled, ids_tiled, seg_tiled) -> Array:
         raise NotImplementedError
 
@@ -187,24 +192,29 @@ class KernelStages(StageImpl):
 _VMAP_WORKSET_BYTES = 1 << 30
 
 
-def _over_tiles(spec, *tiled):
-    """``jax.vmap`` over the tile axis, or ``lax.map`` over chunks of tiles
-    when the vmapped working set would pass ``_VMAP_WORKSET_BYTES``. The
-    per-tile estimate is three int32 planes of the local solve's width: the
-    sub-digit stage planes plus the pair rows for a fused pair (DESIGN.md
-    §13), else the m_eff-wide one-hot/packed planes."""
-    n_tiles, t = next(x for x in tiled if x is not None).shape
+def _map_batch(spec, n_tiles: int, t: int) -> int:
+    """Tiles per ``lax.map`` chunk where the vmapped working set of
+    ``n_tiles`` tiles of ``t`` keys would pass ``_VMAP_WORKSET_BYTES``, else
+    0. The per-tile estimate is three int32 planes of the local solve's
+    width: the sub-digit stage planes plus the pair rows for a fused pair
+    (DESIGN.md §13), else the m_eff-wide one-hot/packed planes."""
     if spec.digit_split is not None:
         per_tile = 12 * (t * 16 * (spec.segments or 1) + spec.m_eff)
     else:
         per_tile = 12 * t * spec.m_eff
     chunk = _VMAP_WORKSET_BYTES // max(per_tile, 1)
-    if chunk >= n_tiles:
+    return 0 if chunk >= n_tiles else max(1, chunk)
+
+
+def _over_tiles(spec, *tiled):
+    """``jax.vmap`` over the tile axis, or ``lax.map`` over chunks of tiles
+    (:func:`_map_batch`)."""
+    batch = _map_batch(spec, *next(x for x in tiled if x is not None).shape)
+    if not batch:
         return jax.vmap
 
     def chunked(fn):
-        return lambda *xs: jax.lax.map(
-            lambda a: fn(*a), xs, batch_size=max(1, chunk))
+        return lambda *xs: jax.lax.map(lambda a: fn(*a), xs, batch_size=batch)
 
     return chunked
 
@@ -221,6 +231,9 @@ class VmapStages(StageImpl):
     labels are an XLA-fused intermediate of the per-tile computation, never
     a host/plan-layer array (bitwise identical to the ids path).
     """
+
+    def map_batch(self, spec, n_tiles, tile):
+        return _map_batch(spec, n_tiles, tile)
 
     @staticmethod
     def _tile_ids(spec, keys_tiled, ids_tiled):

@@ -37,6 +37,7 @@ from repro.core.pipeline.tiles import (
     resolve_sub_bits,
     resolve_tile,
 )
+from repro.runtime import tracing
 
 Array = jnp.ndarray
 
@@ -46,7 +47,7 @@ MODES = ("reorder", "counts_only", "positions_only")
 # stage implementations re-evaluate the bucket spec in EVERY tile stage
 # (prescan and postscan), so wide scans pay the spec twice while the
 # materialized path pays it once plus the n-sized label traffic. Measured
-# host-bench crossover (BENCH_multisplit.json fused_labels sweep re-run at
+# host-bench crossover (bench_multisplit.py fused_labels sweep re-run at
 # n ∈ {2^18, 2^20}, key-value flat): fused wins up to m=256 (1.03–1.06×)
 # and loses from m=512 (0.95–0.97×). Kernel backends fuse in-register and
 # always win; the radix BitfieldSpec is a shift-and-mask and always wins
@@ -344,7 +345,23 @@ class PipelineSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MultisplitPlan(PipelineSpec):
-    """An executable :class:`PipelineSpec`: call with concrete arrays."""
+    """An executable :class:`PipelineSpec`: call with concrete arrays.
+
+    Each stage runs inside a ``repro.stage.<name>`` span (layout, prescan,
+    scan, postscan, scatter; :mod:`repro.runtime.tracing`), so that a
+    profiler trace of an eager call puts every device program down to the
+    stage that launched it."""
+
+    def _stage(self, name: str, tiles: int):
+        """The span of stage ``name`` over ``tiles`` tiles."""
+        if not tracing.enabled():
+            return tracing.OFF
+        impl = get_backend(self.backend).stages
+        return tracing.span(
+            f"repro.stage.{name}", backend=self.backend, family=self.family,
+            tile=self.tile, tiles=tiles,
+            map_batch=impl.map_batch(self, tiles, self.tile) if impl else 0,
+        )
 
     # -- stage entry points (delegating to the registered backend) ---------
     def prescan(
@@ -397,39 +414,44 @@ class MultisplitPlan(PipelineSpec):
         scan/scatter). :class:`~repro.core.pipeline.radix.RadixPipeline`
         iterates this on resident ping-pong buffers, one call per digit
         pass."""
-        hist = self.prescan(keys_tiled, ids_tiled, seg_tiled)
-        if rows is None:
-            g = _st.global_scan(hist)
-        else:
-            l_b = hist.shape[0] // rows
-            g = jax.vmap(_st.global_scan)(
-                hist.reshape(rows, l_b, hist.shape[-1])
-            ).reshape(hist.shape)
-        src_keys, src_vals, pos, perm_tiled = self.postscan(
-            g, keys_tiled, ids_tiled, vals_tiled, seg_tiled
-        )
-        if rows is None:
-            n_total = keys_tiled.size
-            scatter_pos = pos.reshape(-1)
-            keys_pad = (
-                jnp.zeros((n_total,), keys_tiled.dtype)
-                .at[scatter_pos].set(src_keys.reshape(-1))
+        tiles = keys_tiled.shape[0]
+        with self._stage("prescan", tiles):
+            hist = self.prescan(keys_tiled, ids_tiled, seg_tiled)
+        with self._stage("scan", tiles):
+            if rows is None:
+                g = _st.global_scan(hist)
+            else:
+                l_b = hist.shape[0] // rows
+                g = jax.vmap(_st.global_scan)(
+                    hist.reshape(rows, l_b, hist.shape[-1])
+                ).reshape(hist.shape)
+        with self._stage("postscan", tiles):
+            src_keys, src_vals, pos, perm_tiled = self.postscan(
+                g, keys_tiled, ids_tiled, vals_tiled, seg_tiled
             )
+        with self._stage("scatter", tiles):
+            if rows is None:
+                n_total = keys_tiled.size
+                scatter_pos = pos.reshape(-1)
+                keys_pad = (
+                    jnp.zeros((n_total,), keys_tiled.dtype)
+                    .at[scatter_pos].set(src_keys.reshape(-1))
+                )
+                vals_pad = None
+                if vals_tiled is not None:
+                    vals_pad = (
+                        jnp.zeros((n_total,), vals_tiled.dtype)
+                        .at[scatter_pos].set(src_vals.reshape(-1))
+                    )
+                return keys_pad, vals_pad, hist, perm_tiled
+            n_row = keys_tiled.size // rows
+            pos_rows = pos.reshape(rows, n_row)
+            scat = lambda p, src: jnp.zeros((n_row,), src.dtype).at[p].set(src)
+            keys_pad = jax.vmap(scat)(pos_rows, src_keys.reshape(rows, n_row))
             vals_pad = None
             if vals_tiled is not None:
-                vals_pad = (
-                    jnp.zeros((n_total,), vals_tiled.dtype)
-                    .at[scatter_pos].set(src_vals.reshape(-1))
-                )
+                vals_pad = jax.vmap(scat)(pos_rows, src_vals.reshape(rows, n_row))
             return keys_pad, vals_pad, hist, perm_tiled
-        n_row = keys_tiled.size // rows
-        pos_rows = pos.reshape(rows, n_row)
-        scat = lambda p, src: jnp.zeros((n_row,), src.dtype).at[p].set(src)
-        keys_pad = jax.vmap(scat)(pos_rows, src_keys.reshape(rows, n_row))
-        vals_pad = None
-        if vals_tiled is not None:
-            vals_pad = jax.vmap(scat)(pos_rows, src_vals.reshape(rows, n_row))
-        return keys_pad, vals_pad, hist, perm_tiled
 
     # -- layout helpers ----------------------------------------------------
     def _empty_result(self, keys: Array, values: Optional[Array]) -> MultisplitResult:
@@ -492,41 +514,47 @@ class MultisplitPlan(PipelineSpec):
             return res
 
         self._check_key_width(keys)
-        fused = self.label_fusion(keys)
         tile = self.tile
         l_b = -(-n // tile)                       # tiles per batch row
         n_row = l_b * tile
+        tiles = b * l_b
 
         # Per-row tiling: each tile belongs to exactly ONE batch row, so a
         # single kernel grid of b*l_b programs covers the whole batch.
-        if fused:
-            keys_tiled = _st.pad_rows(
-                keys, n_row, self.pad_key(keys.dtype)
-            ).reshape(b * l_b, tile)
-            ids_tiled = None
-        else:
-            ids = self._host_labels(keys)
-            ids_tiled = _st.pad_rows(ids, n_row, m - 1).reshape(b * l_b, tile)
-            if self.mode != "reorder":
-                keys_tiled = None            # partial modes consume only ids
+        with self._stage("layout", tiles):
+            if self.label_fusion(keys):
+                keys_tiled = _st.pad_rows(
+                    keys, n_row, self.pad_key(keys.dtype)
+                ).reshape(tiles, tile)
+                ids_tiled = None
             else:
-                keys_tiled = _st.pad_rows(keys, n_row, 0).reshape(b * l_b, tile)
-        vals_tiled = None
-        if values is not None:
-            vals_tiled = _st.pad_rows(values, n_row, 0).reshape(b * l_b, tile)
+                ids = self._host_labels(keys)
+                ids_tiled = _st.pad_rows(ids, n_row, m - 1).reshape(tiles, tile)
+                if self.mode != "reorder":
+                    keys_tiled = None        # partial modes consume only ids
+                else:
+                    keys_tiled = _st.pad_rows(keys, n_row, 0).reshape(tiles, tile)
+            vals_tiled = None
+            if values is not None:
+                vals_tiled = _st.pad_rows(values, n_row, 0).reshape(tiles, tile)
 
         if self.mode == "counts_only":
-            hist = self.prescan(keys_tiled, ids_tiled)
+            with self._stage("prescan", tiles):
+                hist = self.prescan(keys_tiled, ids_tiled)
             counts = hist.reshape(b, l_b, m).sum(axis=1).astype(jnp.int32)
             counts = counts.at[:, m - 1].add(n - n_row)          # drop pad sentinels
             return MultisplitResult(None, None, _st.exclusive_rows(counts), counts, None)
 
         if self.mode == "positions_only":
-            hist = self.prescan(keys_tiled, ids_tiled)
-            g = jax.vmap(_st.global_scan)(hist.reshape(b, l_b, m)).reshape(b * l_b, m)
-            pos = get_backend(self.backend).stages.positions(
-                self, g, keys_tiled, ids_tiled, None
-            )
+            with self._stage("prescan", tiles):
+                hist = self.prescan(keys_tiled, ids_tiled)
+            with self._stage("scan", tiles):
+                g = jax.vmap(_st.global_scan)(
+                    hist.reshape(b, l_b, m)).reshape(tiles, m)
+            with self._stage("postscan", tiles):
+                pos = get_backend(self.backend).stages.positions(
+                    self, g, keys_tiled, ids_tiled, None
+                )
             counts = hist.reshape(b, l_b, m).sum(axis=1).astype(jnp.int32)
             counts = counts.at[:, m - 1].add(n - n_row)
             return MultisplitResult(
@@ -589,36 +617,37 @@ class MultisplitPlan(PipelineSpec):
             return self._call_direct(keys, values, seg_ids, segment_starts)
 
         self._check_key_width(keys)
-        fused = self.label_fusion(keys)
         n = self.n
+        tiles = -(-n // self.tile)
 
         # ---- layout stage. Pads ride in (segment s-1,) bucket m-1 at the
         # very tail, so they land after every real element and are sliced off
         # below. Fused-label plans pad with the spec's pad key (bucket m-1 by
         # construction; for the radix digit: the all-ones key, digit m-1 in
         # EVERY pass).
-        if fused:
-            keys_p, _ = _st.pad_to_tiles(keys, self.tile, self.pad_key(keys.dtype))
-            keys_tiled = keys_p.reshape(-1, self.tile)
-            ids_tiled = None
-        else:
-            ids = self._host_labels(keys)
-            ids_p, _ = _st.pad_to_tiles(ids, self.tile, m - 1)
-            ids_tiled = ids_p.reshape(-1, self.tile)
-            if self.mode != "reorder":
-                keys_tiled = None            # partial modes consume only ids
-            else:
-                keys_p, _ = _st.pad_to_tiles(keys, self.tile, 0)
+        with self._stage("layout", tiles):
+            if self.label_fusion(keys):
+                keys_p, _ = _st.pad_to_tiles(keys, self.tile, self.pad_key(keys.dtype))
                 keys_tiled = keys_p.reshape(-1, self.tile)
-        seg_tiled = None
-        if s is not None:
-            seg_p, _ = _st.pad_to_tiles(seg_ids, self.tile, s - 1)
-            seg_tiled = seg_p.reshape(-1, self.tile)
-        n_total = keys_tiled.size if keys_tiled is not None else ids_tiled.size
-        vals_tiled = None
-        if values is not None:
-            vals_p, _ = _st.pad_to_tiles(values, self.tile, 0)
-            vals_tiled = vals_p.reshape(-1, self.tile)
+                ids_tiled = None
+            else:
+                ids = self._host_labels(keys)
+                ids_p, _ = _st.pad_to_tiles(ids, self.tile, m - 1)
+                ids_tiled = ids_p.reshape(-1, self.tile)
+                if self.mode != "reorder":
+                    keys_tiled = None        # partial modes consume only ids
+                else:
+                    keys_p, _ = _st.pad_to_tiles(keys, self.tile, 0)
+                    keys_tiled = keys_p.reshape(-1, self.tile)
+            seg_tiled = None
+            if s is not None:
+                seg_p, _ = _st.pad_to_tiles(seg_ids, self.tile, s - 1)
+                seg_tiled = seg_p.reshape(-1, self.tile)
+            vals_tiled = None
+            if values is not None:
+                vals_p, _ = _st.pad_to_tiles(values, self.tile, 0)
+                vals_tiled = vals_p.reshape(-1, self.tile)
+        n_total = tiles * self.tile
 
         def finalize_counts(hist):
             counts = hist.sum(axis=0).astype(jnp.int32)
@@ -626,15 +655,20 @@ class MultisplitPlan(PipelineSpec):
 
         # ---- partial pipelines: counts_only / positions_only
         if self.mode == "counts_only":
-            counts = finalize_counts(self.prescan(keys_tiled, ids_tiled, seg_tiled))
+            with self._stage("prescan", tiles):
+                hist = self.prescan(keys_tiled, ids_tiled, seg_tiled)
+            counts = finalize_counts(hist)
             if s is not None:
                 counts = counts.reshape(s, m)
             return MultisplitResult(None, None, _st.exclusive_rows(counts), counts, None)
 
         if self.mode == "positions_only":
-            hist = self.prescan(keys_tiled, ids_tiled, seg_tiled)
-            g = _st.global_scan(hist)
-            pos = be.stages.positions(self, g, keys_tiled, ids_tiled, seg_tiled)
+            with self._stage("prescan", tiles):
+                hist = self.prescan(keys_tiled, ids_tiled, seg_tiled)
+            with self._stage("scan", tiles):
+                g = _st.global_scan(hist)
+            with self._stage("postscan", tiles):
+                pos = be.stages.positions(self, g, keys_tiled, ids_tiled, seg_tiled)
             counts = finalize_counts(hist)
             perm = pos.reshape(-1)[:n].astype(jnp.int32)
             if s is not None:

@@ -52,7 +52,7 @@ _MIN_TILE = 256
 # Kernel families (DESIGN.md §12). The family heuristic switches to packed
 # counters once the bucket axis is wide enough that the dense one-hot
 # dominates the tile working set. The flip point is the MEASURED host-bench
-# crossover (BENCH_multisplit.json packed_vs_onehot sweep re-run at
+# crossover (bench_multisplit.py packed_vs_onehot sweep re-run at
 # n ∈ {2^18, 2^20}, key-value flat multisplit): packed already wins at m=8
 # (1.12–1.25×) and only ties at m=4 — the original 64 was a working-set
 # argument that left the whole 8 ≤ m < 64 band on the slower family.

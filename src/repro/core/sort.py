@@ -35,8 +35,17 @@ from repro.core.pipeline import (
     radix_passes,
     resolve_backend,
 )
+from repro.runtime import tracing
 
 Array = jnp.ndarray
+
+
+def _op_span(op: str, n: int, radix_bits: int, keys, values):
+    """The ``repro.op`` span of an eager sort; none under a transformation."""
+    if any(isinstance(a, jax.core.Tracer) for a in (keys, values)):
+        return tracing.OFF
+    return tracing.span("repro.op", op=op, n=n, m=1 << radix_bits,
+                        key_value=values is not None)
 
 
 def radix_sort(
@@ -94,7 +103,8 @@ def radix_sort(
         family=family,
         fuse_digits=fuse_digits,
     )
-    return pipe(keys, values)
+    with _op_span("radix_sort", n, radix_bits, keys, values):
+        return pipe(keys, values)
 
 
 def segmented_radix_sort(
@@ -138,7 +148,8 @@ def segmented_radix_sort(
         family=family,
         fuse_digits=fuse_digits,
     )
-    return pipe(keys, values, segment_starts=seg)
+    with _op_span("segmented_radix_sort", keys.shape[0], radix_bits, keys, values):
+        return pipe(keys, values, segment_starts=seg)
 
 
 def radix_sort_per_pass(

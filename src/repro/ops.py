@@ -30,7 +30,7 @@ deliberate, test-visible act.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
@@ -56,6 +56,7 @@ from repro.core.identifiers import (
     range_buckets,
 )
 from repro.core.pipeline import (
+    MultisplitPlan,
     MultisplitResult,
     make_batched_plan,
     make_plan,
@@ -65,6 +66,7 @@ from repro.core.pipeline import (
 from repro.core.multisplit import _empty_segmented_result
 from repro.core.sort import radix_sort, segmented_radix_sort
 from repro.runtime import resilience as _rz
+from repro.runtime import tracing
 from repro.runtime.resilience import set_strict, set_verify
 
 Array = jnp.ndarray
@@ -103,6 +105,25 @@ def _broadcast_unbatched(x: Array, batched: bool, axis_size: int) -> Array:
     return jnp.broadcast_to(x[None], (axis_size,) + x.shape)
 
 
+def _traced(*arrays) -> bool:
+    return any(isinstance(a, jax.core.Tracer) for a in arrays if a is not None)
+
+
+class _PlanOp(NamedTuple):
+    """A resolved plan and the transform-aware op over it."""
+
+    plan: MultisplitPlan
+    op: Callable
+
+    def __call__(self, *arrays):
+        """Under a transformation, the op with its vmap and vjp rules.
+        Eagerly, the plan traced and then evaluated, as the op runs it, with
+        each stage's span open while its equations run."""
+        if _traced(*arrays):
+            return self.op(*arrays)
+        return tracing.run_staged(self.plan, *arrays)
+
+
 def _build_flat_op(spec: BucketSpec, n: int, method: str, backend: str,
                    tile: Optional[int], mode: str, family: Optional[str]):
     """The key-only op for one (spec, n, config): a custom_vmap-wrapped flat
@@ -126,7 +147,7 @@ def _build_flat_op(spec: BucketSpec, n: int, method: str, backend: str,
         res = bplan(keys)
         return res, _out_batched(res)
 
-    return op
+    return _PlanOp(plan, op)
 
 
 # Declarative specs hash by VALUE, so the cache is exact and bounded by the
@@ -137,10 +158,24 @@ def _build_flat_op(spec: BucketSpec, n: int, method: str, backend: str,
 _flat_op_cached = functools.lru_cache(maxsize=512)(_build_flat_op)
 
 
+def _lookup(cached, build, spec, *config):
+    """The op for ``(spec, *config)`` from its cache (built afresh for a
+    CallableSpec), inside a ``repro.plan`` span whose ``hit`` says whether
+    the cache held it."""
+    with tracing.span("repro.plan") as sp:
+        if isinstance(spec, CallableSpec):
+            sp.set(hit=False)
+            return build(spec, *config)
+        hits = cached.cache_info().hits if sp else 0
+        op = cached(spec, *config)
+        if sp:
+            sp.set(hit=cached.cache_info().hits > hits)
+        return op
+
+
 def _flat_op(spec, n, method, backend, tile, mode, family):
-    if isinstance(spec, CallableSpec):
-        return _build_flat_op(spec, n, method, backend, tile, mode, family)
-    return _flat_op_cached(spec, n, method, backend, tile, mode, family)
+    return _lookup(_flat_op_cached, _build_flat_op, spec, n, method, backend,
+                   tile, mode, family)
 
 
 def _ct_gather(ct_leaf, perm):
@@ -193,16 +228,15 @@ def _build_kv_op(spec: BucketSpec, n: int, method: str, backend: str,
         return _ct_gather(ct.keys, perm), _ct_gather(ct.values, perm)
 
     op.defvjp(fwd, bwd)
-    return op
+    return _PlanOp(plan, op)
 
 
 _kv_op_cached = functools.lru_cache(maxsize=512)(_build_kv_op)
 
 
 def _kv_op(spec, n, method, backend, tile, family):
-    if isinstance(spec, CallableSpec):               # see _flat_op
-        return _build_kv_op(spec, n, method, backend, tile, family)
-    return _kv_op_cached(spec, n, method, backend, tile, family)
+    return _lookup(_kv_op_cached, _build_kv_op, spec, n, method, backend,
+                   tile, family)
 
 
 def _check_flat(keys: Array, what: str) -> None:
@@ -213,20 +247,17 @@ def _check_flat(keys: Array, what: str) -> None:
         )
 
 
-def _traced(*arrays) -> bool:
-    return any(isinstance(a, jax.core.Tracer) for a in arrays if a is not None)
-
-
 def _resilient(
     run, keys: Array, values: Optional[Array], spec: BucketSpec, *,
-    n: int, method: str, backend: str, tile: Optional[int], key_value: bool,
-    mode: str, segments: Optional[int] = None, segment_starts=None,
+    op: str, n: int, method: str, backend: str, tile: Optional[int],
+    key_value: bool, mode: str, segments: Optional[int] = None,
+    segment_starts=None,
 ):
     """Route one eager facade call through the degradation ladder + runtime
     verification (DESIGN.md §17): ``run(backend, tile)`` re-executes the op
     on any rung.  Under a jax trace the ladder is bypassed — exceptions
     cannot cross a trace, and the transform rules (vmap/jit/grad) must see
-    the plain op."""
+    the plain op.  Eagerly the call is the ``repro.op`` span ``op``."""
     if _traced(keys, values, segment_starts):
         return run(backend, tile)
     m_eff = spec.num_buckets * (segments or 1)
@@ -253,10 +284,12 @@ def _resilient(
             segment_starts=segment_starts, mode=mode, backend=be, ctx=ctx,
         )
 
-    return _rz.dispatch(
-        run, ctx, backend=backend, tile=tile, resolved_tile=resolved_tile,
-        pin_tile=pin_tile, verifier=verifier,
-    )
+    with tracing.span("repro.op", op=op, n=n, m=spec.num_buckets,
+                      key_value=key_value):
+        return _rz.dispatch(
+            run, ctx, backend=backend, tile=tile, resolved_tile=resolved_tile,
+            pin_tile=pin_tile, verifier=verifier,
+        )
 
 
 
@@ -295,8 +328,8 @@ def multisplit(
     n = keys.shape[0]
     return _resilient(
         lambda be, tl: _flat_op(spec, n, method, be, tl, mode, family)(keys),
-        keys, None, spec, n=n, method=method, backend=backend, tile=tile,
-        key_value=False, mode=mode,
+        keys, None, spec, op="multisplit", n=n, method=method,
+        backend=backend, tile=tile, key_value=False, mode=mode,
     )
 
 
@@ -323,8 +356,8 @@ def multisplit_key_value(
     n = keys.shape[0]
     return _resilient(
         lambda be, tl: _kv_op(spec, n, method, be, tl, family)(keys, values),
-        keys, values, spec, n=n, method=method, backend=backend, tile=tile,
-        key_value=True, mode="reorder",
+        keys, values, spec, op="multisplit_key_value", n=n, method=method,
+        backend=backend, tile=tile, key_value=True, mode="reorder",
     )
 
 
@@ -357,16 +390,18 @@ def segmented_multisplit(
     n, s = keys.shape[0], int(seg.shape[0])
 
     def run(be, tl):
-        plan = make_segmented_plan(
-            n, s, spec.num_buckets, method=method,
-            key_value=values is not None, backend=be, tile=tl,
-            bucket_fn=spec, mode=mode, family=family,
-        )
+        with tracing.span("repro.plan", hit=False):       # not cached
+            plan = make_segmented_plan(
+                n, s, spec.num_buckets, method=method,
+                key_value=values is not None, backend=be, tile=tl,
+                bucket_fn=spec, mode=mode, family=family,
+            )
         return plan(keys, values, segment_starts=seg)
 
     return _resilient(
-        run, keys, values, spec, n=n, method=method, backend=backend,
-        tile=tile, key_value=values is not None, mode=mode, segments=s,
+        run, keys, values, spec, op="segmented_multisplit", n=n,
+        method=method, backend=backend, tile=tile,
+        key_value=values is not None, mode=mode, segments=s,
         segment_starts=seg,
     )
 

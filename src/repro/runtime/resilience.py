@@ -71,6 +71,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.runtime import tracing
+
 log = logging.getLogger("repro.resilience")
 
 SCHEMA_VERSION = 1
@@ -567,11 +569,12 @@ def dispatch(
     """
     level = verify_level()
     if strict():
-        check_faults(backend)
-        result = run(backend, tile)
-        if verifier is not None and level > 0 and backend != "reference":
-            _count("verify_checks")
-            verifier(_block(result), backend)
+        with tracing.span("repro.dispatch", backend=backend, tile=tile, attempt=0):
+            check_faults(backend)
+            result = run(backend, tile)
+            if verifier is not None and level > 0 and backend != "reference":
+                _count("verify_checks")
+                verifier(_block(result), backend)
         return result
 
     from repro.core.pipeline.tiles import _MIN_TILE
@@ -580,6 +583,7 @@ def dispatch(
     transient_left = MAX_TRANSIENT_RETRIES
     shrunk = False
     degraded = False
+    attempt = 0                     # rungs tried, each one ``repro.dispatch`` span
     # sync inside the try whenever a failure is plausible or must be caught
     # here: verification armed, faults armed, or already degraded once.
     while True:
@@ -595,18 +599,20 @@ def dispatch(
             degraded = True
             continue
         try:
-            check_faults(b)
-            result = run(b, t)
-            sync = degraded or (level > 0) or (_FAULT_INJECTOR is not None)
-            if sync:
-                _block(result)
-            if verifier is not None and level > 0 and b != "reference":
-                _count("verify_checks")
-                verifier(result, b)
+            with tracing.span("repro.dispatch", backend=b, tile=t, attempt=attempt):
+                check_faults(b)
+                result = run(b, t)
+                sync = degraded or (level > 0) or (_FAULT_INJECTOR is not None)
+                if sync:
+                    _block(result)
+                if verifier is not None and level > 0 and b != "reference":
+                    _count("verify_checks")
+                    verifier(result, b)
             if shrunk and t is not None and pin_tile is not None:
                 pin_tile(b, t)
             return result
         except Exception as exc:  # noqa: BLE001 — the resilience boundary
+            attempt += 1
             err = classify(exc, backend=b, plan_class=ctx.plan_class())
             if err is None or b == "reference":
                 raise
@@ -619,7 +625,9 @@ def dispatch(
                 _event("verify_fallback", backend=b, spec=ctx.spec_name,
                        shape=ctx.shape, detail=str(err))
                 log.warning("verify mismatch on %r; recovering via reference", b)
-                return _block(run("reference", None))
+                with tracing.span("repro.dispatch", backend="reference",
+                                  attempt=attempt):
+                    return _block(run("reference", None))
             if err.transient and transient_left > 0:
                 transient_left -= 1
                 _count("transient_retries")

@@ -2,11 +2,9 @@
 per-request counters the continuous-batching loop exports (DESIGN.md §16).
 
 Everything here is host-side bookkeeping — nothing touches jax. The summary
-dict is the unit the serving bench appends (git-stamped through
-``benchmarks/common.py``) to ``BENCH_multisplit.json``, so its keys are part
-of the trajectory schema: latency percentiles in milliseconds, sustained
-QPS, queue/batch occupancy, and the robustness counters (shed / retried /
-requeued / failed).
+dict is what the serving bench (``benchmarks/bench_serving.py``) reports:
+latency percentiles in milliseconds, sustained QPS, queue/batch occupancy,
+and the robustness counters (shed / retried / requeued / failed).
 """
 
 from __future__ import annotations
@@ -129,7 +127,7 @@ class ServingMetrics:
         return tok, req
 
     def summary(self) -> Dict[str, float]:
-        """The exported metrics dict (the BENCH trajectory unit)."""
+        """The exported metrics dict."""
         pct = percentiles(self.latencies_s)
         lat = self.latencies_s
         wall = 0.0

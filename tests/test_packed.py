@@ -377,8 +377,8 @@ def test_packed_min_buckets_matches_measured_crossover():
     """Regression pin for the MEASURED family crossover (ISSUE 6 satellite).
 
     The original flip point (64) was a working-set argument; the host-bench
-    packed_vs_onehot sweep (BENCH_multisplit.json, key-value flat multisplit
-    re-measured at n ∈ {2^18, 2^20}) shows packed winning from m=8 up
+    packed_vs_onehot sweep (benchmarks/bench_multisplit.py, key-value flat
+    multisplit re-measured at n ∈ {2^18, 2^20}) shows packed winning from m=8 up
     (1.12–1.25× at m=8, ≥1.5× at m=16) and only tying at m=4. If this pin
     fails, re-run ``benchmarks/bench_multisplit.py`` packed_vs_onehot and
     move the constant to the new measured crossover — don't guess."""
