@@ -7,8 +7,11 @@ One process, seeded data made on the device, public entry points only:
 
 * multisplit: ``repro.ops.multisplit`` / ``multisplit_key_value`` over
   n = 2^25 uniform uint32 keys (the paper's headline size), m in
-  {2, 32, 256} delta buckets, on the compiled ``pallas`` backend and on the
-  default backend, each against a numpy stable partition by bucket id;
+  {2, 32, 256} delta buckets, on the compiled ``pallas`` backend and on
+  ``vmap``, each against a numpy stable partition by bucket id;
+* the default path: ``multisplit_key_value`` (m = 256) and ``radix_sort``
+  over the same keys with no backend named, jitted and eager, which must
+  resolve to ``pallas`` and run its kernels;
 * radix sort of 2^25 keys and key-value pairs, chained (r = 8) and with
   fused digit pairs (r = 4), against ``np.sort`` / a stable ``np.argsort``;
 * histogram, m = 256, against ``np.bincount``;
@@ -148,6 +151,42 @@ def phase_radix(ops, keys, vals, ref: Reference) -> None:
                    fuse_digits=fuse, key_value=kv, compile_s=f"{c_s:.2f}",
                    run_s=f"{r_s:.4f}", agree=agree)
             check(agree, f"radix sort r={radix_bits} fuse={fuse} kv={kv} disagrees")
+
+
+def phase_default(ops, keys, vals, ref: Reference) -> None:
+    import jax
+
+    from repro.core.pipeline import backend_decisions
+
+    m = 256
+    order = ref.order(m)
+    want_keys = np.sort(ref.keys, kind="stable")
+    kv = functools.partial(ops.multisplit_key_value,
+                           spec=ops.delta_buckets(m, key_max=1 << 32))
+    res, c_s, r_s = compile_and_run(kv, keys, vals, pallas=True)
+    agree = (np.array_equal(np.asarray(res.keys), ref.keys[order])
+             and np.array_equal(np.asarray(res.values), order))
+    report("default", op="multisplit_key_value", n=N, m=m, jit=True,
+           compile_s=f"{c_s:.2f}", run_s=f"{r_s:.4f}", agree=agree)
+    del res
+    for name, fn, args in (("multisplit_key_value", kv, (keys, vals)),
+                           ("radix_sort", ops.radix_sort, (keys,))):
+        jax.block_until_ready(fn(*args))               # warm-up
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        e_s = time.perf_counter() - t0
+        if name == "radix_sort":
+            agree = agree and np.array_equal(np.asarray(out[0]), want_keys)
+        else:
+            agree = (agree and np.array_equal(np.asarray(out.keys), ref.keys[order])
+                     and np.array_equal(np.asarray(out.values), order))
+        del out
+        decision = backend_decisions().get((N, str(keys.dtype)))
+        report("default", op=name, n=N, m=m, decision=decision,
+               eager_s=f"{e_s:.4f}", agree=agree)
+        check(decision == ("pallas", "tpu+32-bit keys"),
+              f"default {name} resolved to {decision}, not the compiled kernels")
+    check(agree, "the default path disagrees with numpy")
 
 
 def phase_histogram(ops, keys, ref: Reference) -> None:
@@ -294,6 +333,7 @@ def run_one_chip(seed: int) -> None:
     ref = Reference(np.asarray(keys))
     phase_multisplit(ops, keys, vals, ref)
     phase_radix(ops, keys, vals, ref)
+    phase_default(ops, keys, vals, ref)
     phase_histogram(ops, keys, ref)
     del keys, vals, ref
     phase_server(ops, seed)

@@ -36,7 +36,9 @@ from repro.core.pipeline.registry import (
     StageImpl,
     VmapStages,
     available_backends,
+    backend_decisions,
     backend_names,
+    default_backend,
     get_backend,
     register_backend,
     resolve_backend,
@@ -89,10 +91,11 @@ __all__ = [
     "MultisplitPlan", "MultisplitResult", "PipelineSpec", "RadixPipeline",
     "Stage", "StageImpl", "VMAP_FUSION_MAX_BUCKETS", "VmapStages", "WMS_TILE",
     "autotune_fused2", "autotune_label_fusion", "autotune_status",
-    "autotune_tile", "available_backends", "backend_names",
-    "clear_tile_cache", "direct_counts", "direct_solve_ids",
-    "direct_solve_reference", "exclusive_rows", "family_decision",
-    "family_decisions", "fusion_decision", "fusion_decisions",
+    "autotune_tile", "available_backends", "backend_decisions",
+    "backend_names", "clear_tile_cache", "default_backend", "direct_counts",
+    "direct_solve_ids", "direct_solve_reference", "exclusive_rows",
+    "family_decision", "family_decisions", "fusion_decision",
+    "fusion_decisions",
     "get_backend", "global_scan",
     "make_batched_plan", "make_plan", "make_radix_plan",
     "make_segmented_plan", "make_segmented_radix_plan",

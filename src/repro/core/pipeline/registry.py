@@ -542,6 +542,38 @@ def capability_summary() -> dict:
     }
 
 
+# (n, key dtype) -> (backend, reason) of each default_backend call so far.
+_BACKEND_DECISIONS: dict = {}
+
+
+def default_backend(n: int, key_dtype) -> str:
+    """The backend of a call that names none: ``pallas`` where the kernels
+    lower compiled (a TPU is attached and ``REPRO_INTERPRET`` does not force
+    interpret mode) and the keys are 32-bit, the only width they take; else
+    ``vmap``. Each decision is recorded with its reason
+    (:func:`backend_decisions`)."""
+    from repro.kernels import ops as kops
+
+    dtype = jnp.dtype(key_dtype)
+    bits = 8 * dtype.itemsize
+    if not kops._tpu_available():
+        backend, reason = "vmap", "no TPU"
+    elif kops.resolve_interpret(True):
+        backend, reason = "vmap", "REPRO_INTERPRET"
+    elif bits != 32:
+        backend, reason = "vmap", f"{bits}-bit keys"
+    else:
+        backend, reason = "pallas", "tpu+32-bit keys"
+    _BACKEND_DECISIONS[(n, dtype.name)] = (backend, reason)
+    return backend
+
+
+def backend_decisions() -> dict:
+    """Snapshot of every ((n, key dtype) -> (backend, reason)) default so
+    far."""
+    return dict(_BACKEND_DECISIONS)
+
+
 def resolve_backend(
     use_pallas: bool = False, interpret: bool = True, backend: Optional[str] = None
 ) -> str:

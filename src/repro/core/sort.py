@@ -30,6 +30,7 @@ from repro.core import multisplit as ms
 from repro.core.identifiers import BucketSpec
 from repro.core.pipeline import (
     RadixPipeline,
+    default_backend,
     make_radix_plan,
     make_segmented_radix_plan,
     radix_passes,
@@ -40,12 +41,23 @@ from repro.runtime import tracing
 Array = jnp.ndarray
 
 
-def _op_span(op: str, n: int, radix_bits: int, keys, values):
+def _backend(use_pallas: bool, interpret: bool, backend: Optional[str],
+             n: int, keys) -> Tuple[str, bool]:
+    """The backend a sort runs on, and whether the default chose it: the
+    one named or the legacy knobs select, else :func:`default_backend`."""
+    if use_pallas or backend is not None:
+        return resolve_backend(use_pallas, interpret, backend), False
+    return default_backend(n, keys.dtype), True
+
+
+def _op_span(op: str, n: int, radix_bits: int, keys, values, backend: str,
+             auto: bool):
     """The ``repro.op`` span of an eager sort; none under a transformation."""
     if any(isinstance(a, jax.core.Tracer) for a in (keys, values)):
         return tracing.OFF
     return tracing.span("repro.op", op=op, n=n, m=1 << radix_bits,
-                        key_value=values is not None)
+                        key_value=values is not None, backend=backend,
+                        auto=auto)
 
 
 def radix_sort(
@@ -85,12 +97,16 @@ def radix_sort(
     per tile residency, one HBM scatter per pair instead of per digit
     (r=8 → 2 sweeps instead of 4, plus a trailing single pass for odd
     schedules). Bitwise identical to the unfused sort on every backend.
+
+    With no ``backend`` named (and ``use_pallas`` off) the sort runs where
+    :func:`~repro.core.pipeline.default_backend` sends it: the compiled
+    ``pallas`` kernels on a TPU for 32-bit keys, ``vmap`` otherwise.
     """
-    resolved = resolve_backend(use_pallas, interpret, backend)
     if keys.ndim == 2:
         batch, n = keys.shape
     else:
         batch, n = None, keys.shape[0]
+    resolved, auto = _backend(use_pallas, interpret, backend, n, keys)
     pipe = RadixPipeline(
         n,
         radix_bits=radix_bits,
@@ -103,7 +119,7 @@ def radix_sort(
         family=family,
         fuse_digits=fuse_digits,
     )
-    with _op_span("radix_sort", n, radix_bits, keys, values):
+    with _op_span("radix_sort", n, radix_bits, keys, values, resolved, auto):
         return pipe(keys, values)
 
 
@@ -132,9 +148,9 @@ def segmented_radix_sort(
     the chained pipeline computes the position-keyed segment buffer once and
     keeps it — with the padded keys/values — resident for all passes.
     Stable; bitwise identical to slicing out each segment and running
-    :func:`radix_sort` on it.
+    :func:`radix_sort` on it. The backend defaults as in :func:`radix_sort`.
     """
-    resolved = resolve_backend(use_pallas, interpret, backend)
+    resolved, auto = _backend(use_pallas, interpret, backend, keys.shape[0], keys)
     seg = jnp.asarray(segment_starts, jnp.int32)
     pipe = RadixPipeline(
         keys.shape[0],
@@ -148,7 +164,8 @@ def segmented_radix_sort(
         family=family,
         fuse_digits=fuse_digits,
     )
-    with _op_span("segmented_radix_sort", keys.shape[0], radix_bits, keys, values):
+    with _op_span("segmented_radix_sort", keys.shape[0], radix_bits, keys, values,
+                  resolved, auto):
         return pipe(keys, values, segment_starts=seg)
 
 

@@ -104,7 +104,8 @@ def main(argv=None):
     ap.add_argument("--max-batch-requests", type=int, default=64)
     ap.add_argument("--max-batch-tokens", type=int, default=4096)
     ap.add_argument("--max-wait", type=float, default=0.02)
-    ap.add_argument("--backend", default="vmap")
+    ap.add_argument("--backend", default=None,
+                    help="plan backend (default: pallas on a TPU, else vmap)")
     ap.add_argument("--fault-rate", type=float, default=0.0)
     args = ap.parse_args(argv)
     enable_compile_cache()

@@ -151,7 +151,7 @@ def _ranks_multisplit(
 
 def _segmented_ranks(
     expert_ids: Array, seg: Array, num_experts: int, tile: int,
-    backend: str = "vmap",
+    backend: Optional[str] = None,
 ) -> Tuple[Array, Array, Array]:
     """One segmented ``positions_only`` ``repro.ops`` call -> (ranks, (s, e)
     counts, seg_ids); the derived per-token segment id is returned so
@@ -175,7 +175,7 @@ def route_tokens_segmented(
     num_experts: int,
     capacity: int,
     *,
-    backend: str = "vmap",
+    backend: Optional[str] = None,
 ) -> Tuple[Array, Array, Array]:
     """Per-request token routing: ONE segmented multisplit call assigns every
     virtual token a slot in its request's (expert, capacity) block.
@@ -191,7 +191,8 @@ def route_tokens_segmented(
     calls it once per step (ROADMAP "heavy traffic"). ``s == 0`` (a
     zero-request step) returns empty slots and (0, E) counts; zero-length
     segments (a user with no tokens this step) get all-zero count rows.
-    ``backend`` selects the plan backend of the one segmented launch.
+    ``backend`` selects the plan backend of the one segmented launch
+    (``None``: the ``repro.ops`` default).
     """
     n = expert_ids.shape[0]
     seg = jnp.asarray(segment_starts, jnp.int32)

@@ -19,6 +19,9 @@ JAX transforms wired in as first-class citizens rather than afterthoughts:
 
 Execution is unchanged underneath: every op resolves a
 :class:`~repro.core.pipeline.MultisplitPlan` through the backend registry.
+An op that names no ``backend`` runs where
+:func:`~repro.core.pipeline.default_backend` sends it: the compiled
+``pallas`` kernels on a TPU for 32-bit keys, ``vmap`` otherwise.
 Ops are cached per (spec, shape, config) — hashable specs make the cache
 exact, not identity-based.
 
@@ -58,6 +61,7 @@ from repro.core.identifiers import (
 from repro.core.pipeline import (
     MultisplitPlan,
     MultisplitResult,
+    default_backend,
     make_batched_plan,
     make_plan,
     make_segmented_plan,
@@ -249,7 +253,7 @@ def _check_flat(keys: Array, what: str) -> None:
 
 def _resilient(
     run, keys: Array, values: Optional[Array], spec: BucketSpec, *,
-    op: str, n: int, method: str, backend: str, tile: Optional[int],
+    op: str, n: int, method: str, backend: Optional[str], tile: Optional[int],
     key_value: bool, mode: str, segments: Optional[int] = None,
     segment_starts=None,
 ):
@@ -257,7 +261,12 @@ def _resilient(
     verification (DESIGN.md §17): ``run(backend, tile)`` re-executes the op
     on any rung.  Under a jax trace the ladder is bypassed — exceptions
     cannot cross a trace, and the transform rules (vmap/jit/grad) must see
-    the plain op.  Eagerly the call is the ``repro.op`` span ``op``."""
+    the plain op.  Eagerly the call is the ``repro.op`` span ``op``, with
+    the backend it starts on and whether the default chose it (``auto``).
+    ``backend=None`` takes :func:`~repro.core.pipeline.default_backend`."""
+    auto = backend is None
+    if auto:
+        backend = default_backend(n, keys.dtype)
     if _traced(keys, values, segment_starts):
         return run(backend, tile)
     m_eff = spec.num_buckets * (segments or 1)
@@ -285,7 +294,7 @@ def _resilient(
         )
 
     with tracing.span("repro.op", op=op, n=n, m=spec.num_buckets,
-                      key_value=key_value):
+                      key_value=key_value, backend=backend, auto=auto):
         return _rz.dispatch(
             run, ctx, backend=backend, tile=tile, resolved_tile=resolved_tile,
             pin_tile=pin_tile, verifier=verifier,
@@ -300,7 +309,7 @@ def multisplit(
     values: Optional[Array] = None,
     *,
     method: str = "bms",
-    backend: str = "vmap",
+    backend: Optional[str] = None,
     tile: Optional[int] = None,
     mode: str = "reorder",
     family: Optional[str] = None,
@@ -339,7 +348,7 @@ def multisplit_key_value(
     spec: BucketSpec,
     *,
     method: str = "bms",
-    backend: str = "vmap",
+    backend: Optional[str] = None,
     tile: Optional[int] = None,
     family: Optional[str] = None,
 ) -> MultisplitResult:
@@ -368,7 +377,7 @@ def segmented_multisplit(
     values: Optional[Array] = None,
     *,
     method: str = "bms",
-    backend: str = "vmap",
+    backend: Optional[str] = None,
     tile: Optional[int] = None,
     mode: str = "reorder",
     family: Optional[str] = None,
@@ -410,7 +419,7 @@ def histogram(
     keys: Array,
     spec: BucketSpec,
     *,
-    backend: str = "vmap",
+    backend: Optional[str] = None,
     tile: Optional[int] = None,
     family: Optional[str] = None,
 ) -> Array:

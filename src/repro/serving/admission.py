@@ -49,7 +49,7 @@ LOOKAHEAD_BATCHES = 4
 
 
 @functools.lru_cache(maxsize=64)
-def _bucketing_op(spec, backend: str):
+def _bucketing_op(spec, backend: Optional[str]):
     """The jitted (lengths, idx) -> bucket-major reorder for one (spec,
     backend): specs hash by value, jit retraces only per padded depth —
     admission pays microseconds per step, not an eager pipeline walk."""
@@ -69,7 +69,7 @@ class AdmissionConfig:
     # RangeSpec splitters over request LENGTH (ascending). () disables
     # bucketing (pure FIFO admission).
     length_splitters: Tuple[int, ...] = (32, 128)
-    backend: str = "vmap"
+    backend: Optional[str] = None                # None: the ops default
     lookahead_batches: int = LOOKAHEAD_BATCHES
 
     def __post_init__(self) -> None:

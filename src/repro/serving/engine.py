@@ -34,6 +34,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core.pipeline import default_backend
 from repro.runtime import resilience as _rz
 from repro.serving.admission import AdmissionConfig, AdmissionPolicy
 from repro.serving.metrics import ServingMetrics, StepRecord
@@ -99,7 +100,7 @@ class ServingConfig:
     max_wait: float = 0.02           # flush deadline (s)
     length_splitters: Tuple[int, ...] = (32, 128)
     token_pad_classes: Tuple[int, ...] = ()     # () -> derived ladder
-    backend: str = "vmap"
+    backend: Optional[str] = None    # None -> default_backend at the batch cap
     max_step_attempts: int = 3       # in-step launch tries (1 = no retry)
     max_requeues: int = 1            # failed-step requeues before a request fails
     max_queue_depth: int = 4096
@@ -108,6 +109,9 @@ class ServingConfig:
     verify_seed: int = 0             # is armed (DESIGN.md §17)
 
     def __post_init__(self) -> None:
+        if self.backend is None:
+            object.__setattr__(self, "backend", default_backend(
+                self.max_batch_tokens, np.int32))
         if not self.token_pad_classes:
             object.__setattr__(
                 self, "token_pad_classes",

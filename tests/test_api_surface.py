@@ -26,19 +26,21 @@ EXPECTED_SIGNATURES = {
     # DESIGN.md §12) to every plan-backed op, per the §11 stability policy.
     # ISSUE 6 additively appended keyword-only ``fuse_digits`` (fused
     # two-digit radix pairs, DESIGN.md §13) to the two radix sorts.
+    # ``backend=None`` takes the platform default (``default_backend``:
+    # compiled pallas on a TPU for 32-bit keys, else vmap).
     "multisplit": (
-        "(keys, spec, values=None, *, method='bms', backend='vmap', "
+        "(keys, spec, values=None, *, method='bms', backend=None, "
         "tile=None, mode='reorder', family=None)"
     ),
     "multisplit_key_value": (
-        "(keys, values, spec, *, method='bms', backend='vmap', tile=None, "
+        "(keys, values, spec, *, method='bms', backend=None, tile=None, "
         "family=None)"
     ),
     "segmented_multisplit": (
         "(keys, spec, segment_starts, values=None, *, method='bms', "
-        "backend='vmap', tile=None, mode='reorder', family=None)"
+        "backend=None, tile=None, mode='reorder', family=None)"
     ),
-    "histogram": "(keys, spec, *, backend='vmap', tile=None, family=None)",
+    "histogram": "(keys, spec, *, backend=None, tile=None, family=None)",
     "radix_sort": (
         "(keys, values=None, *, radix_bits=8, key_bits=32, method='bms', "
         "use_pallas=False, interpret=True, backend=None, tile=None, "

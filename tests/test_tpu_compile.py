@@ -86,6 +86,7 @@ CASES = {
     "flat-m32-kv": lambda: _flat(32, True),
     "flat-m256-keys": lambda: _flat(256, False),
     "flat-m256-kv": lambda: _flat(256, True),
+    "radix-pass-keys": lambda: _radix_pass(False),
     "radix-pass-kv": lambda: _radix_pass(True),
     "segmented-routing": None,
 }

@@ -88,6 +88,7 @@ def test_multisplit_spans_nest_as_the_call_runs(profiled):
         assert name == "repro.op"
         assert stats["op"] == "multisplit_key_value"
         assert (stats["n"], stats["m"], stats["key_value"]) == (N, M, 1)
+        assert (stats["backend"], stats["auto"]) == ("vmap", 1)
         (dispatch,) = children
         assert dispatch[0] == "repro.dispatch"
         assert dispatch[1]["backend"] == "vmap" and dispatch[1]["attempt"] == 0
@@ -103,6 +104,7 @@ def test_radix_sort_spans_one_pass_per_digit(profiled):
     (op,) = sort.spans
     assert op[0] == "repro.op" and op[1]["op"] == "radix_sort"
     assert (op[1]["n"], op[1]["m"], op[1]["key_value"]) == (N, 256, 0)
+    assert (op[1]["backend"], op[1]["auto"]) == ("vmap", 1)
     passes = op[2][1:]
     assert _names(op[2]) == ["repro.stage.layout"] + ["repro.sort.pass"] * 4
     assert [(p[1]["shift"], p[1]["bits"]) for p in passes] == [
