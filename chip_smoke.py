@@ -27,7 +27,8 @@ is absorbed by the degradation ladder, and every resilience counter must
 stay 0. Any failure exits non-zero without printing a result. The last line
 of standard output is one JSON object naming the device.
 
-``--chips 4`` runs ``multisplit_sharded`` (dense all-to-all),
+``--chips 4`` runs ``multisplit_sharded`` (dense all-to-all) with default
+arguments, whose local stage must resolve to ``pallas``,
 ``multisplit_bucket_sharded`` (``ragged_all_to_all``) over 2^25 keys in total
 and the expert-parallel MoE dispatch on a 4-device mesh, against the same
 one-device references, and checks that every array spans all four devices.
@@ -358,6 +359,7 @@ def run_four_chips(seed: int) -> None:
 
     from repro import ops
     from repro.core import distributed as dist
+    from repro.core.pipeline import backend_decisions
 
     devices = jax.devices()
     check(len(devices) == 4, f"--chips 4 needs 4 devices, found {len(devices)}")
@@ -372,13 +374,16 @@ def run_four_chips(seed: int) -> None:
     spec = ops.delta_buckets(m, key_max=1 << 32)
     order = ref.order(m)
 
-    fn = dist.make_multisplit_sharded(spec, mesh, "x", key_value=True, backend="pallas")
+    fn = dist.make_multisplit_sharded(spec, mesh, "x", key_value=True)
     res, c_s, r_s = compile_and_run(fn, keys, vals, pallas=True)
+    decision = backend_decisions().get((N // 4, "uint32"))
     agree = (_spans(res.keys, devices)
              and np.array_equal(np.asarray(res.keys), ref.keys[order])
              and np.array_equal(np.asarray(res.values), order))
     report("multisplit_sharded", devices=4, n=N, m=m, transport="dense",
-           compile_s=f"{c_s:.2f}", run_s=f"{r_s:.4f}", agree=agree)
+           backend=decision, compile_s=f"{c_s:.2f}", run_s=f"{r_s:.4f}", agree=agree)
+    check(decision == ("pallas", "tpu+32-bit keys"),
+          f"the sharded local stage did not default to pallas: {decision}")
     check(agree, "multisplit_sharded disagrees with the one-device reference")
 
     ids = ref.bucket_ids(m)

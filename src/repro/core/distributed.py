@@ -25,9 +25,26 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.identifiers import BucketSpec, IdentitySpec
-from repro.core.pipeline import MultisplitResult, make_plan, resolve_backend
+from repro.core.pipeline import (
+    MultisplitResult,
+    default_backend,
+    make_plan,
+    resolve_backend,
+)
+from repro.runtime import tracing
 
 Array = jnp.ndarray
+
+
+def _backend(use_pallas: Optional[bool], backend: Optional[str], n: int,
+             key_dtype) -> str:
+    """A local stage's backend: the one named, else the legacy knob's
+    (``use_pallas=True`` the ``pallas`` kernels, compiled where a TPU is
+    attached; ``False`` ``vmap``), else :func:`default_backend` of the
+    stage's ``n`` and key dtype, as the ``repro.ops`` facade chooses."""
+    if backend is not None or use_pallas is not None:
+        return resolve_backend(bool(use_pallas), False, backend)
+    return default_backend(n, key_dtype)
 
 
 def multisplit_all_shards(
@@ -36,7 +53,7 @@ def multisplit_all_shards(
     values: Optional[Array] = None,
     *,
     method: str = "bms",
-    use_pallas: bool = False,
+    use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
 ) -> MultisplitResult:
@@ -64,7 +81,7 @@ def multisplit_all_shards(
         bucket_fn.num_buckets,
         method=method,
         key_value=values is not None,
-        backend=resolve_backend(use_pallas, True, backend),
+        backend=_backend(use_pallas, backend, n_shard, keys.dtype),
         tile=tile,
         bucket_fn=bucket_fn,
         batch=d_num,
@@ -105,15 +122,8 @@ def multisplit_all_shards(
     return MultisplitResult(keys_out, values_out, g_flat, totals, perm.reshape(-1))
 
 
-def _local_plan(
-    keys: Array,
-    bucket_fn: BucketSpec,
-    values,
-    method: str,
-    use_pallas: bool,
-    backend,
-    tile,
-):
+def _local_plan(keys: Array, bucket_fn: BucketSpec, values, method: str,
+                backend: str, tile):
     """The per-device local stage IS a multisplit plan (DESIGN.md §3/§7):
     the device shard is one subproblem of the same {prescan, scan, postscan}
     pipeline that tiles are — so it is built from the shared plan layer
@@ -123,7 +133,7 @@ def _local_plan(
         bucket_fn.num_buckets,
         method=method,
         key_value=values is not None,
-        backend=resolve_backend(use_pallas, True, backend),
+        backend=backend,
         tile=tile,
         bucket_fn=bucket_fn,
     )
@@ -168,14 +178,24 @@ def _expand(mask, ndim):
     return mask.reshape(mask.shape + (1,) * (ndim - 1))
 
 
+def _count_exchange(shipped: Array, payload: Array) -> None:
+    """The exchange counters of one array moved (added once per trace):
+    ``exchange_shipped_bytes``, the operand bytes of the transport's
+    collectives on this chip, padding and positions included, and
+    ``exchange_payload_bytes``, the bytes of the array they carry."""
+    tracing.count(exchange_shipped_bytes=int(shipped),
+                  exchange_payload_bytes=int(payload))
+
+
 def _transport_dense_positions(buf, positions, in_off, send, axis_name):
-    """Position-carrying dense transport (XLA:CPU-compilable fallback).
+    """Position-carrying dense transport (XLA:CPU-compilable).
 
     Each source's run for destination d is one contiguous local segment
     (guaranteed by the local reorder); we pad each segment to the shard size,
     ship (data, global position) with a dense ``all_to_all``, and the
     receiver scatters by position. Correct for any interleaving at the
-    destination — used on CPU and as the DMS (no-ragged-possible) baseline.
+    destination. Per chip it ships D·n_dev elements and as many positions
+    for every array it moves, D times the array's own bytes and more.
     """
     n_dev = buf.shape[0]
     d_num = send.shape[0]
@@ -189,6 +209,7 @@ def _transport_dense_positions(buf, positions, in_off, send, axis_name):
 
     send_buf = pack(buf, 0)
     send_pos = pack(positions, -1)
+    _count_exchange(send_buf.nbytes + send_pos.nbytes, buf.nbytes)
     recv_buf = jax.lax.all_to_all(send_buf, axis_name, split_axis=0, concat_axis=0)
     recv_pos = jax.lax.all_to_all(send_pos, axis_name, split_axis=0, concat_axis=0)
     my_idx = jax.lax.axis_index(axis_name)
@@ -205,7 +226,7 @@ def multisplit_sharded(
     *,
     axis_name: str,
     method: str = "bms",
-    use_pallas: bool = False,
+    use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
     transport: str = "dense",
@@ -216,38 +237,50 @@ def multisplit_sharded(
     device's equal-size shard. Output: shard ``d`` of the result holds global
     positions ``[d*n_dev, (d+1)*n_dev)`` of the bucket-major output.
 
-    ``transport="dense"`` ships (data, position) pairs with a padded dense
-    ``all_to_all`` (XLA:CPU-compilable). ``transport="ragged"`` (TPU target)
-    composes two single-segment ``ragged_all_to_all`` hops: a bucket-sharded
-    hop (see :func:`multisplit_bucket_sharded`) followed by an equal-shard
-    rebalance — each hop's per-peer payload is one contiguous run, which is
-    exactly the paper's reorder-for-coalescing property lifted to ICI.
+    The local stage runs ``backend``, else the one ``use_pallas`` selects,
+    else :func:`default_backend` of the shard (the compiled ``pallas``
+    kernels on a TPU for 32-bit keys). ``transport="dense"``, the only one,
+    ships each array with a dense ``all_to_all``: every chip's run for each
+    peer is padded to a full shard and shipped with the global position of
+    every element, once for the keys and once more for the values (the
+    compiler may merge the two), and the receiver scatters by position. A
+    chip thus ships D shards of data and D of positions per array, where
+    about (D-1)/D of one shard leaves it.
     """
+    if transport != "dense":
+        raise ValueError(
+            f"multisplit_sharded supports transport='dense' only, got {transport!r}")
     n_dev = keys.shape[0]
+    d_num = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
+    m = bucket_fn.num_buckets
+    name = _backend(use_pallas, backend, n_dev, keys.dtype)
 
     # ---- local stage: reorder shard bucket-major, get local histogram ----
-    local = _local_plan(keys, bucket_fn, values, method, use_pallas, backend, tile)
+    local = _local_plan(keys, bucket_fn, values, method, name, tile)
 
-    # ---- global stage: ONE tiny collective over H (D, m) + replicated scan ----
-    hist_all = jax.lax.all_gather(local.bucket_counts, axis_name)    # (D, m)
-    in_off_all, send_all, g_flat, totals = _send_plan(hist_all, n_dev)
-    in_off = in_off_all[my_idx]
-    send = send_all[my_idx]
+    with tracing.span("repro.stage.exchange", transport=transport, chips=d_num,
+                      n_shard=n_dev, m=m, backend=name):
+        # ---- global stage: ONE tiny collective over H (D, m) + replicated scan
+        hist_all = jax.lax.all_gather(local.bucket_counts, axis_name)    # (D, m)
+        in_off_all, send_all, g_flat, totals = _send_plan(hist_all, n_dev)
+        in_off = in_off_all[my_idx]
+        send = send_all[my_idx]
 
-    # global output position of each local (reordered) element: strictly
-    # increasing in local index (bucket-major local x bucket-major global)
-    m = bucket_fn.num_buckets
-    local_starts = jnp.cumsum(local.bucket_counts) - local.bucket_counts   # (m,)
-    c_excl = (jnp.cumsum(hist_all, axis=0) - hist_all)[my_idx]             # (m,)
-    lidx = jnp.arange(n_dev, dtype=jnp.int32)
-    lids = jnp.searchsorted(jnp.cumsum(local.bucket_counts), lidx, side="right").astype(jnp.int32)
-    rank_in_bucket = lidx - local_starts[lids]
-    positions = g_flat[lids] + c_excl[lids] + rank_in_bucket               # (n_dev,)
+        # global output position of each local (reordered) element: strictly
+        # increasing in local index (bucket-major local x bucket-major global)
+        local_starts = jnp.cumsum(local.bucket_counts) - local.bucket_counts   # (m,)
+        c_excl = (jnp.cumsum(hist_all, axis=0) - hist_all)[my_idx]             # (m,)
+        lidx = jnp.arange(n_dev, dtype=jnp.int32)
+        lids = jnp.searchsorted(jnp.cumsum(local.bucket_counts), lidx,
+                                side="right").astype(jnp.int32)
+        rank_in_bucket = lidx - local_starts[lids]
+        positions = g_flat[lids] + c_excl[lids] + rank_in_bucket               # (n_dev,)
 
-    move = lambda buf: _transport_dense_positions(buf, positions, in_off, send, axis_name)
-    keys_out = move(local.keys)
-    values_out = move(local.values) if values is not None else None
+        move = lambda buf: _transport_dense_positions(buf, positions, in_off, send,
+                                                      axis_name)
+        keys_out = move(local.keys)
+        values_out = move(local.values) if values is not None else None
     return ShardedMultisplitResult(keys_out, values_out, g_flat, totals.astype(jnp.int32))
 
 
@@ -267,7 +300,7 @@ def multisplit_bucket_sharded(
     axis_name: str,
     capacity: int,
     method: str = "bms",
-    use_pallas: bool = False,
+    use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
     transport: str = "dense",
@@ -293,47 +326,54 @@ def multisplit_bucket_sharded(
     n_dev = keys.shape[0]
 
     # local stage
-    local = _local_plan(keys, bucket_fn, values, method, use_pallas, backend, tile)
-    hist_all = jax.lax.all_gather(local.bucket_counts, axis_name)      # (D, m)
+    name = _backend(use_pallas, backend, n_dev, keys.dtype)
+    local = _local_plan(keys, bucket_fn, values, method, name, tile)
 
-    group = hist_all.reshape(d_num, d_num, mb)                          # (src, dstgroup, mb)
-    send_matrix = group.sum(-1).astype(jnp.int32)                       # (src, dst)
-    local_starts = (jnp.cumsum(local.bucket_counts) - local.bucket_counts).astype(jnp.int32)
-    in_off = local_starts[jnp.arange(d_num) * mb]                       # (dst,) my run starts
-    send = send_matrix[my_idx]                                          # (dst,)
-    recv = send_matrix[:, my_idx]                                       # (src,)
-    out_off = (jnp.cumsum(recv) - recv).astype(jnp.int32)               # src-major receiver layout
-    # ragged_all_to_all wants sender-side knowledge of where its chunk lands
-    # on each receiver: cumulative sizes of lower-indexed sources there.
-    send_out_off = (jnp.cumsum(send_matrix, axis=0) - send_matrix)[my_idx]  # (dst,)
+    with tracing.span("repro.stage.exchange", transport=transport, chips=d_num,
+                      n_shard=n_dev, m=m, backend=name):
+        hist_all = jax.lax.all_gather(local.bucket_counts, axis_name)  # (D, m)
 
-    if transport == "ragged":
-        def move(buf):
-            out = jnp.zeros((capacity,) + buf.shape[1:], buf.dtype)
-            return jax.lax.ragged_all_to_all(
-                buf, out, in_off, send, send_out_off, recv, axis_name=axis_name
-            )
-    else:
-        def move(buf):
-            idx = jnp.arange(n_dev, dtype=jnp.int32)
-            gidx = jnp.clip(in_off[:, None] + idx[None, :], 0, n_dev - 1)
-            mask = idx[None, :] < send[:, None]
-            packed = jnp.where(
-                _expand(mask, buf.ndim),
-                buf[gidx.reshape(-1)].reshape((d_num, n_dev) + buf.shape[1:]),
-                0,
-            )
-            recv_buf = jax.lax.all_to_all(packed, axis_name, split_axis=0, concat_axis=0)
-            recv_buf = recv_buf.reshape((d_num, n_dev) + buf.shape[1:])
-            pos = out_off[:, None] + idx[None, :]
-            pos = jnp.where(idx[None, :] < recv[:, None], pos, capacity)  # pads dropped
-            out = jnp.zeros((capacity,) + buf.shape[1:], buf.dtype)
-            return out.at[jnp.clip(pos, 0, capacity).reshape(-1)].set(
-                recv_buf.reshape((-1,) + buf.shape[1:]), mode="drop"
-            )
+        group = hist_all.reshape(d_num, d_num, mb)                      # (src, dstgroup, mb)
+        send_matrix = group.sum(-1).astype(jnp.int32)                   # (src, dst)
+        local_starts = (jnp.cumsum(local.bucket_counts)
+                        - local.bucket_counts).astype(jnp.int32)
+        in_off = local_starts[jnp.arange(d_num) * mb]                   # (dst,) my run starts
+        send = send_matrix[my_idx]                                      # (dst,)
+        recv = send_matrix[:, my_idx]                                   # (src,)
+        out_off = (jnp.cumsum(recv) - recv).astype(jnp.int32)           # src-major receiver layout
+        # ragged_all_to_all wants sender-side knowledge of where its chunk
+        # lands on each receiver: cumulative sizes of lower-indexed sources.
+        send_out_off = (jnp.cumsum(send_matrix, axis=0) - send_matrix)[my_idx]  # (dst,)
 
-    keys_rx = move(local.keys)
-    vals_rx = move(local.values) if values is not None else None
+        if transport == "ragged":
+            def move(buf):
+                _count_exchange(buf.nbytes, buf.nbytes)
+                out = jnp.zeros((capacity,) + buf.shape[1:], buf.dtype)
+                return jax.lax.ragged_all_to_all(
+                    buf, out, in_off, send, send_out_off, recv, axis_name=axis_name
+                )
+        else:
+            def move(buf):
+                idx = jnp.arange(n_dev, dtype=jnp.int32)
+                gidx = jnp.clip(in_off[:, None] + idx[None, :], 0, n_dev - 1)
+                mask = idx[None, :] < send[:, None]
+                packed = jnp.where(
+                    _expand(mask, buf.ndim),
+                    buf[gidx.reshape(-1)].reshape((d_num, n_dev) + buf.shape[1:]),
+                    0,
+                )
+                _count_exchange(packed.nbytes, buf.nbytes)
+                recv_buf = jax.lax.all_to_all(packed, axis_name, split_axis=0, concat_axis=0)
+                recv_buf = recv_buf.reshape((d_num, n_dev) + buf.shape[1:])
+                pos = out_off[:, None] + idx[None, :]
+                pos = jnp.where(idx[None, :] < recv[:, None], pos, capacity)  # pads dropped
+                out = jnp.zeros((capacity,) + buf.shape[1:], buf.dtype)
+                return out.at[jnp.clip(pos, 0, capacity).reshape(-1)].set(
+                    recv_buf.reshape((-1,) + buf.shape[1:]), mode="drop"
+                )
+
+        keys_rx = move(local.keys)
+        vals_rx = move(local.values) if values is not None else None
 
     # final local stage: src-major -> bucket-major within my group.
     # Received buffer is a concatenation of per-src bucket-major chunks; a
@@ -343,11 +383,12 @@ def multisplit_bucket_sharded(
     lo = my_idx * mb
     sub_ids = jnp.clip(bucket_fn(keys_rx) - lo, 0, mb - 1)
     valid = jnp.arange(capacity) < jnp.minimum(recv.sum(), capacity)
-    sub_ids = jnp.where(valid, sub_ids, mb - 1)  # pads ride in the last sub-bucket
+    sub_ids = jnp.where(valid, sub_ids, mb - 1).astype(jnp.int32)  # pads: last sub-bucket
     dest = make_plan(
-        capacity, mb, method=method, backend=resolve_backend(use_pallas, True, backend),
+        capacity, mb, method=method,
+        backend=_backend(use_pallas, backend, capacity, sub_ids.dtype),
         tile=tile, bucket_fn=IdentitySpec(mb), mode="positions_only",
-    )(sub_ids.astype(jnp.int32)).permutation
+    )(sub_ids).permutation
     keys_out = jnp.zeros_like(keys_rx).at[dest].set(keys_rx)
     vals_out = None
     if vals_rx is not None:

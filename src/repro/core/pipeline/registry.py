@@ -546,12 +546,23 @@ def capability_summary() -> dict:
 _BACKEND_DECISIONS: dict = {}
 
 
+def _auto_partitioned() -> bool:
+    """Whether the call is traced under a mesh whose partitioner would have
+    to split it: an axis of more than one device that no ``shard_map`` has
+    made manual. A Mosaic kernel cannot be partitioned automatically."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return not mesh.empty and any(
+        size > 1 and kind != jax.sharding.AxisType.Manual
+        for size, kind in zip(mesh.axis_sizes, mesh.axis_types))
+
+
 def default_backend(n: int, key_dtype) -> str:
     """The backend of a call that names none: ``pallas`` where the kernels
     lower compiled (a TPU is attached and ``REPRO_INTERPRET`` does not force
-    interpret mode) and the keys are 32-bit, the only width they take; else
-    ``vmap``. Each decision is recorded with its reason
-    (:func:`backend_decisions`)."""
+    interpret mode), the keys are 32-bit, the only width they take, and no
+    automatically partitioned mesh axis would have to split the kernels
+    (inside ``shard_map`` they run per device); else ``vmap``. Each decision
+    is recorded with its reason (:func:`backend_decisions`)."""
     from repro.kernels import ops as kops
 
     dtype = jnp.dtype(key_dtype)
@@ -562,6 +573,8 @@ def default_backend(n: int, key_dtype) -> str:
         backend, reason = "vmap", "REPRO_INTERPRET"
     elif bits != 32:
         backend, reason = "vmap", f"{bits}-bit keys"
+    elif _auto_partitioned():
+        backend, reason = "vmap", "auto-partitioned mesh"
     else:
         backend, reason = "pallas", "tpu+32-bit keys"
     _BACKEND_DECISIONS[(n, dtype.name)] = (backend, reason)

@@ -17,6 +17,10 @@ written as stats when it closes (zeros are left out):
   read lies inside its compile and is not added again; a ``jit`` traced
   inside another's trace is timed in both.
 
+``count(**amounts)`` adds the program's own counters to the innermost open
+span the same way (the exchange's ``exchange_shipped_bytes`` and
+``exchange_payload_bytes``).
+
 With no profiler collecting, ``span`` returns one shared context that does
 nothing: no annotation, no stack, no counting. Under a JAX transformation
 (``jit``, ``vmap``, ``grad``) a span times the tracing only, and changes no
@@ -175,6 +179,18 @@ def run_staged(fn, *args):
         while _local.marks:                     # closed early by an error
             _local.marks.pop().__exit__(None, None, None)
     return jax.tree.unflatten(jax.tree.structure(shape), out)
+
+
+def count(**amounts) -> None:
+    """Add ``amounts`` to the counters of the innermost open span, written
+    as its stats when it closes; nothing while no span is open. Called
+    while a ``jit`` traces, it counts once per trace, not once per call."""
+    stack = getattr(_local, "stack", None)
+    if not stack:
+        return
+    counts = stack[-1].counts
+    for key, amount in amounts.items():
+        counts[key] = counts.get(key, 0) + amount
 
 
 def _on_event(event: str, secs: float, **_kw) -> None:

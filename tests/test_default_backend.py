@@ -86,6 +86,28 @@ def test_facade_defaults_reach_the_kernels_on_a_tpu(tpu):
     assert backend_decisions()[(N, "uint32")] == ("pallas", "tpu+32-bit keys")
 
 
+@pytest.mark.parametrize("sizes, kinds, backend", [
+    ((4,), ("Auto",), "vmap"),                 # the partitioner would split a kernel
+    ((1, 4), ("Auto", "Explicit"), "vmap"),
+    ((4,), ("Manual",), "pallas"),             # inside shard_map: one device's part
+    ((4, 2), ("Manual", "Auto"), "vmap"),      # shard_map over one axis of two
+    ((1, 1), ("Auto", "Auto"), "pallas"),      # nothing to split
+])
+def test_under_an_auto_partitioned_mesh_the_default_is_vmap(tpu, sizes, kinds, backend):
+    """Under a mesh whose partitioner would have to split the call (the MoE
+    router's load count under the expert-parallel mesh), a Mosaic kernel
+    cannot lower; inside ``shard_map`` it runs per device."""
+    from jax.sharding import AbstractMesh, AxisType
+
+    names = ("a", "b")[:len(sizes)]
+    mesh = AbstractMesh(sizes, names, axis_types=tuple(getattr(AxisType, k) for k in kinds))
+    with jax.sharding.use_abstract_mesh(mesh):
+        assert default_backend(N + 5, jnp.int32) == backend
+    reason = "auto-partitioned mesh" if backend == "vmap" else "tpu+32-bit keys"
+    assert backend_decisions()[(N + 5, "int32")] == (backend, reason)
+    assert default_backend(N + 5, jnp.int32) == "pallas"      # no mesh
+
+
 def test_routing_and_serving_defaults_reach_the_kernels_on_a_tpu(tpu):
     from repro.models.moe import route_tokens_segmented
 

@@ -1,12 +1,21 @@
 """Distributed multisplit over a mesh axis (runs subprocesses with virtual
 devices: the main pytest process must keep seeing exactly 1 CPU device)."""
 
+import json
 import os
 import subprocess
 import sys
 import textwrap
 
+import jax
+import jax.numpy as jnp
 import pytest
+from jax.sharding import PartitionSpec as P
+
+from repro.core import distributed
+from repro.core.identifiers import delta_buckets
+from repro.core.pipeline import backend_decisions
+from repro.kernels import ops as kops
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -97,3 +106,137 @@ def test_dryrun_one_cell_both_meshes():
         print("OK", rec1["dominant"])
     """)
     assert "OK" in out
+
+
+# ---------------------------------------------------------------------------
+# The sharded key-value multisplit with default arguments: the local stage
+# takes the platform default, as the ``repro.ops`` facade does.
+# ---------------------------------------------------------------------------
+
+SHARD = 4096
+KV_CASES = [(m, be) for m in (2, 256) for be in ("default", "pallas-interpret")]
+
+
+@pytest.fixture(scope="module")
+def sharded_kv_results():
+    """Every case of ``make_multisplit_sharded(spec, mesh, "x",
+    key_value=True)`` at 4 x 4096 pairs, in one child with four CPU
+    devices: ``(m, backend) -> which fields agree with multisplit_ref``."""
+    out = _run_with_devices(4, f"""
+        import json
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.core.distributed import make_multisplit_sharded
+        from repro.core.identifiers import delta_buckets
+        from repro.core.multisplit import multisplit_ref
+        from repro.core.pipeline import backend_decisions
+        D, n_shard = 4, {SHARD}
+        mesh = jax.make_mesh((D,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
+        for m, be in {KV_CASES!r}:
+            keys = jax.random.bits(jax.random.key(m), (D * n_shard,), jnp.uint32)
+            vals = jnp.arange(D * n_shard, dtype=jnp.uint32)
+            spec = delta_buckets(m, 2**32)
+            kw = {{}} if be == "default" else {{"backend": be}}
+            got = jax.jit(make_multisplit_sharded(spec, mesh, "x", key_value=True, **kw))(keys, vals)
+            want = multisplit_ref(keys, spec, vals)
+            row = {{f: bool(np.array_equal(np.asarray(getattr(got, f)), np.asarray(getattr(want, f))))
+                    for f in ("keys", "values", "bucket_counts", "bucket_starts")}}
+            row["placed"] = all(
+                sorted((s.index[0].start or 0, s.data.shape[0]) for s in a.addressable_shards
+                       if s.device == dev) == [(d * n_shard, n_shard)]
+                for a in (got.keys, got.values) for d, dev in enumerate(mesh.devices.flat))
+            row["decision"] = backend_decisions().get((n_shard, "uint32"))
+            print(json.dumps({{"case": [m, be], **row}}))
+    """)
+    rows = [json.loads(x) for x in out.splitlines() if x.startswith("{")]
+    return {tuple(r.pop("case")): r for r in rows}
+
+
+@pytest.mark.parametrize("m, backend", KV_CASES)
+def test_sharded_key_value_with_default_arguments(sharded_kv_results, m, backend):
+    row = sharded_kv_results[(m, backend)]
+    assert row == {"keys": True, "values": True, "bucket_counts": True,
+                   "bucket_starts": True, "placed": True,
+                   "decision": ["vmap", "no TPU"]}, row
+
+
+def _mesh1():
+    return jax.make_mesh((1,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def _plan_backends(monkeypatch, entry, **kw):
+    """The backend of every plan the sharded ``entry`` builds, traced on a
+    one-device mesh (nothing is run)."""
+    seen = []
+    make_plan = distributed.make_plan
+
+    def spy(*args, **plan_kw):
+        seen.append(plan_kw["backend"])
+        return make_plan(*args, **plan_kw)
+
+    monkeypatch.setattr(distributed, "make_plan", spy)
+    keys = jnp.arange(SHARD, dtype=jnp.uint32) * 977
+    spec = delta_buckets(8, 2**32)
+    mesh = _mesh1()
+    if entry == "multisplit_sharded":
+        fn = distributed.make_multisplit_sharded(spec, mesh, "x", key_value=True, **kw)
+    elif entry == "multisplit_bucket_sharded":
+        fn = jax.shard_map(
+            lambda k, v: distributed.multisplit_bucket_sharded(
+                k, spec, v, axis_name="x", capacity=SHARD, **kw),
+            mesh=mesh, in_specs=(P("x"), P("x")),
+            out_specs=distributed.BucketShardedResult(P("x"), P("x"), P("x"), P("x"), P()),
+            check_vma=False)
+    else:
+        fn = lambda k, v: distributed.multisplit_all_shards(k[None], spec, v[None], **kw)
+    jaxpr = str(jax.make_jaxpr(fn)(keys, keys))
+    return seen, "pallas_call" in jaxpr
+
+
+ENTRIES = ["multisplit_sharded", "multisplit_bucket_sharded", "multisplit_all_shards"]
+
+
+@pytest.fixture
+def tpu(monkeypatch):
+    """A TPU attached, REPRO_INTERPRET unset: kernels trace compiled."""
+    monkeypatch.setattr(kops, "_tpu_available", lambda: True)
+    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_sharded_call_naming_no_backend_asks_the_default(tpu, monkeypatch, entry):
+    seen, kernels = _plan_backends(monkeypatch, entry)
+    plans = 2 if entry == "multisplit_bucket_sharded" else 1
+    assert seen == ["pallas"] * plans and kernels
+    assert backend_decisions()[(SHARD, "uint32")] == ("pallas", "tpu+32-bit keys")
+    if entry == "multisplit_bucket_sharded":       # the positions-only plan
+        assert backend_decisions()[(SHARD, "int32")] == ("pallas", "tpu+32-bit keys")
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_sharded_call_on_this_host_defaults_to_vmap(monkeypatch, entry):
+    seen, kernels = _plan_backends(monkeypatch, entry)
+    assert set(seen) == {"vmap"} and not kernels
+
+
+@pytest.mark.parametrize("kw, backend", [
+    ({"use_pallas": True}, "pallas"),
+    ({"use_pallas": False}, "vmap"),
+    ({"backend": "pallas-interpret"}, "pallas-interpret"),
+])
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_legacy_use_pallas_keeps_its_meaning(tpu, monkeypatch, entry, kw, backend):
+    """``use_pallas=True`` is the compiled ``pallas`` backend where the
+    kernels compile, no longer ``pallas-interpret``."""
+    seen, _ = _plan_backends(monkeypatch, entry, **kw)
+    assert set(seen) == {backend}
+
+
+@pytest.mark.parametrize("transport", ["ragged", "sparse"])
+def test_multisplit_sharded_refuses_a_transport_it_lacks(transport):
+    keys = jnp.arange(SHARD, dtype=jnp.uint32)
+    spec = delta_buckets(8, 2**32)
+    with pytest.raises(ValueError, match="transport='dense' only"):
+        distributed.multisplit_sharded(keys, spec, axis_name="x", transport=transport)
+    fn = distributed.make_multisplit_sharded(spec, _mesh1(), "x", transport=transport)
+    with pytest.raises(ValueError, match="'dense'"):
+        jax.jit(fn)(keys)

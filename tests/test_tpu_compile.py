@@ -112,3 +112,47 @@ def test_main_path_kernel_compiles_for_v5e(case, one_chip, monkeypatch):
     monkeypatch.setattr(mst, "VMEM_LIMIT_BYTES", budget)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def tpu_attached(monkeypatch):
+    """Code that asks whether a TPU is attached is told yes, so that the
+    default backend is the one it would be on the described chips."""
+    from repro.kernels import ops as kops
+
+    monkeypatch.setattr(kops, "_tpu_available", lambda: True)
+    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+
+
+def test_sharded_default_path_compiles_for_v5e_2x2(topo, tpu_attached):
+    """The four-chip key-value multisplit with default arguments: the local
+    stage's kernels inside ``shard_map``, then the exchange's collectives."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.distributed import make_multisplit_sharded
+    from repro.core.pipeline import backend_decisions
+
+    mesh = Mesh(topo.devices, ("x",))
+    n_shard = 1 << 14
+    arg = jax.ShapeDtypeStruct((4 * n_shard,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P("x")))
+    fn = make_multisplit_sharded(DeltaSpec(256, 1 << 32), mesh, "x", key_value=True)
+    text = jax.jit(fn).lower(arg, arg).compile().as_text()
+    assert "tpu_custom_call" in text and "all-to-all" in text
+    assert backend_decisions()[(n_shard, "uint32")] == ("pallas", "tpu+32-bit keys")
+
+
+def test_routing_under_an_auto_sharded_mesh_compiles_for_v5e_2x2(topo, tpu_attached):
+    """The MoE router's load count runs outside ``shard_map`` under the
+    expert-parallel mesh, where a Mosaic kernel cannot be partitioned: the
+    default must keep it off the kernels."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.models.moe import expert_load_stats
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    ids = jax.ShapeDtypeStruct((4096,), jnp.int32, sharding=NamedSharding(mesh, P()))
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(lambda e: expert_load_stats(e, 16)[0]).lower(ids).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

@@ -2,6 +2,7 @@
 counts on them, read back from a profiler trace of eager calls on the CPU."""
 
 import glob
+import json
 import os
 import tempfile
 
@@ -247,3 +248,80 @@ def test_tile_stages_in_chunks_match_the_reference(jitted, monkeypatch):
         assert stages["repro.stage.postscan"]["map_batch"] == 1
     for a, b in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# The exchange of the sharded multisplit, on four CPU devices
+# ---------------------------------------------------------------------------
+
+EXCHANGE_BODY = """
+    import json, os, sys
+    sys.path.insert(0, {tests!r})
+    import jax, jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.core import distributed
+    from repro.core.identifiers import delta_buckets
+    from repro.runtime import tracing
+    from test_tracing import _Profiled, _all
+
+    D, n_shard, m = 4, 1024, 16
+    mesh = jax.make_mesh((D,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
+    keys = jax.random.bits(jax.random.key(1), (D * n_shard,), jnp.uint32)
+    vals = jnp.arange(D * n_shard, dtype=jnp.uint32)
+    spec = delta_buckets(m, 2**32)
+
+    def sharded():
+        return jax.jit(distributed.make_multisplit_sharded(spec, mesh, "x", key_value=True))
+
+    def bucket_sharded():
+        return jax.jit(jax.shard_map(
+            lambda k, v: distributed.multisplit_bucket_sharded(
+                k, spec, v, axis_name="x", capacity=2 * n_shard),
+            mesh=mesh, in_specs=(P("x"), P("x")),
+            out_specs=distributed.BucketShardedResult(P("x"), P("x"), P("x"), P("x"), P()),
+            check_vma=False))
+
+    made = []
+    init = tracing._Span.__init__
+    tracing._Span.__init__ = lambda self, name, stats: (made.append(name), init(self, name, stats))[1]
+    for build in (sharded, bucket_sharded):
+        jax.block_until_ready(build()(keys, vals))     # traced with no collector
+    print(json.dumps({{"off": made}}))
+    for build in (sharded, bucket_sharded):
+        run = _Profiled(lambda: build()(keys, vals))
+        spans = [(n, st) for n, st, _ in _all(run.spans) if n == "repro.stage.exchange"]
+        print(json.dumps({{"entry": build.__name__, "spans": spans}}))
+"""
+
+
+@pytest.fixture(scope="module")
+def exchange_spans():
+    from test_distributed import _run_with_devices
+
+    tests = os.path.dirname(os.path.abspath(__file__))
+    out = _run_with_devices(4, EXCHANGE_BODY.format(tests=tests))
+    return [json.loads(x) for x in out.splitlines() if x.startswith("{")]
+
+
+def test_exchange_records_nothing_without_a_collector(exchange_spans):
+    assert exchange_spans[0] == {"off": []}
+
+
+@pytest.mark.parametrize("entry, positions", [("sharded", True), ("bucket_sharded", False)])
+def test_exchange_span_and_its_byte_counters(exchange_spans, entry, positions):
+    """One ``repro.stage.exchange`` span a trace, with the shipped and
+    payload bytes of the dense transport in closed form: per chip and per
+    array (keys, values) a (D, n_shard) all-to-all operand of 4-byte
+    elements, and as many positions where the transport ships them."""
+    d, n_shard, m = 4, 1024, 16
+    (row,) = [r for r in exchange_spans[1:] if r["entry"] == entry]
+    (span,) = row["spans"]
+    name, stats = span
+    assert {k: stats[k] for k in ("transport", "chips", "n_shard", "m", "backend")} == {
+        "transport": "dense", "chips": d, "n_shard": n_shard, "m": m, "backend": "vmap"}
+    payload = 2 * n_shard * 4
+    shipped = 2 * d * n_shard * 4 * (2 if positions else 1)
+    assert stats["exchange_payload_bytes"] == payload
+    assert stats["exchange_shipped_bytes"] == shipped
+    assert stats["exchange_shipped_bytes"] / stats["exchange_payload_bytes"] == (
+        2 * d if positions else d)
