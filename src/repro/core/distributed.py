@@ -4,16 +4,19 @@ onto a JAX mesh axis (DESIGN.md §2, §7).
 Hierarchy (paper §4.4, one more level than the GPU version):
 
     tile (VMEM direct solve)  ->  chip (grid accumulation)
-        ->  device axis (THIS module: one tiny collective + ragged a2a)
+        ->  device axis (THIS module: one tiny collective + one run per peer)
 
 Key property (paper §4.7 lifted to ICI): after each device *locally
 reorders* its shard bucket-major, the map ``local index -> global output
 position`` is strictly increasing. Hence the data each device must send to
 any given peer is ONE contiguous run of its local buffer — i.e., the local
-reorder turns a random inter-device scatter into a single-segment
-``ragged_all_to_all``. Without the reorder (DMS), per-peer sends are
-scattered and the collective degenerates to a dense gather/scatter; this is
-the paper's coalescing argument, with "DRAM burst" replaced by "ICI DMA".
+reorder turns a random inter-device scatter into one contiguous slice per
+peer: a padded row of a dense ``all_to_all`` (``multisplit_sharded``) or a
+segment of a ``ragged_all_to_all`` (``multisplit_bucket_sharded``), and the
+receiver's runs arrive source-major, for a second local multisplit to put
+bucket-major. Without the reorder (DMS), per-peer sends are scattered and
+the collective degenerates to a dense gather/scatter; this is the paper's
+coalescing argument, with "DRAM burst" replaced by "ICI DMA".
 """
 
 from __future__ import annotations
@@ -148,12 +151,13 @@ class ShardedMultisplitResult(NamedTuple):
 
 
 def _send_plan(hist_all: Array, n_dev: int):
-    """Compute the ragged_all_to_all plan from the gathered histogram.
+    """Compute the run transport's plan from the gathered histogram.
 
     ``hist_all``: (D, m) per-device bucket counts — the paper's matrix H with
     L = D columns. Everything below is O(D·m + D²) scalar work, computed
     redundantly on every device (recompute-over-communicate, paper §5.3).
-    Returns the full (D_src, D_dst) matrices so caller can slice both its
+    Returns the full (D_src, D_dst) matrices of run starts in each source's
+    reordered buffer and run lengths, so the caller can slice both its
     sender row and its receiver column.
     """
     d_num, m = hist_all.shape
@@ -181,42 +185,34 @@ def _expand(mask, ndim):
 def _count_exchange(shipped: Array, payload: Array) -> None:
     """The exchange counters of one array moved (added once per trace):
     ``exchange_shipped_bytes``, the operand bytes of the transport's
-    collectives on this chip, padding and positions included, and
+    collectives on this chip, padding included, and
     ``exchange_payload_bytes``, the bytes of the array they carry."""
     tracing.count(exchange_shipped_bytes=int(shipped),
                   exchange_payload_bytes=int(payload))
 
 
-def _transport_dense_positions(buf, positions, in_off, send, axis_name):
-    """Position-carrying dense transport (XLA:CPU-compilable).
+def _transport_runs(buf, in_off, out_off, axis_name):
+    """Dense run transport (XLA:CPU-compilable): no per-element index.
 
-    Each source's run for destination d is one contiguous local segment
-    (guaranteed by the local reorder); we pad each segment to the shard size,
-    ship (data, global position) with a dense ``all_to_all``, and the
-    receiver scatters by position. Correct for any interleaving at the
-    destination. Per chip it ships D·n_dev elements and as many positions
-    for every array it moves, D times the array's own bytes and more.
+    Row d of the (D, n_dev) send buffer is the slice of the bucket-major
+    ``buf`` starting at ``in_off[d]``: this chip's run for peer d, then
+    whatever follows it, which the receiver overwrites or cuts. One dense
+    ``all_to_all`` ships it; row s of what arrives is source s's run, and
+    writing row s at ``out_off[s]`` in source order compacts the runs into
+    exactly n_dev elements, source-major, each row overwriting the unused
+    tail of the row before. Per chip it ships D·n_dev elements per array.
     """
     n_dev = buf.shape[0]
-    d_num = send.shape[0]
-    idx = jnp.arange(n_dev, dtype=jnp.int32)
-    gidx = jnp.clip(in_off[:, None] + idx[None, :], 0, n_dev - 1)      # (D, n_dev)
-    send_mask = idx[None, :] < send[:, None]
-
-    def pack(x, fill):
-        g = x[gidx.reshape(-1)].reshape((d_num, n_dev) + x.shape[1:])
-        return jnp.where(_expand(send_mask, x.ndim), g, fill)
-
-    send_buf = pack(buf, 0)
-    send_pos = pack(positions, -1)
-    _count_exchange(send_buf.nbytes + send_pos.nbytes, buf.nbytes)
+    d_num = in_off.shape[0]
+    padded = jnp.concatenate([buf, jnp.zeros_like(buf)])               # (2 n_dev,)
+    send_buf = jnp.stack([jax.lax.dynamic_slice_in_dim(padded, in_off[d], n_dev)
+                          for d in range(d_num)])                      # (D, n_dev)
+    _count_exchange(send_buf.nbytes, buf.nbytes)
     recv_buf = jax.lax.all_to_all(send_buf, axis_name, split_axis=0, concat_axis=0)
-    recv_pos = jax.lax.all_to_all(send_pos, axis_name, split_axis=0, concat_axis=0)
-    my_idx = jax.lax.axis_index(axis_name)
-    local_pos = recv_pos.reshape(-1) - my_idx * n_dev
-    local_pos = jnp.where(recv_pos.reshape(-1) < 0, n_dev, local_pos)  # pads -> dropped
-    out = jnp.zeros((n_dev,) + buf.shape[1:], buf.dtype)
-    return out.at[local_pos].set(recv_buf.reshape((-1,) + buf.shape[1:]), mode="drop")
+    out = jnp.zeros_like(padded)
+    for s in range(d_num):
+        out = jax.lax.dynamic_update_slice_in_dim(out, recv_buf[s], out_off[s], axis=0)
+    return out[:n_dev]
 
 
 def multisplit_sharded(
@@ -237,15 +233,18 @@ def multisplit_sharded(
     device's equal-size shard. Output: shard ``d`` of the result holds global
     positions ``[d*n_dev, (d+1)*n_dev)`` of the bucket-major output.
 
-    The local stage runs ``backend``, else the one ``use_pallas`` selects,
+    The local stages run ``backend``, else the one ``use_pallas`` selects,
     else :func:`default_backend` of the shard (the compiled ``pallas``
-    kernels on a TPU for 32-bit keys). ``transport="dense"``, the only one,
-    ships each array with a dense ``all_to_all``: every chip's run for each
-    peer is padded to a full shard and shipped with the global position of
-    every element, once for the keys and once more for the values (the
-    compiler may merge the two), and the receiver scatters by position. A
-    chip thus ships D shards of data and D of positions per array, where
-    about (D-1)/D of one shard leaves it.
+    kernels on a TPU for 32-bit keys). Local -> global -> local, as the
+    paper's model: each chip reorders its shard bucket-major, so its data
+    for each peer is one contiguous run; ``transport="dense"``, the only
+    one, ships the runs with one dense ``all_to_all`` per array (keys, then
+    values), each padded to a full shard, and no positions. A chip receives
+    exactly n_dev elements, the runs of its sources in source order; a
+    second stable local multisplit of them gives the bucket-major shard,
+    source-major inside each bucket, which is the global order. A chip thus
+    ships D shards of data per array, where about (D-1)/D of one shard
+    leaves it.
     """
     if transport != "dense":
         raise ValueError(
@@ -264,24 +263,17 @@ def multisplit_sharded(
         # ---- global stage: ONE tiny collective over H (D, m) + replicated scan
         hist_all = jax.lax.all_gather(local.bucket_counts, axis_name)    # (D, m)
         in_off_all, send_all, g_flat, totals = _send_plan(hist_all, n_dev)
-        in_off = in_off_all[my_idx]
-        send = send_all[my_idx]
+        recv = send_all[:, my_idx]                                       # (src,), sums to n_dev
+        out_off = (jnp.cumsum(recv) - recv).astype(jnp.int32)           # src-major layout
 
-        # global output position of each local (reordered) element: strictly
-        # increasing in local index (bucket-major local x bucket-major global)
-        local_starts = jnp.cumsum(local.bucket_counts) - local.bucket_counts   # (m,)
-        c_excl = (jnp.cumsum(hist_all, axis=0) - hist_all)[my_idx]             # (m,)
-        lidx = jnp.arange(n_dev, dtype=jnp.int32)
-        lids = jnp.searchsorted(jnp.cumsum(local.bucket_counts), lidx,
-                                side="right").astype(jnp.int32)
-        rank_in_bucket = lidx - local_starts[lids]
-        positions = g_flat[lids] + c_excl[lids] + rank_in_bucket               # (n_dev,)
+        move = lambda buf: _transport_runs(buf, in_off_all[my_idx], out_off, axis_name)
+        keys_rx = move(local.keys)
+        values_rx = move(local.values) if values is not None else None
 
-        move = lambda buf: _transport_dense_positions(buf, positions, in_off, send,
-                                                      axis_name)
-        keys_out = move(local.keys)
-        values_out = move(local.values) if values is not None else None
-    return ShardedMultisplitResult(keys_out, values_out, g_flat, totals.astype(jnp.int32))
+    # ---- final local stage: source-major -> bucket-major (stable) ----
+    merged = _local_plan(keys_rx, bucket_fn, values_rx, method, name, tile)
+    return ShardedMultisplitResult(merged.keys, merged.values, g_flat,
+                                   totals.astype(jnp.int32))
 
 
 class BucketShardedResult(NamedTuple):

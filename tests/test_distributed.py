@@ -115,13 +115,22 @@ def test_dryrun_one_cell_both_meshes():
 
 SHARD = 4096
 KV_CASES = [(m, be) for m in (2, 256) for be in ("default", "pallas-interpret")]
+# Inputs that shape the exchange's send matrix, on the default backend:
+# "one_bucket" puts every key in one bucket (the matrix is diagonal, most
+# runs empty); "skewed" draws three shards' keys from the lowest eighth of
+# the range, so buckets straddle chip boundaries.
+EDGE_CASES = [(m, dist) for dist in ("one_bucket", "skewed") for m in (2, 16, 256)]
+EDGE_CASES.append((16, "uniform"))
 
 
 @pytest.fixture(scope="module")
 def sharded_kv_results():
     """Every case of ``make_multisplit_sharded(spec, mesh, "x",
     key_value=True)`` at 4 x 4096 pairs, in one child with four CPU
-    devices: ``(m, backend) -> which fields agree with multisplit_ref``."""
+    devices: ``(m, backend, keys) -> which fields agree with
+    multisplit_ref``."""
+    cases = [(m, be, "uniform") for m, be in KV_CASES]
+    cases += [(m, "default", dist) for m, dist in EDGE_CASES]
     out = _run_with_devices(4, f"""
         import json
         import numpy as np, jax, jax.numpy as jnp
@@ -131,8 +140,12 @@ def sharded_kv_results():
         from repro.core.pipeline import backend_decisions
         D, n_shard = 4, {SHARD}
         mesh = jax.make_mesh((D,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
-        for m, be in {KV_CASES!r}:
+        for m, be, dist in {cases!r}:
             keys = jax.random.bits(jax.random.key(m), (D * n_shard,), jnp.uint32)
+            if dist == "one_bucket":
+                keys = jnp.full_like(keys, 3 * 2**30 + 12345)
+            elif dist == "skewed":
+                keys = keys.at[:3 * n_shard].set(keys[:3 * n_shard] >> 3)
             vals = jnp.arange(D * n_shard, dtype=jnp.uint32)
             spec = delta_buckets(m, 2**32)
             kw = {{}} if be == "default" else {{"backend": be}}
@@ -145,18 +158,28 @@ def sharded_kv_results():
                        if s.device == dev) == [(d * n_shard, n_shard)]
                 for a in (got.keys, got.values) for d, dev in enumerate(mesh.devices.flat))
             row["decision"] = backend_decisions().get((n_shard, "uint32"))
-            print(json.dumps({{"case": [m, be], **row}}))
+            print(json.dumps({{"case": [m, be, dist], **row}}))
     """)
     rows = [json.loads(x) for x in out.splitlines() if x.startswith("{")]
     return {tuple(r.pop("case")): r for r in rows}
 
 
+AGREE = {"keys": True, "values": True, "bucket_counts": True, "bucket_starts": True,
+         "placed": True, "decision": ["vmap", "no TPU"]}
+
+
 @pytest.mark.parametrize("m, backend", KV_CASES)
 def test_sharded_key_value_with_default_arguments(sharded_kv_results, m, backend):
-    row = sharded_kv_results[(m, backend)]
-    assert row == {"keys": True, "values": True, "bucket_counts": True,
-                   "bucket_starts": True, "placed": True,
-                   "decision": ["vmap", "no TPU"]}, row
+    row = sharded_kv_results[(m, backend, "uniform")]
+    assert row == AGREE, row
+
+
+@pytest.mark.parametrize("m, dist", EDGE_CASES)
+def test_sharded_exchange_edge_cases(sharded_kv_results, m, dist):
+    """Empty runs, a diagonal send matrix and buckets that straddle chips
+    give the reference's result, each chip holding its own global slice."""
+    row = sharded_kv_results[(m, "default", dist)]
+    assert row == AGREE, row
 
 
 def _mesh1():
@@ -205,7 +228,7 @@ def tpu(monkeypatch):
 @pytest.mark.parametrize("entry", ENTRIES)
 def test_sharded_call_naming_no_backend_asks_the_default(tpu, monkeypatch, entry):
     seen, kernels = _plan_backends(monkeypatch, entry)
-    plans = 2 if entry == "multisplit_bucket_sharded" else 1
+    plans = 1 if entry == "multisplit_all_shards" else 2   # local stage, receiver's
     assert seen == ["pallas"] * plans and kernels
     assert backend_decisions()[(SHARD, "uint32")] == ("pallas", "tpu+32-bit keys")
     if entry == "multisplit_bucket_sharded":       # the positions-only plan

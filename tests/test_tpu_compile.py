@@ -126,7 +126,11 @@ def tpu_attached(monkeypatch):
 
 def test_sharded_default_path_compiles_for_v5e_2x2(topo, tpu_attached):
     """The four-chip key-value multisplit with default arguments: the local
-    stage's kernels inside ``shard_map``, then the exchange's collectives."""
+    stage's kernels inside ``shard_map``, then the exchange's collectives.
+    The exchange ships each peer's run as one slice: the program holds
+    all-to-alls, none of a 32-bit signed operand (positions), and no gather."""
+    import re
+
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.core.distributed import make_multisplit_sharded
@@ -140,6 +144,9 @@ def test_sharded_default_path_compiles_for_v5e_2x2(topo, tpu_attached):
     text = jax.jit(fn).lower(arg, arg).compile().as_text()
     assert "tpu_custom_call" in text and "all-to-all" in text
     assert backend_decisions()[(n_shard, "uint32")] == ("pallas", "tpu+32-bit keys")
+    ops = re.findall(r"= (\S+) ([\w-]+)\(", text)                # (result type, opcode)
+    assert not [op for _, op in ops if op == "gather"]
+    assert not [ty for ty, op in ops if op == "all-to-all" and ty.startswith("s32")]
 
 
 def test_routing_under_an_auto_sharded_mesh_compiles_for_v5e_2x2(topo, tpu_attached):

@@ -258,9 +258,11 @@ EXCHANGE_BODY = """
     import json, os, sys
     sys.path.insert(0, {tests!r})
     import jax, jax.numpy as jnp
+    import numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.core import distributed
     from repro.core.identifiers import delta_buckets
+    from repro.core.multisplit import multisplit_ref
     from repro.runtime import tracing
     from test_tracing import _Profiled, _all
 
@@ -287,10 +289,13 @@ EXCHANGE_BODY = """
     for build in (sharded, bucket_sharded):
         jax.block_until_ready(build()(keys, vals))     # traced with no collector
     print(json.dumps({{"off": made}}))
+    want = multisplit_ref(keys, spec, vals)
     for build in (sharded, bucket_sharded):
         run = _Profiled(lambda: build()(keys, vals))
         spans = [(n, st) for n, st, _ in _all(run.spans) if n == "repro.stage.exchange"]
-        print(json.dumps({{"entry": build.__name__, "spans": spans}}))
+        exact = all(np.array_equal(np.asarray(getattr(run.result, f)), np.asarray(getattr(want, f)))
+                    for f in ("keys", "values"))
+        print(json.dumps({{"entry": build.__name__, "spans": spans, "exact": exact}}))
 """
 
 
@@ -307,12 +312,15 @@ def test_exchange_records_nothing_without_a_collector(exchange_spans):
     assert exchange_spans[0] == {"off": []}
 
 
-@pytest.mark.parametrize("entry, positions", [("sharded", True), ("bucket_sharded", False)])
-def test_exchange_span_and_its_byte_counters(exchange_spans, entry, positions):
+@pytest.mark.parametrize("entry, exact", [("sharded", True), ("bucket_sharded", False)])
+def test_exchange_span_and_its_byte_counters(exchange_spans, entry, exact):
     """One ``repro.stage.exchange`` span a trace, with the shipped and
     payload bytes of the dense transport in closed form: per chip and per
     array (keys, values) a (D, n_shard) all-to-all operand of 4-byte
-    elements, and as many positions where the transport ships them."""
+    elements, one run per peer padded to a shard and no positions. The
+    profiled call gives the reference's keys and values where the entry's
+    result is the exact global multisplit, and not where it is padded to a
+    capacity per chip."""
     d, n_shard, m = 4, 1024, 16
     (row,) = [r for r in exchange_spans[1:] if r["entry"] == entry]
     (span,) = row["spans"]
@@ -320,8 +328,6 @@ def test_exchange_span_and_its_byte_counters(exchange_spans, entry, positions):
     assert {k: stats[k] for k in ("transport", "chips", "n_shard", "m", "backend")} == {
         "transport": "dense", "chips": d, "n_shard": n_shard, "m": m, "backend": "vmap"}
     payload = 2 * n_shard * 4
-    shipped = 2 * d * n_shard * 4 * (2 if positions else 1)
     assert stats["exchange_payload_bytes"] == payload
-    assert stats["exchange_shipped_bytes"] == shipped
-    assert stats["exchange_shipped_bytes"] / stats["exchange_payload_bytes"] == (
-        2 * d if positions else d)
+    assert stats["exchange_shipped_bytes"] == d * payload
+    assert row["exact"] is exact
