@@ -101,8 +101,6 @@ pipe = RadixPipeline(keys.shape[0], radix_bits=8, backend="vmap",
                      fuse_digits=True)
 print(f"fused r=8 sort: {pipe.n_sweeps} sweeps for {pipe.n_passes} digits, "
       f"stage 0 = {pipe.plans[0].stages()[0]!r}")
-# Roofline tracking (ideal bytes vs measured bandwidth, per mode):
-#   PYTHONPATH=src:. python benchmarks/roofline_multisplit.py [--quick]
 
 # --- 9. self-tuning (DESIGN.md §14) -----------------------------------------
 # Opt in and every per-shape decision (tile, family, fused-pair sub_bits,
@@ -123,8 +121,6 @@ with tempfile.TemporaryDirectory() as d:                # demo: throwaway cache
     print(f"  reason: {why[:72]}...")
     ops.set_autotune(False)
     clear_tile_cache()
-# The heuristic-vs-tuned gap is tracked and CI-gated:
-#   PYTHONPATH=src:. python benchmarks/autotune_drift.py --quick --ci-max 1.25
 
 # --- 10. the compiled kernel path (DESIGN.md §15) ---------------------------
 # backend="pallas" means COMPILED-WHEN-AVAILABLE: on a TPU host the kernels
@@ -145,8 +141,7 @@ print(f"pallas: compiled={b.compiled}, interpret-now={b.stages.interpret}")
 # ONE segmented plan launch per step (admission by RangeSpec length
 # bucketing, warm-plan reuse, bounded fault retry, load shedding). The
 # exported metrics are exact nearest-rank percentiles + sustained QPS —
-# bench: PYTHONPATH=src:. python benchmarks/bench_serving.py --quick
-# CLI:   PYTHONPATH=src python -m repro.launch.serve --traffic
+# CLI: PYTHONPATH=src python -m repro.launch.serve --traffic
 from repro.serving import ServerLoop, ServingConfig
 
 loop = ServerLoop(ServingConfig(num_experts=8, capacity=16,

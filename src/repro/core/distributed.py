@@ -28,26 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.identifiers import BucketSpec, IdentitySpec
-from repro.core.pipeline import (
-    MultisplitResult,
-    default_backend,
-    make_plan,
-    resolve_backend,
-)
+from repro.core.pipeline import MultisplitResult, backend_for, make_plan
 from repro.runtime import tracing
 
 Array = jnp.ndarray
-
-
-def _backend(use_pallas: Optional[bool], backend: Optional[str], n: int,
-             key_dtype) -> str:
-    """A local stage's backend: the one named, else the legacy knob's
-    (``use_pallas=True`` the ``pallas`` kernels, compiled where a TPU is
-    attached; ``False`` ``vmap``), else :func:`default_backend` of the
-    stage's ``n`` and key dtype, as the ``repro.ops`` facade chooses."""
-    if backend is not None or use_pallas is not None:
-        return resolve_backend(bool(use_pallas), False, backend)
-    return default_backend(n, key_dtype)
 
 
 def multisplit_all_shards(
@@ -56,7 +40,6 @@ def multisplit_all_shards(
     values: Optional[Array] = None,
     *,
     method: str = "bms",
-    use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
 ) -> MultisplitResult:
@@ -74,8 +57,8 @@ def multisplit_all_shards(
     ``multisplit_ref`` on ``keys.reshape(-1)``), with the element-ordered
     permutation in flat global coordinates.
 
-    Use this as the single-process path for multi-shard data (benchmarks,
-    verification, one-host serving); the collective version below is its
+    Use this as the single-process path for multi-shard data (verification,
+    one-host serving); the collective version below is its
     mesh-distributed twin.
     """
     d_num, n_shard = keys.shape
@@ -84,7 +67,7 @@ def multisplit_all_shards(
         bucket_fn.num_buckets,
         method=method,
         key_value=values is not None,
-        backend=_backend(use_pallas, backend, n_shard, keys.dtype),
+        backend=backend_for(backend, n_shard, keys.dtype),
         tile=tile,
         bucket_fn=bucket_fn,
         batch=d_num,
@@ -222,7 +205,6 @@ def multisplit_sharded(
     *,
     axis_name: str,
     method: str = "bms",
-    use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
     transport: str = "dense",
@@ -233,10 +215,10 @@ def multisplit_sharded(
     device's equal-size shard. Output: shard ``d`` of the result holds global
     positions ``[d*n_dev, (d+1)*n_dev)`` of the bucket-major output.
 
-    The local stages run ``backend``, else the one ``use_pallas`` selects,
-    else :func:`default_backend` of the shard (the compiled ``pallas``
-    kernels on a TPU for 32-bit keys). Local -> global -> local, as the
-    paper's model: each chip reorders its shard bucket-major, so its data
+    The local stages run ``backend``, else
+    :func:`~repro.core.pipeline.default_backend` of the shard (the compiled
+    ``pallas`` kernels on a TPU for 32-bit keys). Local -> global -> local,
+    as the paper's model: each chip reorders its shard bucket-major, so its data
     for each peer is one contiguous run; ``transport="dense"``, the only
     one, ships the runs with one dense ``all_to_all`` per array (keys, then
     values), each padded to a full shard, and no positions. A chip receives
@@ -253,7 +235,7 @@ def multisplit_sharded(
     d_num = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     m = bucket_fn.num_buckets
-    name = _backend(use_pallas, backend, n_dev, keys.dtype)
+    name = backend_for(backend, n_dev, keys.dtype)
 
     # ---- local stage: reorder shard bucket-major, get local histogram ----
     local = _local_plan(keys, bucket_fn, values, method, name, tile)
@@ -292,7 +274,6 @@ def multisplit_bucket_sharded(
     axis_name: str,
     capacity: int,
     method: str = "bms",
-    use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
     transport: str = "dense",
@@ -318,7 +299,7 @@ def multisplit_bucket_sharded(
     n_dev = keys.shape[0]
 
     # local stage
-    name = _backend(use_pallas, backend, n_dev, keys.dtype)
+    name = backend_for(backend, n_dev, keys.dtype)
     local = _local_plan(keys, bucket_fn, values, method, name, tile)
 
     with tracing.span("repro.stage.exchange", transport=transport, chips=d_num,
@@ -378,7 +359,7 @@ def multisplit_bucket_sharded(
     sub_ids = jnp.where(valid, sub_ids, mb - 1).astype(jnp.int32)  # pads: last sub-bucket
     dest = make_plan(
         capacity, mb, method=method,
-        backend=_backend(use_pallas, backend, capacity, sub_ids.dtype),
+        backend=backend_for(backend, capacity, sub_ids.dtype),
         tile=tile, bucket_fn=IdentitySpec(mb), mode="positions_only",
     )(sub_ids).permutation
     keys_out = jnp.zeros_like(keys_rx).at[dest].set(keys_rx)

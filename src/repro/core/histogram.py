@@ -19,7 +19,7 @@ from typing import Optional
 import jax.numpy as jnp
 
 from repro.core.identifiers import BucketSpec, even_buckets, range_buckets
-from repro.core.pipeline import make_plan, resolve_backend
+from repro.core.pipeline import backend_for, make_plan
 
 Array = jnp.ndarray
 
@@ -29,20 +29,19 @@ def histogram(
     bucket_fn: BucketSpec,
     *,
     tile: Optional[int] = None,
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend: Optional[str] = None,
 ) -> Array:
     """Global bucket counts: a ``counts_only`` pipeline (prescan + reduce).
 
     ``tile=None`` resolves through the shared per-shape heuristic/autotune
-    cache — the same tile every other consumer of this shape gets.
+    cache — the same tile every other consumer of this shape gets;
+    ``backend=None`` takes :func:`~repro.core.pipeline.default_backend`.
     """
     plan = make_plan(
         keys.shape[0],
         bucket_fn.num_buckets,
         method="bms",
-        backend=resolve_backend(use_pallas, interpret, backend),
+        backend=backend_for(backend, keys.shape[0], keys.dtype),
         tile=tile,
         bucket_fn=bucket_fn,
         mode="counts_only",

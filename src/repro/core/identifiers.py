@@ -3,7 +3,7 @@ hashable, transform-native.
 
 The paper's defining feature is that *the function that categorizes an
 element into a bucket is provided by the programmer*.  PR-1..3 carried that
-function as an opaque closure (``BucketIdentifier.fn``), which every backend
+function as an opaque closure, which every backend
 had to evaluate into a full n-sized label array before the pipeline started
 — the exact "more expensive data movements" overhead the paper charges the
 sort-based baselines with (§3.4) — and which defeated jit caching (closures
@@ -28,9 +28,7 @@ declarative :class:`BucketSpec` dataclasses:
 
 :class:`CallableSpec` remains the escape hatch for arbitrary user functions
 (the paper's "prime vs composite"); it is the only spec backends must
-materialize labels for.  :class:`BucketIdentifier` survives as a deprecation
-shim (an alias subclass of :class:`CallableSpec`) so pre-PR-4 imports and
-constructions keep working unchanged.
+materialize labels for.
 """
 
 from __future__ import annotations
@@ -255,8 +253,8 @@ class RangeSpec(BucketSpec):
         # (a raw Python int would weak-type to int32 and overflow for
         # splitters above 2^31).  The bucket id is the POPCOUNT of those
         # compares; summing them pairwise as a balanced binary tree keeps
-        # the dependency depth at O(log s) vector adds (vs the O(s) serial
-        # chain of ``_emit_chain``), which is what unblocks large splitter
+        # the dependency depth at O(log s) vector adds (vs O(s) for a serial
+        # chain of adds), which is what unblocks large splitter
         # counts (s = 255+, the sample-sort regime) — a per-element binary
         # SEARCH over the splitter domain is impossible without a gather or
         # a captured array, and would cost O(s) selects per probe anyway.
@@ -271,18 +269,6 @@ class RangeSpec(BucketSpec):
                 nxt.append(parts[-1])
             parts = nxt
         return parts[0]
-
-    def _emit_chain(self, keys: Array) -> Array:
-        """Pre-tree serialized compare chain (O(s) dependency depth), kept
-        as the equivalence/bench baseline for :meth:`emit_in_kernel`."""
-        if not self.splitters:
-            return jnp.zeros(keys.shape, jnp.int32)
-        plane, vals = self._compare_plane(keys.dtype)
-        kc = keys.astype(plane)
-        ids = jnp.zeros(keys.shape, jnp.int32)
-        for s in vals:
-            ids = ids + (kc >= np.asarray(s, plane)[()]).astype(jnp.int32)
-        return ids
 
     @property
     def name(self) -> str:
@@ -345,17 +331,6 @@ class CallableSpec(BucketSpec):
         )
 
 
-class BucketIdentifier(CallableSpec):
-    """Deprecated pre-PR-4 alias of :class:`CallableSpec`.
-
-    Kept so ``from repro.core.identifiers import BucketIdentifier`` and
-    ``BucketIdentifier(fn, m, name)`` keep working (warning-clean); new code
-    should construct a declarative spec (or :class:`CallableSpec`)."""
-
-
-_register_static(BucketIdentifier)
-
-
 def delta_buckets(num_buckets: int, key_max: int = 2**30) -> DeltaSpec:
     """Equal-width buckets over the key domain: ``f(u) = u // delta`` (§6)."""
     return DeltaSpec(num_buckets, key_max)
@@ -393,7 +368,7 @@ def from_fn(fn: Callable[[Array], Array], num_buckets: int, name: str = "user") 
 def as_spec(spec) -> BucketSpec:
     """Coerce a user-supplied identifier into a :class:`BucketSpec`.
 
-    Accepts any spec (including the :class:`BucketIdentifier` shim) as-is;
+    Accepts any spec as-is;
     a bare callable is wrapped iff it carries a ``num_buckets`` attribute.
     """
     if isinstance(spec, BucketSpec):

@@ -22,9 +22,7 @@ of ``__ballot`` and reduced/scanned with MXU/VPU ops instead of ``__popc``.
 Execution is owned by :mod:`repro.core.pipeline` (DESIGN.md §3, §10):
 ``multisplit`` resolves a :class:`repro.core.pipeline.MultisplitPlan`
 through the backend registry and runs it, so the
-postscan + reorder is ONE fused evaluation per tile on every backend. The
-pre-plan three-pass host orchestration survives only as
-:func:`multisplit_unfused`, the fused-vs-legacy benchmark baseline.
+postscan + reorder is ONE fused evaluation per tile on every backend.
 
 Three variants map to the paper's three implementations:
 
@@ -47,7 +45,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.identifiers import BucketSpec
@@ -55,12 +52,11 @@ from repro.core.pipeline import (        # re-exported for consumers/tests
     BMS_TILE,
     MultisplitResult,
     WMS_TILE,
+    backend_for,
     global_scan,
     make_batched_plan,
     make_plan,
     make_segmented_plan,
-    pad_to_tiles as _pad_to_tiles,
-    resolve_backend,
     segment_ids_from_starts,
     tile_local_offsets,
 )
@@ -69,25 +65,9 @@ Array = jnp.ndarray
 
 __all__ = [
     "WMS_TILE", "BMS_TILE", "MultisplitResult", "global_scan",
-    "tile_histogram", "tile_local_offsets", "multisplit_ref", "multisplit",
+    "tile_local_offsets", "multisplit_ref", "multisplit",
     "batched_multisplit", "segmented_multisplit", "segment_ids_from_starts",
-    "multisplit_unfused", "prescan", "postscan_positions",
 ]
-
-
-# ---------------------------------------------------------------------------
-# Direct solve on one tile (paper §4.5, adapted per DESIGN.md §2)
-# ---------------------------------------------------------------------------
-
-def tile_histogram(bucket_ids: Array, num_buckets: int) -> Array:
-    """Histogram of one tile: column-sum of the one-hot matrix H̄ (m,)."""
-    one_hot = (bucket_ids[:, None] == jnp.arange(num_buckets)[None, :]).astype(jnp.int32)
-    return one_hot.sum(axis=0)
-
-
-# tile_local_offsets (stable in-bucket rank + tile histogram, paper Alg. 3
-# without ballots) is defined once in repro.core.pipeline and re-exported
-# above.
 
 
 # ---------------------------------------------------------------------------
@@ -106,25 +86,6 @@ def multisplit_ref(
 
 
 # ---------------------------------------------------------------------------
-# Tiled stage helpers (kept public: histogram.py & tests build on them)
-# ---------------------------------------------------------------------------
-
-def prescan(ids_tiled: Array, num_buckets: int) -> Array:
-    """Local stage 1: per-tile histograms -> H with shape (L, m)."""
-    return jax.vmap(lambda t: tile_histogram(t, num_buckets))(ids_tiled)
-
-
-def postscan_positions(ids_tiled: Array, g: Array, num_buckets: int) -> Array:
-    """Local stage 2 (unfused form): per-element destination, eq. (2)."""
-
-    def one_tile(ids, g_tile):
-        local, _ = tile_local_offsets(ids, num_buckets)
-        return g_tile[ids] + local
-
-    return jax.vmap(one_tile)(ids_tiled, g)
-
-
-# ---------------------------------------------------------------------------
 # The multisplit entry point: resolve a plan, run it
 # ---------------------------------------------------------------------------
 
@@ -135,8 +96,6 @@ def multisplit(
     *,
     method: str = "bms",
     tile: Optional[int] = None,
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend: Optional[str] = None,
     mode: str = "reorder",
     family: Optional[str] = None,
@@ -148,9 +107,10 @@ def multisplit(
     (paper §4.7: the reorder changes data movement, not the result); they
     differ in the width L of the global scan and in scatter contiguity.
 
-    ``backend`` (overrides ``use_pallas``/``interpret``): "reference",
-    "vmap", "pallas-interpret", or "pallas" — registered in
-    :mod:`repro.core.pipeline.registry`.
+    ``backend``: "reference", "vmap", "pallas-interpret", or "pallas" —
+    registered in :mod:`repro.core.pipeline.registry`; ``None`` takes
+    :func:`~repro.core.pipeline.default_backend` (the compiled ``pallas``
+    kernels on a TPU for 32-bit keys, else ``vmap``).
 
     ``mode`` selects a partial pipeline (DESIGN.md §10): ``counts_only``
     (prescan + reduce — the §7.3 histogram; only starts/counts are
@@ -166,7 +126,7 @@ def multisplit(
         bucket_fn.num_buckets,
         method=method,
         key_value=values is not None,
-        backend=resolve_backend(use_pallas, interpret, backend),
+        backend=backend_for(backend, keys.shape[0], keys.dtype),
         tile=tile,
         bucket_fn=bucket_fn,
         mode=mode,
@@ -187,8 +147,6 @@ def batched_multisplit(
     *,
     method: str = "bms",
     tile: Optional[int] = None,
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend: Optional[str] = None,
     mode: str = "reorder",
     family: Optional[str] = None,
@@ -206,7 +164,7 @@ def batched_multisplit(
         b, n, bucket_fn.num_buckets,
         method=method,
         key_value=values is not None,
-        backend=resolve_backend(use_pallas, interpret, backend),
+        backend=backend_for(backend, n, keys.dtype),
         tile=tile,
         bucket_fn=bucket_fn,
         mode=mode,
@@ -244,8 +202,6 @@ def segmented_multisplit(
     *,
     method: str = "bms",
     tile: Optional[int] = None,
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend: Optional[str] = None,
     mode: str = "reorder",
     family: Optional[str] = None,
@@ -275,98 +231,10 @@ def segmented_multisplit(
         keys.shape[0], int(seg.shape[0]), bucket_fn.num_buckets,
         method=method,
         key_value=values is not None,
-        backend=resolve_backend(use_pallas, interpret, backend),
+        backend=backend_for(backend, keys.shape[0], keys.dtype),
         tile=tile,
         bucket_fn=bucket_fn,
         mode=mode,
         family=family,
     )
     return plan(keys, values, segment_starts=seg)
-
-
-# ---------------------------------------------------------------------------
-# Legacy three-pass pipeline — benchmark baseline ONLY (DESIGN.md §6).
-# The postscan/reorder work here evaluates the one-hot/cumsum up to three
-# times per tile (positions, key reorder, value reorder); kept verbatim so
-# benchmarks/bench_multisplit.py can measure what the fused plan removed.
-# ---------------------------------------------------------------------------
-
-def multisplit_unfused(
-    keys: Array,
-    bucket_fn: BucketSpec,
-    values: Optional[Array] = None,
-    *,
-    method: str = "bms",
-    tile: Optional[int] = None,
-) -> MultisplitResult:
-    """Pre-plan host orchestration (3 one-hot/cumsum passes per tile)."""
-    if method not in ("dms", "wms", "bms"):
-        raise ValueError(f"unknown multisplit method {method!r}")
-    if tile is None:
-        tile = WMS_TILE if method in ("dms", "wms") else BMS_TILE
-    m = bucket_fn.num_buckets
-    n = keys.shape[0]
-
-    ids = bucket_fn(keys)
-    ids_p, _ = _pad_to_tiles(ids, tile, m - 1)
-    n_total = ids_p.shape[0]
-    ids_tiled = ids_p.reshape(-1, tile)
-
-    hist = prescan(ids_tiled, m)
-    g = global_scan(hist)
-    pos_tiled = postscan_positions(ids_tiled, g, m)          # pass 1
-    perm_full = pos_tiled.reshape(-1)
-
-    if method in ("wms", "bms"):
-        def reorder_tile(ids_t, keys_t, pos_t):              # pass 2
-            local, h = tile_local_offsets(ids_t, m)
-            starts = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32), jnp.cumsum(h)[:-1].astype(jnp.int32)]
-            )
-            tile_pos = starts[ids_t] + local
-            keys_r = jnp.zeros_like(keys_t).at[tile_pos].set(keys_t)
-            pos_r = jnp.zeros_like(pos_t).at[tile_pos].set(pos_t)
-            return keys_r, pos_r
-
-        keys_p, _ = _pad_to_tiles(keys, tile, 0)
-        keys_tiled = keys_p.reshape(-1, tile)
-        keys_r, pos_r = jax.vmap(reorder_tile)(ids_tiled, keys_tiled, pos_tiled)
-        scatter_src_keys = keys_r.reshape(-1)
-        scatter_pos = pos_r.reshape(-1)
-        if values is not None:
-            vals_p, _ = _pad_to_tiles(values, tile, 0)
-            vals_tiled = vals_p.reshape(-1, tile)
-
-            def reorder_vals(ids_t, vals_t):                 # pass 3
-                local, h = tile_local_offsets(ids_t, m)
-                starts = jnp.concatenate(
-                    [jnp.zeros((1,), jnp.int32), jnp.cumsum(h)[:-1].astype(jnp.int32)]
-                )
-                tile_pos = starts[ids_t] + local
-                return jnp.zeros_like(vals_t).at[tile_pos].set(vals_t)
-
-            vals_r = jax.vmap(reorder_vals)(ids_tiled, vals_tiled)
-            scatter_src_vals = vals_r.reshape(-1)
-    else:
-        keys_p, _ = _pad_to_tiles(keys, tile, 0)
-        scatter_src_keys = keys_p
-        scatter_pos = perm_full
-        if values is not None:
-            vals_p, _ = _pad_to_tiles(values, tile, 0)
-            scatter_src_vals = vals_p
-
-    keys_out = jnp.zeros((n_total,), keys.dtype).at[scatter_pos].set(scatter_src_keys)[:n]
-    values_out = None
-    if values is not None:
-        values_out = (
-            jnp.zeros((n_total,) + values.shape[1:], values.dtype)
-            .at[scatter_pos]
-            .set(scatter_src_vals)[:n]
-        )
-
-    counts = hist.sum(axis=0).astype(jnp.int32)
-    counts = counts.at[m - 1].add(n - n_total)
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)]
-    )
-    return MultisplitResult(keys_out, values_out, starts, counts, perm_full[:n])

@@ -17,8 +17,6 @@ structure explicit:
   segmented layouts) and the executable :class:`MultisplitPlan`.
 * :mod:`~repro.core.pipeline.radix`    — :class:`RadixPipeline`: chained
   digit passes on resident padded buffers (pad/tile once per sort).
-
-``repro.core.plan`` remains a compatibility shim re-exporting this package.
 """
 
 from repro.core.pipeline.autotune import (
@@ -37,11 +35,11 @@ from repro.core.pipeline.registry import (
     VmapStages,
     available_backends,
     backend_decisions,
+    backend_for,
     backend_names,
     default_backend,
     get_backend,
     register_backend,
-    resolve_backend,
 )
 from repro.core.pipeline.spec import (
     MODES,
@@ -92,7 +90,8 @@ __all__ = [
     "Stage", "StageImpl", "VMAP_FUSION_MAX_BUCKETS", "VmapStages", "WMS_TILE",
     "autotune_fused2", "autotune_label_fusion", "autotune_status",
     "autotune_tile", "available_backends", "backend_decisions",
-    "backend_names", "clear_tile_cache", "default_backend", "direct_counts",
+    "backend_for", "backend_names", "clear_tile_cache", "default_backend",
+    "direct_counts",
     "direct_solve_ids", "direct_solve_reference", "exclusive_rows",
     "family_decision", "family_decisions", "fusion_decision",
     "fusion_decisions",
@@ -101,7 +100,6 @@ __all__ = [
     "make_segmented_plan", "make_segmented_radix_plan",
     "packed_direct_solve_ids", "packed_tile_local_offsets", "pad_rows",
     "pad_to_tiles", "radix_pass_pairs", "radix_passes", "register_backend",
-    "resolve_backend",
     "resolve_kernel_family", "resolve_sub_bits", "resolve_tile",
     "seg_tile_local", "segment_ids_from_starts", "set_autotune",
     "tile_local_offsets",

@@ -31,9 +31,6 @@ re-benched by hand).  This module closes the loop:
   flat shape class as a proxy for segmented/batched plans of equal scan
   width; :func:`~repro.core.pipeline.tiles.autotune_tile` accepts explicit
   ``segments=`` / ``batch=`` arguments to measure those layouts directly.
-
-The heuristic-vs-tuned gap is tracked by ``benchmarks/autotune_drift.py``
-and gated in CI, so the cost model can never silently rot again.
 """
 
 from __future__ import annotations

@@ -587,12 +587,10 @@ def backend_decisions() -> dict:
     return dict(_BACKEND_DECISIONS)
 
 
-def resolve_backend(
-    use_pallas: bool = False, interpret: bool = True, backend: Optional[str] = None
-) -> str:
-    """Map the legacy ``(use_pallas, interpret)`` knobs onto a backend name."""
+def backend_for(backend: Optional[str], n: int, key_dtype) -> str:
+    """The backend a call runs on: the one named (validated against the
+    registry), else :func:`default_backend` of its ``n`` and key dtype.
+    Every public entry point resolves its ``backend`` argument here."""
     if backend is not None:
         return get_backend(backend).name
-    if not use_pallas:
-        return "vmap"
-    return "pallas-interpret" if interpret else "pallas"
+    return default_backend(n, key_dtype)

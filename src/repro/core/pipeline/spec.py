@@ -802,8 +802,7 @@ def make_plan(
     """Resolve (n, m, method, key-value-ness, backend, mode) into a staged
     plan.
 
-    ``bucket_fn`` is a :class:`~repro.core.identifiers.BucketSpec` (the
-    :class:`~repro.core.identifiers.BucketIdentifier` shim is one); fusable
+    ``bucket_fn`` is a :class:`~repro.core.identifiers.BucketSpec`; fusable
     specs run label-fused on fusing backends (DESIGN.md §11).  ``batch=b``
     resolves a batched plan over ``(b, n)`` inputs; ``segments=s`` a
     segmented plan over flat ``(n,)`` inputs with an ``(s,)``

@@ -23,8 +23,7 @@ measurement instead of the heuristic: when autotuning is opted in
 (``repro.ops.set_autotune(True)`` / ``REPRO_AUTOTUNE=1``), the miss first
 consults a persistent on-disk cache keyed by (host fingerprint, backend,
 shape class) and otherwise runs the joint timing search, pinning AND
-persisting the winner.  The heuristics remain the default — and the drift
-gate (``benchmarks/autotune_drift.py``) measures how far they rot.
+persisting the winner.  The heuristics remain the default.
 """
 
 from __future__ import annotations

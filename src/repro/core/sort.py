@@ -6,10 +6,6 @@
                              :class:`~repro.core.pipeline.radix.RadixPipeline`
                              (DESIGN.md §10): tiles resolved once, buffers
                              padded once, ping-pong across digit passes.
-* ``radix_sort_per_pass``  — the PR-2 execution (one full plan round trip —
-                             pad, tile, run, slice — per digit pass). Kept
-                             verbatim as the chained-vs-per-pass benchmark
-                             baseline and bitwise-equivalence witness.
 * ``rb_sort_multisplit``   — the paper's *reduced-bit sort* baseline (§3.4):
                              multisplit implemented by sorting ⌈log m⌉-bit
                              labels with the platform sort primitive
@@ -28,26 +24,10 @@ import jax.numpy as jnp
 
 from repro.core import multisplit as ms
 from repro.core.identifiers import BucketSpec
-from repro.core.pipeline import (
-    RadixPipeline,
-    default_backend,
-    make_radix_plan,
-    make_segmented_radix_plan,
-    radix_passes,
-    resolve_backend,
-)
+from repro.core.pipeline import RadixPipeline, backend_for
 from repro.runtime import tracing
 
 Array = jnp.ndarray
-
-
-def _backend(use_pallas: bool, interpret: bool, backend: Optional[str],
-             n: int, keys) -> Tuple[str, bool]:
-    """The backend a sort runs on, and whether the default chose it: the
-    one named or the legacy knobs select, else :func:`default_backend`."""
-    if use_pallas or backend is not None:
-        return resolve_backend(use_pallas, interpret, backend), False
-    return default_backend(n, keys.dtype), True
 
 
 def _op_span(op: str, n: int, radix_bits: int, keys, values, backend: str,
@@ -67,8 +47,6 @@ def radix_sort(
     radix_bits: int = 8,
     key_bits: int = 32,
     method: str = "bms",
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
     family: Optional[str] = None,
@@ -90,7 +68,7 @@ def radix_sort(
 
     2-D ``(b, n)`` keys sort every row independently through BATCHED radix
     plans (DESIGN.md §9): still one kernel launch per pass, covering all
-    rows. Bitwise identical to :func:`radix_sort_per_pass`.
+    rows.
 
     ``fuse_digits=True`` (DESIGN.md §13) runs adjacent digit passes as FUSED
     PAIRS: one sweep per pair — two digit solves around an in-VMEM reorder
@@ -98,7 +76,7 @@ def radix_sort(
     (r=8 → 2 sweeps instead of 4, plus a trailing single pass for odd
     schedules). Bitwise identical to the unfused sort on every backend.
 
-    With no ``backend`` named (and ``use_pallas`` off) the sort runs where
+    With no ``backend`` named the sort runs where
     :func:`~repro.core.pipeline.default_backend` sends it: the compiled
     ``pallas`` kernels on a TPU for 32-bit keys, ``vmap`` otherwise.
     """
@@ -106,7 +84,7 @@ def radix_sort(
         batch, n = keys.shape
     else:
         batch, n = None, keys.shape[0]
-    resolved, auto = _backend(use_pallas, interpret, backend, n, keys)
+    resolved = backend_for(backend, n, keys.dtype)
     pipe = RadixPipeline(
         n,
         radix_bits=radix_bits,
@@ -119,7 +97,8 @@ def radix_sort(
         family=family,
         fuse_digits=fuse_digits,
     )
-    with _op_span("radix_sort", n, radix_bits, keys, values, resolved, auto):
+    with _op_span("radix_sort", n, radix_bits, keys, values, resolved,
+                  backend is None):
         return pipe(keys, values)
 
 
@@ -131,8 +110,6 @@ def segmented_radix_sort(
     radix_bits: int = 8,
     key_bits: int = 32,
     method: str = "bms",
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend: Optional[str] = None,
     tile: Optional[int] = None,
     family: Optional[str] = None,
@@ -150,7 +127,7 @@ def segmented_radix_sort(
     Stable; bitwise identical to slicing out each segment and running
     :func:`radix_sort` on it. The backend defaults as in :func:`radix_sort`.
     """
-    resolved, auto = _backend(use_pallas, interpret, backend, keys.shape[0], keys)
+    resolved = backend_for(backend, keys.shape[0], keys.dtype)
     seg = jnp.asarray(segment_starts, jnp.int32)
     pipe = RadixPipeline(
         keys.shape[0],
@@ -165,54 +142,8 @@ def segmented_radix_sort(
         fuse_digits=fuse_digits,
     )
     with _op_span("segmented_radix_sort", keys.shape[0], radix_bits, keys, values,
-                  resolved, auto):
+                  resolved, backend is None):
         return pipe(keys, values, segment_starts=seg)
-
-
-def radix_sort_per_pass(
-    keys: Array,
-    values: Optional[Array] = None,
-    *,
-    radix_bits: int = 8,
-    key_bits: int = 32,
-    method: str = "bms",
-    backend: str = "vmap",
-    tile: Optional[int] = None,
-    segment_starts=None,
-) -> Tuple[Array, Optional[Array]]:
-    """The PR-2 radix sort: one full plan round trip PER digit pass.
-
-    Every pass re-resolves a plan and re-enters the generic pipeline front
-    door, which re-pads the (already pad-free) buffers to a tile multiple,
-    re-tiles them, and slices the tail back off — ⌈key_bits/r⌉ times. Kept
-    verbatim as the benchmark baseline for the chained
-    :class:`~repro.core.pipeline.radix.RadixPipeline` (which pads/tiles
-    exactly once) and as its bitwise-equivalence witness in the tests.
-    Handles the same flat / batched / segmented layouts.
-    """
-    if keys.ndim == 2:
-        batch, n = keys.shape
-    else:
-        batch, n = None, keys.shape[0]
-    seg = None
-    if segment_starts is not None:
-        seg = jnp.asarray(segment_starts, jnp.int32)
-    for shift, bits in radix_passes(radix_bits, key_bits):
-        if seg is not None:
-            plan = make_segmented_radix_plan(
-                n, int(seg.shape[0]), shift, bits, method=method,
-                key_value=values is not None, backend=backend, tile=tile,
-            )
-            res = plan(keys, values, segment_starts=seg)
-        else:
-            plan = make_radix_plan(
-                n, shift, bits, method=method, key_value=values is not None,
-                backend=backend, tile=tile, batch=batch,
-            )
-            res = plan(keys, values)
-        keys = res.keys
-        values = res.values
-    return keys, values
 
 
 def rb_sort_multisplit(

@@ -164,9 +164,6 @@ def kernel_entry_points() -> Dict[str, Callable[[], LintResult]]:
     add("dense/fused_kv",
         lambda i, g, k, v: _mst.fused_postscan_reorder_pallas(i, g, k, v, _M),
         _ids(), _g(_M), _keys(), _keys())
-    add("dense/reorder",
-        lambda i, k, v: _mst.tile_reorder_pallas(i, k, v, _M),
-        _ids(), _keys(), _keys())
 
     # segmented strip kernels (cid = seg*m + bucket in-register)
     add("seg/histograms",

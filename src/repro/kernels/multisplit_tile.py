@@ -20,9 +20,6 @@ Kernels:
                                      separate postscan/reorder-keys/
                                      reorder-values passes of the legacy host
                                      orchestration.
-* ``tile_reorder_pallas``          — standalone reorder, kept as the unfused
-                                     baseline for kernel tests and the
-                                     fused-vs-legacy benchmark.
 
 Segmented variants (``seg_*``, DESIGN.md §9): identical math, but each tile
 additionally carries a per-element SEGMENT id strip. The kernel combines
@@ -63,9 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
-    cumsum_mxu as _cumsum_mxu,
     dense_positions_body,
-    exclusive_starts_mxu,
     _pair_hist2d_shape,
     fused2_counts_2d,
     fused2_counts_body,
@@ -78,8 +73,6 @@ from repro.kernels.common import (
     packed_positions_body,
     packed_postscan_body,
     pad_lanes as _pad_lanes,
-    permutation_matrix,
-    permute_matmul_32,
 )
 
 Array = jnp.ndarray
@@ -894,45 +887,3 @@ def _layout_kw(bits: Optional[int], subtile: Optional[int]) -> dict:
     if subtile is not None:
         kw["subtile"] = subtile
     return kw
-
-
-# ---------------------------------------------------------------------------
-# Kernel 4: standalone tile reorder — unfused baseline (tests + benchmarks)
-# ---------------------------------------------------------------------------
-
-def _reorder_kernel(ids_ref, keys_ref, vals_ref, keys_out_ref, vals_out_ref, dest_ref, *, m_pad: int):
-    ids = ids_ref[0, :]
-    t = ids.shape[0]
-    one_hot = _one_hot(ids, m_pad)                          # (T, m)
-    incl = _cumsum_mxu(one_hot)
-    local = ((incl - 1.0) * one_hot).sum(axis=1)            # (T,)
-    hist = incl[t - 1, :]                                   # (m,)
-    starts = exclusive_starts_mxu(hist)
-    base = jax.lax.dot(one_hot, starts[:, None], precision=jax.lax.Precision.HIGHEST)[:, 0]
-    dest = (base + local).astype(jnp.int32)                 # within-tile destination
-    dest_ref[0, :] = dest
-
-    perm = permutation_matrix(dest)
-    keys_out_ref[0, :] = permute_matmul_32(perm, keys_ref[0, :])
-    vals_out_ref[0, :] = permute_matmul_32(perm, vals_ref[0, :])
-
-
-def tile_reorder_pallas(
-    ids_tiled: Array,
-    keys_tiled: Array,
-    values_tiled: Array,
-    num_buckets: int,
-    *,
-    interpret: bool = True,
-):
-    """Stable within-tile bucket-major reorder of (keys, values) + dest map."""
-    n_tiles, t = ids_tiled.shape
-    m_pad = _pad_lanes(num_buckets)
-    keys_r, vals_r, dest = _tiled_call(
-        functools.partial(_reorder_kernel, m_pad=m_pad),
-        [ids_tiled, keys_tiled, values_tiled],
-        [_rows(n_tiles, t, keys_tiled.dtype), _rows(n_tiles, t, values_tiled.dtype),
-         _rows(n_tiles, t)],
-        interpret=interpret,
-    )
-    return keys_r, vals_r, dest

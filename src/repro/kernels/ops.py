@@ -78,19 +78,6 @@ def tile_positions(ids_tiled: Array, g: Array, num_buckets: int, interpret: bool
 
 
 @functools.partial(jax.jit, static_argnames=("num_buckets", "interpret"))
-def tile_reorder(
-    ids_tiled: Array,
-    keys_tiled: Array,
-    values_tiled: Array,
-    num_buckets: int,
-    interpret: bool = True,
-) -> Tuple[Array, Array, Array]:
-    return _mst.tile_reorder_pallas(
-        ids_tiled, keys_tiled, values_tiled, num_buckets, interpret=interpret
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("num_buckets", "interpret"))
 def fused_postscan_reorder(
     ids_tiled: Array,
     g: Array,

@@ -11,8 +11,8 @@ BitfieldSpec` and in-kernel label fusion is the GENERIC fused-label
 machinery of :mod:`repro.kernels.multisplit_tile` (DESIGN.md §11): every
 entry point here is a thin ``BitfieldSpec(shift, bits)`` instantiation of
 the corresponding ``spec_*`` kernel, kept under its historical name because
-``radix_sort`` predates the general mechanism and benchmarks/tests address
-these doors directly.
+``radix_sort`` predates the general mechanism and tests address these doors
+directly.
 
 * ``radix_tile_histograms_pallas``        — prescan: digits + tile histogram.
 * ``radix_fused_postscan_reorder_pallas`` — postscan: digits + local ranks +

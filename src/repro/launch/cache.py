@@ -5,7 +5,7 @@ keeps the compiled executables on disk so a second run in the same
 checkout reuses them. The directory is part of the cache's key, so it is a
 fixed path — never a temp, pid- or time-based name. Importing ``repro``
 does not touch the cache: only the entry points (``chip_smoke.py``,
-``repro.launch.serve``, ``benchmarks/run.py``) call :func:`enable_compile_cache`.
+``repro.launch.serve``, ``bench/run.py``) call :func:`enable_compile_cache`.
 """
 
 from __future__ import annotations

@@ -10,8 +10,7 @@ on the production meshes and record memory/cost/collective analysis.
         --shape train_4k --mesh single                       # one cell
     PYTHONPATH=src python -m repro.launch.dryrun --all       # everything
 
-Artifacts: artifacts/dryrun/{arch}__{shape}__{mesh}.json — consumed by
-benchmarks/roofline.py and EXPERIMENTS.md.
+Artifacts: artifacts/dryrun/{arch}__{shape}__{mesh}.json.
 """
 
 import argparse

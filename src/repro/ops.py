@@ -43,7 +43,6 @@ from jax import custom_batching
 
 from repro.core.identifiers import (
     BitfieldSpec,
-    BucketIdentifier,
     BucketSpec,
     CallableSpec,
     DeltaSpec,
@@ -61,7 +60,7 @@ from repro.core.identifiers import (
 from repro.core.pipeline import (
     MultisplitPlan,
     MultisplitResult,
-    default_backend,
+    backend_for,
     make_batched_plan,
     make_plan,
     make_segmented_plan,
@@ -78,7 +77,7 @@ Array = jnp.ndarray
 __all__ = [
     # bucket specs (hashable, pytree-static, kernel-fusable)
     "BucketSpec", "BitfieldSpec", "CallableSpec", "DeltaSpec", "EvenSpec",
-    "IdentitySpec", "RangeSpec", "BucketIdentifier",
+    "IdentitySpec", "RangeSpec",
     "as_spec", "delta_buckets", "even_buckets", "from_fn",
     "identity_buckets", "radix_buckets", "range_buckets",
     # results
@@ -265,8 +264,7 @@ def _resilient(
     the backend it starts on and whether the default chose it (``auto``).
     ``backend=None`` takes :func:`~repro.core.pipeline.default_backend`."""
     auto = backend is None
-    if auto:
-        backend = default_backend(n, keys.dtype)
+    backend = backend_for(backend, n, keys.dtype)
     if _traced(keys, values, segment_starts):
         return run(backend, tile)
     m_eff = spec.num_buckets * (segments or 1)

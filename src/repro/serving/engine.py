@@ -34,7 +34,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.core.pipeline import default_backend
+from repro.core.pipeline import backend_for
 from repro.runtime import resilience as _rz
 from repro.serving.admission import AdmissionConfig, AdmissionPolicy
 from repro.serving.metrics import ServingMetrics, StepRecord
@@ -109,9 +109,8 @@ class ServingConfig:
     verify_seed: int = 0             # is armed (DESIGN.md §17)
 
     def __post_init__(self) -> None:
-        if self.backend is None:
-            object.__setattr__(self, "backend", default_backend(
-                self.max_batch_tokens, np.int32))
+        object.__setattr__(self, "backend", backend_for(
+            self.backend, self.max_batch_tokens, np.int32))
         if not self.token_pad_classes:
             object.__setattr__(
                 self, "token_pad_classes",

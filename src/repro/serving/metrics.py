@@ -2,9 +2,9 @@
 per-request counters the continuous-batching loop exports (DESIGN.md §16).
 
 Everything here is host-side bookkeeping — nothing touches jax. The summary
-dict is what the serving bench (``benchmarks/bench_serving.py``) reports:
-latency percentiles in milliseconds, sustained QPS, queue/batch occupancy,
-and the robustness counters (shed / retried / requeued / failed).
+dict holds latency percentiles in milliseconds, sustained QPS, queue/batch
+occupancy, and the robustness counters (shed / retried / requeued /
+failed).
 """
 
 from __future__ import annotations

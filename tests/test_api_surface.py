@@ -11,7 +11,7 @@ from repro import ops
 
 EXPECTED_ALL = (
     "BucketSpec", "BitfieldSpec", "CallableSpec", "DeltaSpec", "EvenSpec",
-    "IdentitySpec", "RangeSpec", "BucketIdentifier",
+    "IdentitySpec", "RangeSpec",
     "as_spec", "delta_buckets", "even_buckets", "from_fn",
     "identity_buckets", "radix_buckets", "range_buckets",
     "MultisplitResult",
@@ -43,13 +43,12 @@ EXPECTED_SIGNATURES = {
     "histogram": "(keys, spec, *, backend=None, tile=None, family=None)",
     "radix_sort": (
         "(keys, values=None, *, radix_bits=8, key_bits=32, method='bms', "
-        "use_pallas=False, interpret=True, backend=None, tile=None, "
-        "family=None, fuse_digits=False)"
+        "backend=None, tile=None, family=None, fuse_digits=False)"
     ),
     "segmented_radix_sort": (
         "(keys, segment_starts, values=None, *, radix_bits=8, key_bits=32, "
-        "method='bms', use_pallas=False, interpret=True, backend=None, "
-        "tile=None, family=None, fuse_digits=False)"
+        "method='bms', backend=None, tile=None, family=None, "
+        "fuse_digits=False)"
     ),
     "delta_buckets": "(num_buckets, key_max=1073741824)",
     "identity_buckets": "(num_buckets)",
@@ -100,7 +99,7 @@ def test_specs_in_all_are_hashable_types():
     import dataclasses
 
     for name in ("DeltaSpec", "BitfieldSpec", "RangeSpec", "EvenSpec",
-                 "IdentitySpec", "CallableSpec", "BucketIdentifier"):
+                 "IdentitySpec", "CallableSpec"):
         cls = getattr(ops, name)
         assert issubclass(cls, ops.BucketSpec)
     s = ops.DeltaSpec(8, 1 << 20)

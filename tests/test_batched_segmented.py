@@ -7,7 +7,6 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import plan as msplan
 from repro.core.identifiers import delta_buckets
 from repro.core.multisplit import (
     batched_multisplit,
@@ -129,23 +128,23 @@ def test_segmented_pallas_is_one_grid_launch(monkeypatch):
 
 
 def test_segmented_radix_sort_pallas_never_materializes_labels(monkeypatch):
-    """The fused-digit guarantee extends to the segmented path: no
-    BucketIdentifier is ever evaluated host-side."""
+    """The fused-digit guarantee extends to the segmented path: no bucket
+    spec is ever evaluated host-side."""
     from repro.core import identifiers
 
     calls = []
-    orig = identifiers.BucketIdentifier.__call__
+    orig = identifiers.BucketSpec.__call__
 
     def spy(self, keys):
         calls.append(self.name)
         return orig(self, keys)
 
-    monkeypatch.setattr(identifiers.BucketIdentifier, "__call__", spy)
+    monkeypatch.setattr(identifiers.BucketSpec, "__call__", spy)
     keys = _keys(900, seed=9, hi=2**32)
     vals = jnp.arange(900, dtype=jnp.int32)
     starts = [0, 300, 300, 500]
     ks, vs = segmented_radix_sort(
-        keys, starts, vals, radix_bits=4, use_pallas=True, tile=256
+        keys, starts, vals, radix_bits=4, backend="pallas-interpret", tile=256
     )
     assert calls == [], f"host-side label materialization via {calls}"
     ends = starts[1:] + [900]

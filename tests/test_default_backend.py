@@ -14,7 +14,9 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro import ops
-from repro.core.pipeline import backend_decisions, default_backend
+from repro.core import histogram as core_histogram
+from repro.core import multisplit as core_multisplit
+from repro.core.pipeline import backend_decisions, backend_for, default_backend
 from repro.kernels import ops as kops
 from repro.serving import ServingConfig
 
@@ -84,6 +86,44 @@ def test_facade_defaults_reach_the_kernels_on_a_tpu(tpu):
                lambda k: ops.segmented_radix_sort(k, seg)[0]):
         assert _kernels_in(fn, keys)
     assert backend_decisions()[(N, "uint32")] == ("pallas", "tpu+32-bit keys")
+
+
+def test_backend_for_resolves_a_name_or_asks_the_default(no_tpu):
+    """A named backend is looked up in the registry and asks no default;
+    ``None`` is the default's answer; an unknown name is refused, also by
+    the serving config."""
+    assert backend_for("pallas-interpret", N + 6, jnp.uint32) == "pallas-interpret"
+    assert (N + 6, "uint32") not in backend_decisions()
+    assert backend_for(None, N + 6, jnp.uint32) == "vmap"
+    assert backend_decisions()[(N + 6, "uint32")] == ("vmap", "no TPU")
+    with pytest.raises(ValueError, match="unknown backend"):
+        backend_for("cuda", N + 6, jnp.uint32)
+    with pytest.raises(ValueError, match="unknown backend"):
+        ServingConfig(backend="cuda")
+
+
+CORE_ENTRIES = {
+    "multisplit": lambda k, spec, seg: core_multisplit.multisplit(k, spec).keys,
+    "batched_multisplit": lambda k, spec, seg: core_multisplit.batched_multisplit(
+        k.reshape(2, -1), spec).keys,
+    "segmented_multisplit": lambda k, spec, seg: core_multisplit.segmented_multisplit(
+        k, spec, seg).keys,
+    "histogram": lambda k, spec, seg: core_histogram.histogram(k, spec),
+}
+
+
+@pytest.mark.parametrize("entry", list(CORE_ENTRIES))
+def test_core_entry_points_take_the_default(request, entry):
+    """The core entry points resolve ``backend=None`` by the facade's rule:
+    the compiled kernels on a TPU, the ``vmap`` stages without one."""
+    keys = jnp.arange(N, dtype=jnp.uint32)
+    seg = jnp.array([0, N // 2], jnp.int32)
+    spec = ops.delta_buckets(16, N)
+    # a fresh function per trace: jax caches the trace of a function object
+    request.getfixturevalue("tpu")
+    assert _kernels_in(lambda k: CORE_ENTRIES[entry](k, spec, seg), keys)
+    request.getfixturevalue("no_tpu")
+    assert not _kernels_in(lambda k: CORE_ENTRIES[entry](k, spec, seg), keys)
 
 
 @pytest.mark.parametrize("sizes, kinds, backend", [

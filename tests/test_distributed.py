@@ -241,17 +241,13 @@ def test_sharded_call_on_this_host_defaults_to_vmap(monkeypatch, entry):
     assert set(seen) == {"vmap"} and not kernels
 
 
-@pytest.mark.parametrize("kw, backend", [
-    ({"use_pallas": True}, "pallas"),
-    ({"use_pallas": False}, "vmap"),
-    ({"backend": "pallas-interpret"}, "pallas-interpret"),
-])
+@pytest.mark.parametrize("backend", ["pallas", "vmap", "pallas-interpret"])
 @pytest.mark.parametrize("entry", ENTRIES)
-def test_legacy_use_pallas_keeps_its_meaning(tpu, monkeypatch, entry, kw, backend):
-    """``use_pallas=True`` is the compiled ``pallas`` backend where the
-    kernels compile, no longer ``pallas-interpret``."""
-    seen, _ = _plan_backends(monkeypatch, entry, **kw)
+def test_a_named_backend_is_honoured(tpu, monkeypatch, entry, backend):
+    """A backend the caller names runs every local stage, on a TPU too."""
+    seen, kernels = _plan_backends(monkeypatch, entry, backend=backend)
     assert set(seen) == {backend}
+    assert kernels == (backend != "vmap")
 
 
 @pytest.mark.parametrize("transport", ["ragged", "sparse"])

@@ -25,7 +25,7 @@ from repro.core.pipeline import (
     radix_passes,
 )
 from repro.core.pipeline.radix import MAX_PAIR_BITS
-from repro.core.sort import radix_sort, radix_sort_per_pass, segmented_radix_sort
+from repro.core.sort import radix_sort, segmented_radix_sort
 
 TILED_BACKENDS = ("vmap", "pallas-interpret")
 ALL_BACKENDS = ("reference",) + TILED_BACKENDS
@@ -94,11 +94,9 @@ def test_fused_bitwise_identical_flat_kv(backend, radix_bits):
                         fuse_digits=False)
     np.testing.assert_array_equal(np.asarray(kf), np.asarray(kc))
     np.testing.assert_array_equal(np.asarray(vf), np.asarray(vc))
-    if backend != "reference":
-        kp, vp = radix_sort_per_pass(keys, vals, radix_bits=radix_bits,
-                                     backend=backend)
-        np.testing.assert_array_equal(np.asarray(kf), np.asarray(kp))
-        np.testing.assert_array_equal(np.asarray(vf), np.asarray(vp))
+    order = np.argsort(np.asarray(keys), kind="stable")
+    np.testing.assert_array_equal(np.asarray(kf), np.asarray(keys)[order])
+    np.testing.assert_array_equal(np.asarray(vf), np.asarray(vals)[order])
 
 
 @pytest.mark.parametrize("backend", TILED_BACKENDS)

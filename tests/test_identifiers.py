@@ -1,9 +1,8 @@
 """BucketSpec layer (ISSUE 4): value hashing / equality, pytree staticness,
-the range_buckets validation + dtype-max fixes, pad-key invariants, and the
-BucketIdentifier deprecation shim."""
+the range_buckets validation + dtype-max fixes, pad-key invariants, and
+as_spec."""
 
 import dataclasses
-import warnings
 
 import numpy as np
 import jax
@@ -12,9 +11,6 @@ import pytest
 
 from repro.core.identifiers import (
     BitfieldSpec,
-    BucketIdentifier,
-    BucketSpec,
-    CallableSpec,
     DeltaSpec,
     EvenSpec,
     IdentitySpec,
@@ -239,31 +235,8 @@ def test_bitfield_spec_rejects_float_keys():
 
 
 # ---------------------------------------------------------------------------
-# the BucketIdentifier deprecation shim + as_spec
+# as_spec
 # ---------------------------------------------------------------------------
-
-def test_bucket_identifier_shim_warning_clean():
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        from repro.core.identifiers import BucketIdentifier as BI  # noqa: F401
-
-        bi = BI(lambda u: (u % 3).astype(jnp.int32), 3, name="mod3")
-        out = bi(jnp.arange(9, dtype=jnp.uint32))
-    assert not caught, [str(w.message) for w in caught]
-    assert isinstance(bi, CallableSpec) and isinstance(bi, BucketSpec)
-    assert bi.name == "mod3" and bi.num_buckets == 3 and not bi.fusable
-    np.testing.assert_array_equal(np.asarray(out), np.arange(9) % 3)
-
-
-def test_bucket_identifier_shim_runs_through_multisplit():
-    from repro.core.multisplit import multisplit, multisplit_ref
-
-    keys = jnp.asarray(np.random.RandomState(1).randint(0, 1000, 700, dtype=np.uint32))
-    bi = BucketIdentifier(lambda u: (u % 7).astype(jnp.int32), 7)
-    out = multisplit(keys, bi, tile=128)
-    ref = multisplit_ref(keys, bi)
-    np.testing.assert_array_equal(np.asarray(out.keys), np.asarray(ref.keys))
-
 
 def test_as_spec():
     s = delta_buckets(4)

@@ -32,26 +32,13 @@ def test_tile_positions(L, T, m):
 
 
 @pytest.mark.parametrize("L,T,m", SWEEP)
-@pytest.mark.parametrize("kdtype", [np.uint32, np.int32])
-def test_tile_reorder(L, T, m, kdtype):
-    rng = np.random.RandomState(T)
-    ids = jnp.asarray(rng.randint(0, m, (L, T), dtype=np.int32))
-    keys = jnp.asarray(rng.randint(0, 2**31 - 1, (L, T)).astype(kdtype))
-    vals = jnp.asarray(rng.randint(0, 2**31 - 1, (L, T), dtype=np.int32))
-    kk, vk, dk = ops.tile_reorder(ids, keys, vals, m)
-    kr, vr, dr = ref.tile_reorder(ids, keys, vals, m)
-    np.testing.assert_array_equal(np.asarray(kk), np.asarray(kr))
-    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
-    np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
-
-
-@pytest.mark.parametrize("L,T,m", SWEEP)
 @pytest.mark.parametrize("key_value", [False, True])
-def test_fused_postscan_reorder(L, T, m, key_value):
+@pytest.mark.parametrize("kdtype", [np.uint32, np.int32])
+def test_fused_postscan_reorder(L, T, m, key_value, kdtype):
     """THE fused kernel == composition of positions + reorder oracles."""
     rng = np.random.RandomState(L * T + m)
     ids = jnp.asarray(rng.randint(0, m, (L, T), dtype=np.int32))
-    keys = jnp.asarray(rng.randint(0, 2**31 - 1, (L, T)).astype(np.uint32))
+    keys = jnp.asarray(rng.randint(0, 2**31 - 1, (L, T)).astype(kdtype))
     vals = jnp.asarray(rng.randint(0, 2**31 - 1, (L, T), dtype=np.int32)) if key_value else None
     g = jnp.asarray(rng.randint(0, 100000, (L, m), dtype=np.int32))
     kk, vk, pk, permk = ops.fused_postscan_reorder(ids, g, keys, vals, m)

@@ -89,21 +89,10 @@ def test_pallas_backed_path_matches():
     vals = jnp.arange(4096, dtype=jnp.int32)
     bf = delta_buckets(32, 2**30)
     ref = multisplit_ref(keys, bf, vals)
-    out = multisplit(keys, bf, vals, method="bms", tile=512, use_pallas=True)
+    out = multisplit(keys, bf, vals, method="bms", tile=512,
+                     backend="pallas-interpret")
     np.testing.assert_array_equal(np.asarray(out.keys), np.asarray(ref.keys))
     np.testing.assert_array_equal(np.asarray(out.values), np.asarray(ref.values))
-
-
-def test_backend_arg_overrides_use_pallas():
-    """`backend=` is the plan-layer spelling; it must agree with the legacy
-    use_pallas knob it supersedes (see repro.core.plan)."""
-    keys = _random_keys(1024 + 5, seed=9)
-    bf = delta_buckets(8, 2**30)
-    legacy = multisplit(keys, bf, method="wms", tile=256, use_pallas=True)
-    modern = multisplit(keys, bf, method="wms", tile=256, backend="pallas-interpret")
-    np.testing.assert_array_equal(np.asarray(legacy.keys), np.asarray(modern.keys))
-    ref = multisplit_ref(keys, bf)
-    np.testing.assert_array_equal(np.asarray(modern.keys), np.asarray(ref.keys))
 
 
 @pytest.mark.parametrize("backend", ["reference", "vmap", "pallas-interpret"])

@@ -281,7 +281,7 @@ def test_packed_local_offsets_oblivious_runtime_guard():
 
 
 # ---------------------------------------------------------------------------
-# 3. RangeSpec: balanced-tree emit == serial chain == searchsorted (sat. 1)
+# 3. RangeSpec: balanced-tree emit == host emit == searchsorted (sat. 1)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("s", [1, 2, 3, 31, 255])
@@ -294,9 +294,9 @@ def test_rangespec_tree_matches_chain_and_searchsorted(s, dtype):
     keys_np[:s] = splitters[: min(s, 4096)]         # exact splitter hits
     keys = jnp.asarray(keys_np)
     tree = np.asarray(spec.emit_in_kernel(keys))
-    chain = np.asarray(spec._emit_chain(keys))
+    host = np.asarray(spec.emit(keys))
     ref = np.searchsorted(splitters, keys_np, side="right")
-    np.testing.assert_array_equal(tree, chain)
+    np.testing.assert_array_equal(tree, host)
     np.testing.assert_array_equal(tree, ref)
 
 

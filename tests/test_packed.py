@@ -14,7 +14,6 @@ import pytest
 import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
-import repro.core.plan as msplan
 from repro.core.identifiers import delta_buckets, from_fn
 from repro.core.multisplit import (
     batched_multisplit,
@@ -32,6 +31,7 @@ from repro.core.pipeline import (
     resolve_kernel_family,
     tile_local_offsets,
 )
+from repro.core.pipeline import tiles
 from repro.core.pipeline.tiles import PACKED_MIN_BUCKETS, _FAMILY_CACHE
 from repro.core.sort import radix_sort
 from repro.kernels.common import (
@@ -278,9 +278,9 @@ def test_heuristic_tile_regression_n1m_m256():
     (19 MiB at T=1024 on v5e, over the 16 MiB budget), so the packed tile
     is 512 there too (vmap keeps 4096)."""
     clear_tile_cache()
-    assert msplan._heuristic_tile(1 << 20, 256, "bms", "pallas", family="onehot") == 512
-    assert msplan._heuristic_tile(1 << 20, 256, "bms", "pallas", family="packed") == 512
-    assert msplan._heuristic_tile(1 << 20, 256, "bms", "vmap", family="packed") == 4096
+    assert tiles._heuristic_tile(1 << 20, 256, "bms", "pallas", family="onehot") == 512
+    assert tiles._heuristic_tile(1 << 20, 256, "bms", "pallas", family="packed") == 512
+    assert tiles._heuristic_tile(1 << 20, 256, "bms", "vmap", family="packed") == 4096
     p = make_plan(1 << 20, 256, method="bms", backend="pallas")
     assert (p.family, p.tile) == ("packed", 512)
     p1h = make_plan(1 << 20, 256, method="bms", backend="pallas", family="onehot")
@@ -293,12 +293,12 @@ def test_explicit_family_does_not_poison_tile_cache():
     rule)."""
     clear_tile_cache()
     shape = (1 << 20, 256, "bms", False, "vmap")
-    msplan._TILE_CACHE[shape] = 2048                # a measured pin
+    tiles._TILE_CACHE[shape] = 2048                # a measured pin
     p_pk = make_plan(1 << 20, 256, method="bms", backend="vmap")            # auto: packed
-    assert p_pk.family == "packed" and msplan._TILE_CACHE[shape] == p_pk.tile == 2048
+    assert p_pk.family == "packed" and tiles._TILE_CACHE[shape] == p_pk.tile == 2048
     p_1h = make_plan(1 << 20, 256, method="bms", backend="vmap", family="onehot")
     assert (p_1h.family, p_1h.tile) == ("onehot", 4096)    # its own model
-    assert msplan._TILE_CACHE[shape] == 2048        # auto entry untouched
+    assert tiles._TILE_CACHE[shape] == 2048        # auto entry untouched
     assert make_plan(1 << 20, 256, method="bms", backend="vmap").tile == 2048
 
 
@@ -315,11 +315,11 @@ def test_family_is_a_hashable_plan_axis():
 def test_autotune_searches_tile_family_jointly_and_records_reason():
     clear_tile_cache()
     bf = delta_buckets(64, 2**30)
-    tuned = msplan.autotune_tile(
+    tuned = tiles.autotune_tile(
         4096, bf, method="bms", backend="vmap", candidates=(512, 1024), trials=1
     )
     assert tuned in (512, 1024)
-    assert msplan._TILE_CACHE[(4096, 64, "bms", False, "vmap")] == tuned
+    assert tiles._TILE_CACHE[(4096, 64, "bms", False, "vmap")] == tuned
     fam, reason = family_decision(4096, 64, "bms", "vmap")
     assert fam in FAMILIES
     assert "autotuned" in reason and str(tuned) in reason
@@ -354,14 +354,14 @@ def test_autotune_family_flip_invalidates_other_kv_tile():
     p0 = make_plan(1 << 14, 256, method="bms", backend="pallas-interpret")
     assert (p0.family, p0.tile) == ("packed", 512)
     # force an autotuned family flip via the kv variant (onehot only)
-    msplan.autotune_tile(
+    tiles.autotune_tile(
         1 << 14, bf, method="bms", backend="pallas-interpret", key_value=True,
         candidates=(512,), families=("onehot",), trials=1,
     )
     assert family_decision(1 << 14, 256, "bms", "pallas-interpret")[0] == "onehot"
     # the key-only shape must now re-resolve its tile under 'onehot' — the
     # stale entry sized under the packed model is gone
-    assert (1 << 14, 256, "bms", False, "pallas-interpret") not in msplan._TILE_CACHE
+    assert (1 << 14, 256, "bms", False, "pallas-interpret") not in tiles._TILE_CACHE
     p1 = make_plan(1 << 14, 256, method="bms", backend="pallas-interpret")
     assert (p1.family, p1.tile) == ("onehot", 512)
 
@@ -374,12 +374,12 @@ def test_packed_min_buckets_threshold_is_the_flip_point():
 
 
 def test_packed_min_buckets_matches_measured_crossover():
-    """Regression pin for the MEASURED family crossover (ISSUE 6 satellite).
+    """Regression pin for the packed/onehot family crossover.
 
-    The original flip point (64) was a working-set argument; the host-bench
-    packed_vs_onehot sweep (benchmarks/bench_multisplit.py, key-value flat
-    multisplit re-measured at n ∈ {2^18, 2^20}) shows packed winning from m=8 up
-    (1.12–1.25× at m=8, ≥1.5× at m=16) and only tying at m=4. If this pin
-    fails, re-run ``benchmarks/bench_multisplit.py`` packed_vs_onehot and
-    move the constant to the new measured crossover — don't guess."""
+    The original flip point (64) was a working-set argument. The 8 was
+    measured on a CPU host, not on the chip: a key-value flat multisplit at
+    n ∈ {2^18, 2^20} had packed winning from m=8 up (1.12–1.25× at m=8,
+    ≥1.5× at m=16) and only tying at m=4. It stays pinned until the chip
+    benchmark has a cell on each side of it (ROADMAP Queue 1 item 7); move
+    it only on that measurement — don't guess."""
     assert PACKED_MIN_BUCKETS == 8

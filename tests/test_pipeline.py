@@ -1,9 +1,6 @@
 """The stage-graph pipeline package (ISSUE 3): backend registry, partial
-pipelines (counts_only / positions_only), the chained RadixPipeline
-(pad/tile exactly once per sort), and the repro.core.plan compat shim."""
-
-import importlib
-import warnings
+pipelines (counts_only / positions_only), and the chained RadixPipeline
+(pad/tile exactly once per sort)."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -18,7 +15,7 @@ from repro.core.multisplit import (
     segmented_multisplit,
 )
 from repro.core.pipeline import RadixPipeline, get_backend, make_plan
-from repro.core.sort import radix_sort, radix_sort_per_pass, segmented_radix_sort
+from repro.core.sort import radix_sort, segmented_radix_sort
 
 BACKENDS = ["reference", "vmap", "pallas-interpret"]
 
@@ -156,19 +153,14 @@ def test_partial_modes_non_32bit_keys_on_kernel_backend():
 @pytest.mark.parametrize("key_value", [False, True])
 def test_chained_radix_bitwise_matches_per_pass(backend, method, key_value):
     """THE acceptance criterion: radix_sort (chained RadixPipeline) is
-    bitwise identical to the PR-2 per-pass execution on every backend."""
+    bitwise identical to a stable sort of the keys on every backend."""
     rng = np.random.RandomState(7)
     keys = jnp.asarray(rng.randint(0, 2**32, 2500 + 13, dtype=np.uint32))
     vals = jnp.arange(keys.shape[0], dtype=jnp.int32) if key_value else None
     ks, vs = radix_sort(keys, vals, radix_bits=8, method=method, backend=backend, tile=512)
-    ks2, vs2 = radix_sort_per_pass(
-        keys, vals, radix_bits=8, method=method, backend=backend, tile=512
-    )
-    np.testing.assert_array_equal(np.asarray(ks), np.asarray(ks2))
     order = np.argsort(np.asarray(keys), kind="stable")
     np.testing.assert_array_equal(np.asarray(ks), np.asarray(keys)[order])
     if key_value:
-        np.testing.assert_array_equal(np.asarray(vs), np.asarray(vs2))
         np.testing.assert_array_equal(np.asarray(vs), np.asarray(vals)[order])
 
 
@@ -177,9 +169,9 @@ def test_chained_batched_radix_matches_per_pass(backend):
     rng = np.random.RandomState(3)
     keys = jnp.asarray(rng.randint(0, 2**16, (5, 700), dtype=np.uint32))
     ks, _ = radix_sort(keys, radix_bits=4, key_bits=16, backend=backend, tile=128)
-    ks2, _ = radix_sort_per_pass(keys, radix_bits=4, key_bits=16, backend=backend, tile=128)
-    np.testing.assert_array_equal(np.asarray(ks), np.asarray(ks2))
-    np.testing.assert_array_equal(np.asarray(ks), np.sort(np.asarray(keys), axis=1))
+    order = np.argsort(np.asarray(keys), axis=1, kind="stable")
+    np.testing.assert_array_equal(
+        np.asarray(ks), np.take_along_axis(np.asarray(keys), order, axis=1))
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -190,14 +182,10 @@ def test_chained_segmented_radix_matches_per_pass(backend):
     ks, _ = segmented_radix_sort(
         keys, starts, radix_bits=4, key_bits=16, backend=backend, tile=128
     )
-    ks2, _ = radix_sort_per_pass(
-        keys, radix_bits=4, key_bits=16, backend=backend, tile=128,
-        segment_starts=starts,
-    )
-    np.testing.assert_array_equal(np.asarray(ks), np.asarray(ks2))
     for a, e in zip(starts, starts[1:] + [900]):
+        seg = np.asarray(keys[a:e])
         np.testing.assert_array_equal(
-            np.asarray(ks[a:e]), np.sort(np.asarray(keys[a:e]))
+            np.asarray(ks[a:e]), seg[np.argsort(seg, kind="stable")]
         )
 
 
@@ -223,7 +211,7 @@ def _count_padding(monkeypatch):
 @pytest.mark.parametrize("backend", ["vmap", "pallas-interpret"])
 def test_radix_pipeline_pads_and_tiles_exactly_once(backend, monkeypatch):
     """Acceptance: the chained pipeline pads/tiles each operand ONCE per
-    sort; the legacy per-pass path re-pads every pass."""
+    sort, not once per digit pass."""
     calls = _count_padding(monkeypatch)
     rng = np.random.RandomState(0)
     keys = jnp.asarray(rng.randint(0, 2**32, 3000, dtype=np.uint32))
@@ -231,13 +219,6 @@ def test_radix_pipeline_pads_and_tiles_exactly_once(backend, monkeypatch):
 
     ks, vs = radix_sort(keys, vals, radix_bits=8, backend=backend, tile=512)
     assert calls["pad_to_tiles"] == 2                # keys once + values once
-    chained = calls["pad_to_tiles"]
-
-    radix_sort_per_pass(keys, vals, radix_bits=8, backend=backend, tile=512)
-    legacy = calls["pad_to_tiles"] - chained
-    n_pass = 4
-    # per pass: keys + values (+ host-side ids on non-fusing backends)
-    assert legacy >= 2 * n_pass
     order = np.argsort(np.asarray(keys), kind="stable")
     np.testing.assert_array_equal(np.asarray(ks), np.asarray(keys)[order])
     np.testing.assert_array_equal(np.asarray(vs), np.asarray(vals)[order])
@@ -268,32 +249,8 @@ def test_radix_pipeline_resolves_tile_once():
 
 
 # ---------------------------------------------------------------------------
-# repro.core.plan compat shim
+# Layering
 # ---------------------------------------------------------------------------
-
-def test_plan_shim_import_compat():
-    """Old imports keep working, warning-free, and share state with the
-    package (the tile cache is the SAME dict, not a copy)."""
-    import repro.core.plan as plan_shim
-
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        plan_shim = importlib.reload(plan_shim)
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    assert not dep, f"plan shim import raised {dep}"
-    for sym in (
-        "MultisplitPlan", "MultisplitResult", "make_plan", "make_radix_plan",
-        "make_batched_plan", "make_segmented_plan", "make_segmented_radix_plan",
-        "resolve_tile", "autotune_tile", "clear_tile_cache", "resolve_backend",
-        "BACKENDS", "WMS_TILE", "BMS_TILE", "global_scan", "pad_to_tiles",
-        "segment_ids_from_starts", "tile_local_offsets", "_TILE_CACHE",
-        "_heuristic_tile", "_VMEM_BUDGET_BYTES", "_MIN_TILE",
-    ):
-        assert hasattr(plan_shim, sym), f"shim lost {sym}"
-    from repro.core.pipeline import tiles
-
-    assert plan_shim._TILE_CACHE is tiles._TILE_CACHE
-
 
 def test_no_private_cross_module_reaches_in_consumers():
     """Acceptance: migrated consumers are grep-clean of private plan-layer
@@ -329,8 +286,8 @@ def test_histogram_is_counts_only_pipeline(monkeypatch):
         monkeypatch.setattr(cls, "positions", boom)
         monkeypatch.setattr(cls, "reorder", boom)
     keys = jnp.asarray(np.random.RandomState(1).uniform(0, 64, 9000).astype(np.float32))
-    for use_pallas in (False, True):
-        h = histogram_even(keys, 0.0, 64.0, 16, use_pallas=use_pallas)
+    for backend in ("vmap", "pallas-interpret"):
+        h = histogram_even(keys, 0.0, 64.0, 16, backend=backend)
         expect, _ = np.histogram(np.asarray(keys), bins=16, range=(0, 64))
         np.testing.assert_array_equal(np.asarray(h), expect)
 

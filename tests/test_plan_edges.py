@@ -10,7 +10,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import plan as msplan
+from repro.core import pipeline as pl
 from repro.core.identifiers import delta_buckets, from_fn, identity_buckets
 from repro.core.multisplit import (
     batched_multisplit,
@@ -18,6 +18,7 @@ from repro.core.multisplit import (
     multisplit_ref,
     segmented_multisplit,
 )
+from repro.core.pipeline import tiles
 from repro.core.sort import radix_sort, segmented_radix_sort
 
 BACKENDS = ["reference", "vmap", "pallas-interpret"]
@@ -172,30 +173,30 @@ def test_segmented_radix_sort_empty_segments():
 
 def test_layout_validation():
     with pytest.raises(ValueError):
-        msplan.make_plan(100, 4, batch=2, segments=2)        # mutually exclusive
+        pl.make_plan(100, 4, batch=2, segments=2)        # mutually exclusive
     with pytest.raises(ValueError):
-        msplan.make_plan(100, 4, batch=0)
+        pl.make_plan(100, 4, batch=0)
     with pytest.raises(ValueError):
-        msplan.make_plan(100, 4, segments=0)
+        pl.make_plan(100, 4, segments=0)
     bf = delta_buckets(4)
-    p = msplan.make_plan(100, 4, bucket_fn=bf)
+    p = pl.make_plan(100, 4, bucket_fn=bf)
     with pytest.raises(ValueError):                          # not segmented
         p(_keys(100), segment_starts=jnp.zeros((1,), jnp.int32))
-    ps = msplan.make_plan(100, 4, bucket_fn=bf, segments=2)
+    ps = pl.make_plan(100, 4, bucket_fn=bf, segments=2)
     with pytest.raises(ValueError):                          # starts required
         ps(_keys(100))
     with pytest.raises(ValueError):                          # wrong starts shape
         ps(_keys(100), segment_starts=jnp.zeros((3,), jnp.int32))
-    pb = msplan.make_plan(50, 4, bucket_fn=bf, batch=2)
+    pb = pl.make_plan(50, 4, bucket_fn=bf, batch=2)
     with pytest.raises(ValueError):                          # wrong batch shape
         pb(_keys(100).reshape(4, 25))
 
 
 def test_stages_mark_layouts():
     bf = delta_buckets(8)
-    fl = msplan.make_plan(256, 8, bucket_fn=bf)
-    bt = msplan.make_plan(256, 8, bucket_fn=bf, batch=4)
-    sg = msplan.make_plan(256, 8, bucket_fn=bf, segments=4)
+    fl = pl.make_plan(256, 8, bucket_fn=bf)
+    bt = pl.make_plan(256, 8, bucket_fn=bf, batch=4)
+    sg = pl.make_plan(256, 8, bucket_fn=bf, segments=4)
     assert not fl.stages()[0].startswith("layout:")
     assert bt.stages()[0] == "layout:batched[4]"
     assert sg.stages()[0] == "layout:segmented[4]"
@@ -210,49 +211,49 @@ def test_stages_mark_layouts():
 def test_explicit_tile_does_not_poison_cache():
     """Regression: a one-off ``tile=`` override must leave subsequent
     same-shape plans resolving to the heuristic/autotuned tile."""
-    msplan.clear_tile_cache()
+    tiles.clear_tile_cache()
     shape = (1 << 16, 32, "bms", False, "vmap")
-    heuristic = msplan._heuristic_tile(1 << 16, 32, "bms", "vmap")
+    heuristic = tiles._heuristic_tile(1 << 16, 32, "bms", "vmap")
     assert heuristic != 64  # the override below must be distinguishable
 
-    p_override = msplan.make_plan(1 << 16, 32, method="bms", backend="vmap", tile=64)
+    p_override = pl.make_plan(1 << 16, 32, method="bms", backend="vmap", tile=64)
     assert p_override.tile == 64
     # the override was honored but NOT cached
-    assert shape not in msplan._TILE_CACHE or msplan._TILE_CACHE[shape] != 64
+    assert shape not in tiles._TILE_CACHE or tiles._TILE_CACHE[shape] != 64
 
-    p_after = msplan.make_plan(1 << 16, 32, method="bms", backend="vmap")
+    p_after = pl.make_plan(1 << 16, 32, method="bms", backend="vmap")
     assert p_after.tile == heuristic
-    assert msplan._TILE_CACHE[shape] == heuristic
+    assert tiles._TILE_CACHE[shape] == heuristic
 
     # and an override AFTER the cache is warm neither reads nor clobbers it
-    p_again = msplan.make_plan(1 << 16, 32, method="bms", backend="vmap", tile=128)
+    p_again = pl.make_plan(1 << 16, 32, method="bms", backend="vmap", tile=128)
     assert p_again.tile == 128
-    assert msplan._TILE_CACHE[shape] == heuristic
+    assert tiles._TILE_CACHE[shape] == heuristic
 
 
 def test_autotuned_tile_survives_override():
     """An autotune-pinned winner stays pinned across explicit overrides."""
-    msplan.clear_tile_cache()
+    tiles.clear_tile_cache()
     bf = delta_buckets(8, 2**30)
-    tuned = msplan.autotune_tile(
+    tuned = tiles.autotune_tile(
         4096, bf, method="bms", backend="vmap", candidates=(256, 1024), trials=1
     )
-    msplan.make_plan(4096, 8, method="bms", backend="vmap", bucket_fn=bf, tile=32)
-    assert msplan._TILE_CACHE[(4096, 8, "bms", False, "vmap")] == tuned
-    assert msplan.make_plan(4096, 8, method="bms", backend="vmap", bucket_fn=bf).tile == tuned
+    pl.make_plan(4096, 8, method="bms", backend="vmap", bucket_fn=bf, tile=32)
+    assert tiles._TILE_CACHE[(4096, 8, "bms", False, "vmap")] == tuned
+    assert pl.make_plan(4096, 8, method="bms", backend="vmap", bucket_fn=bf).tile == tuned
 
 
 def test_segmented_tile_cache_keyed_on_combined_width():
     """Segmented plans budget VMEM for the COMBINED (s*m) scan width, so
     their cache entries must not collide with the flat (n, m) shape."""
-    msplan.clear_tile_cache()
+    tiles.clear_tile_cache()
     bf = delta_buckets(4)
-    flat = msplan.make_plan(1 << 18, 4, backend="pallas-interpret", bucket_fn=bf)
-    seg = msplan.make_plan(
+    flat = pl.make_plan(1 << 18, 4, backend="pallas-interpret", bucket_fn=bf)
+    seg = pl.make_plan(
         1 << 18, 4, backend="pallas-interpret", bucket_fn=bf, segments=64
     )
-    assert (1 << 18, 4, "bms", False, "pallas-interpret") in msplan._TILE_CACHE
-    assert (1 << 18, 256, "bms", False, "pallas-interpret") in msplan._TILE_CACHE
+    assert (1 << 18, 4, "bms", False, "pallas-interpret") in tiles._TILE_CACHE
+    assert (1 << 18, 256, "bms", False, "pallas-interpret") in tiles._TILE_CACHE
     # the combined width flips the 256-wide shape into the PACKED family
     # (PR-5); on kernel backends both families are charged the compiled
     # kernel's VMEM, whose T×T planes dominate at these widths, so the
@@ -261,7 +262,7 @@ def test_segmented_tile_cache_keyed_on_combined_width():
     assert seg.tile == flat.tile
     # within the one-hot family the old rule still holds at a width that
     # pushes the working set past the budget floor
-    seg1h = msplan.make_plan(
+    seg1h = pl.make_plan(
         1 << 18, 4, backend="pallas-interpret", bucket_fn=bf, segments=1024,
         family="onehot",
     )

@@ -15,6 +15,7 @@ from repro.runtime.supervisor import FaultInjector
 from repro.serving import (
     ServerLoop,
     ServingConfig,
+    closed_loop,
     open_loop,
     percentiles,
     poisson_arrivals,
@@ -328,12 +329,6 @@ def test_percentiles_edges():
         percentiles([1.0], (101.0,))
 
 
-def test_percentiles_reexported_from_benchmarks_common():
-    from benchmarks.common import percentiles as bench_percentiles
-
-    assert bench_percentiles is percentiles
-
-
 # ---------------------------------------------------------------------------
 # Open loop + engine edges
 # ---------------------------------------------------------------------------
@@ -523,6 +518,22 @@ def test_verify_mismatch_counted_and_healed_by_reference(rz_clean):
     report = rz_clean.last_report()
     assert report is not None and report["spec"] == "route_tokens_segmented"
     assert rz_clean.stats()["verify_mismatches"] == s["verify_mismatches"]
+
+
+def test_dispatch_faults_under_verify_never_corrupt_or_lose_requests(rz_clean):
+    """Chaos: seeded dispatch faults on 5% of launches under level-2
+    verification. Every injected fault must retry or degrade; none may
+    corrupt a routing result or lose a request."""
+    rz_clean.set_verify(2)
+    injector = FaultInjector(dispatch_rate=0.05, seed=13)
+    rz_clean.set_fault_injector(injector)
+    cfg = _cfg(max_batch_tokens=256, max_batch_requests=4, max_queue_depth=512)
+    n = 200                                  # 50 steps, 5 of them faulted
+    s = closed_loop(ServerLoop(cfg), synthetic_requests(n, E, seed=13))
+    assert injector.dispatch_injected > 0
+    assert s["verify_mismatches"] == 0 and s["dropped_by_bug"] == 0
+    assert s["submitted"] == n
+    assert s["completed"] + s["shed"] + s["failed"] == n
 
 
 def test_verify_sample_rate_zero_never_checks(rz_clean):

@@ -39,7 +39,7 @@ def test_radix_sort_fused_pallas(radix_bits, method):
     vals = np.arange(3000, dtype=np.int32)
     ks, vs = radix_sort(
         jnp.asarray(keys), jnp.asarray(vals),
-        radix_bits=radix_bits, method=method, use_pallas=True, tile=512,
+        radix_bits=radix_bits, method=method, backend="pallas-interpret", tile=512,
     )
     order = np.argsort(keys, kind="stable")
     np.testing.assert_array_equal(np.asarray(ks), keys[order])
@@ -80,10 +80,10 @@ def test_direct_sort_baseline():
 
 
 @pytest.mark.parametrize("m", [2, 16, 64, 256])
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_histogram_even(m, use_pallas):
+@pytest.mark.parametrize("backend", ["vmap", "pallas-interpret"])
+def test_histogram_even(m, backend):
     keys = jnp.asarray(np.random.RandomState(m).uniform(0, 1024, 20000).astype(np.float32))
-    h = histogram_even(keys, 0.0, 1024.0, m, use_pallas=use_pallas)
+    h = histogram_even(keys, 0.0, 1024.0, m, backend=backend)
     expect, _ = np.histogram(np.asarray(keys), bins=m, range=(0, 1024))
     np.testing.assert_array_equal(np.asarray(h), expect)
 
@@ -104,24 +104,24 @@ def test_histogram_range():
 # the degenerate shapes the old private-_pad_to_tiles path special-cased)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_histogram_empty_input(use_pallas):
-    h = histogram_even(jnp.zeros((0,), jnp.float32), 0.0, 1.0, 8, use_pallas=use_pallas)
+@pytest.mark.parametrize("backend", ["vmap", "pallas-interpret"])
+def test_histogram_empty_input(backend):
+    h = histogram_even(jnp.zeros((0,), jnp.float32), 0.0, 1.0, 8, backend=backend)
     np.testing.assert_array_equal(np.asarray(h), np.zeros(8))
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_histogram_single_bucket(use_pallas):
+@pytest.mark.parametrize("backend", ["vmap", "pallas-interpret"])
+def test_histogram_single_bucket(backend):
     keys = jnp.asarray(np.random.RandomState(0).uniform(0, 9, 777).astype(np.float32))
-    h = histogram_even(keys, 0.0, 9.0, 1, use_pallas=use_pallas)
+    h = histogram_even(keys, 0.0, 9.0, 1, backend=backend)
     np.testing.assert_array_equal(np.asarray(h), np.asarray([777]))
 
 
 @pytest.mark.parametrize("n", [1, 255, 257, 4096 + 37])
 def test_histogram_non_multiple_of_tile(n):
     keys = jnp.asarray(np.random.RandomState(n).uniform(0, 32, n).astype(np.float32))
-    for use_pallas in (False, True):
-        h = histogram_even(keys, 0.0, 32.0, 8, tile=256, use_pallas=use_pallas)
+    for backend in ("vmap", "pallas-interpret"):
+        h = histogram_even(keys, 0.0, 32.0, 8, tile=256, backend=backend)
         expect, _ = np.histogram(np.asarray(keys), bins=8, range=(0, 32))
         np.testing.assert_array_equal(np.asarray(h), expect)
         assert int(h.sum()) == n
